@@ -14,6 +14,7 @@ from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import make_train_fn
 from sheeprl_tpu.algos.dreamer_v3.utils import init_moments
 from sheeprl_tpu.config.loader import load_config
 from sheeprl_tpu.core.runtime import Runtime
+from sheeprl_tpu.telemetry.device import chip_peak_flops
 
 
 def run(label, extra, batch=128):
@@ -67,7 +68,8 @@ def run(label, extra, batch=128):
             pr, opt, mom, cnt, _flat, m = train_fn(pr, opt, mom, cnt, batches, key)
         np.asarray(cnt)
         dt = (time.perf_counter() - t0) / 10
-        mfu = flops / dt / 197e12 if flops else float("nan")
+        peak = chip_peak_flops(runtime.device)  # None on a chip the table does not know
+        mfu = flops / dt / peak if flops and peak else float("nan")
         print(f"{label}: {dt*1e3:.1f} ms/step  flops={flops/1e12 if flops else 0:.2f}T  MFU={mfu:.3f}", flush=True)
     except Exception as e:
         print(f"{label}: FAILED {type(e).__name__}: {str(e)[:160]}", flush=True)

@@ -97,7 +97,7 @@ def main(workdir: str | None = None, timeout: float = 480.0) -> dict:
         os.environ,
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO_ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        SHEEPRL_TPU_COMP_CACHE_DIR=cache_dir,
+        JAX_COMPILATION_CACHE_DIR=cache_dir,
         # the smoke's kernels are tiny and compile in milliseconds: cache them
         # all, or the warm pass would legitimately miss everything
         SHEEPRL_TPU_COMP_CACHE_MIN_SECS="0",

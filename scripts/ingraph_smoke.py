@@ -98,7 +98,7 @@ def main(workdir: str | None = None, timeout: float = 480.0) -> dict:
         os.environ,
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO_ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        SHEEPRL_TPU_COMP_CACHE_DIR=os.path.join(workdir, "xla_cache"),
+        JAX_COMPILATION_CACHE_DIR=os.path.join(workdir, "xla_cache"),
         _SHEEPRL_INGRAPH_SMOKE_OVERRIDES=json.dumps(OVERRIDES),
         # arm the grad-sync chaos seam in benign `fire` mode: the fused run must
         # actually pass through the microbatched update dispatch every iteration

@@ -118,7 +118,7 @@ def _run_variant(name: str, spec: dict, workdir: str, timeout: float) -> dict:
         JAX_PLATFORMS="cpu",
         XLA_FLAGS=" ".join(xla_flags),
         PYTHONPATH=REPO_ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        SHEEPRL_TPU_COMP_CACHE_DIR=os.path.join(workdir, "xla_cache"),
+        JAX_COMPILATION_CACHE_DIR=os.path.join(workdir, "xla_cache"),
         _SHEEPRL_INGRAPH_TRAIN_SMOKE_OVERRIDES=json.dumps(spec["overrides"]),
     )
     proc = subprocess.run(

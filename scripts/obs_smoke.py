@@ -79,7 +79,7 @@ def _child_env(workdir: str, ledger: str) -> dict:
         os.environ,
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO_ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""),
-        SHEEPRL_TPU_COMP_CACHE_DIR=os.path.join(workdir, "xla_cache"),
+        JAX_COMPILATION_CACHE_DIR=os.path.join(workdir, "xla_cache"),
         SHEEPRL_TPU_TRACE=f"plane=train;capacity=4096;trace_id={_TRACE_ID}",
         SHEEPRL_TPU_PROGRAMS=ledger,
         _SHEEPRL_OBS_SMOKE_OVERRIDES=json.dumps(_OVERRIDES),

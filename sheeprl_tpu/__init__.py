@@ -12,26 +12,19 @@ __version__ = "0.1.0"
 
 ROOT_DIR = os.path.dirname(os.path.abspath(__file__))
 
-# Persistent XLA compilation cache: first-compile of the jitted train steps costs
-# tens of seconds on TPU; later processes reuse the compiled executables. Opt out
-# with SHEEPRL_TPU_NO_COMP_CACHE=1. Settings the user already made (env vars,
-# jax config, the `compile:` Hydra group applied later by the CLI) win: only
-# fill gaps here, never overwrite.
-if not os.environ.get("SHEEPRL_TPU_NO_COMP_CACHE"):
-    try:
-        import jax
+# Persistent XLA compilation cache: the first compile of the jitted train steps
+# costs tens of seconds on TPU; later processes reuse the compiled executables.
+# ONE name places it: where JAX_COMPILATION_CACHE_DIR is set JAX already reads
+# it and nothing here (or in core/compile.py, or any config key) sets a
+# directory. Unset, the cache goes to one fixed path inside the checkout — the
+# path is part of the cache key, so a directory that moves never hits. Disable
+# with JAX's own JAX_ENABLE_COMPILATION_CACHE=false.
+COMPILE_CACHE_DIR = os.path.join(os.path.dirname(ROOT_DIR), ".jax_cache")
 
-        if os.environ.get("SHEEPRL_TPU_COMP_CACHE_DIR"):
-            jax.config.update(
-                "jax_compilation_cache_dir", os.environ["SHEEPRL_TPU_COMP_CACHE_DIR"]
-            )
-        elif jax.config.jax_compilation_cache_dir is None:
-            jax.config.update(
-                "jax_compilation_cache_dir",
-                os.path.join(os.path.expanduser("~"), ".cache", "sheeprl_tpu_xla"),
-            )
-        min_secs = os.environ.get("SHEEPRL_TPU_COMP_CACHE_MIN_SECS")
-        if min_secs is not None:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", float(min_secs))
-    except Exception:  # pragma: no cover - cache is best-effort
-        pass
+import jax  # noqa: E402
+
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+_min_secs = os.environ.get("SHEEPRL_TPU_COMP_CACHE_MIN_SECS")
+if _min_secs is not None:
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", float(_min_secs))

@@ -387,7 +387,8 @@ def main(runtime, cfg: Dict[str, Any]):
                         "Loss/value_loss",
                         "Resilience/nonfinite_skips",
                         "Grads/global_norm",
-                    )
+                    ),
+                    sharding=runtime.replicated,
                 ),
                 name="metric.drain",
             )
@@ -429,7 +430,8 @@ def main(runtime, cfg: Dict[str, Any]):
                         "Loss/value_loss",
                         "Resilience/nonfinite_skips",
                         "Grads/global_norm",
-                    )
+                    ),
+                    sharding=runtime.replicated,
                 ),
                 name="metric.drain",
             )

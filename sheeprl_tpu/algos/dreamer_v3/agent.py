@@ -735,10 +735,11 @@ class RSSM:
         """lax.scan over the sequence dim: the hot loop of world-model learning.
 
         With ``kernels != off`` the non-decoupled path dispatches to the fused
-        Pallas step (ops/pallas/rssm_step.py): same return contract, logits in
-        f32, sampling distribution-equivalent (not bitwise) to this path. Any
-        structural mismatch or an active ``train.kernel_dispatch`` failpoint
-        degrades back to the flax scan below.
+        step (ops/pallas/rssm_step.py): same return contract, logits in f32,
+        sampling distribution-equivalent (not bitwise) to this path. An active
+        ``train.kernel_dispatch`` failpoint degrades back to the flax scan
+        below; so does a structural mismatch under ``kernels=auto`` (logged
+        once), while a NAMED implementation raises it.
         """
         if self.kernels != "off" and not self.decoupled:
             fused = self._fused_dynamic_scan(wm_params, embedded_obs, actions, is_first, key)
@@ -798,21 +799,38 @@ class RSSM:
         posteriors_logits = posteriors_logits.reshape(T, B, self.stochastic_size, self.discrete_size)
         return recurrent_states, posteriors, priors_logits, posteriors_logits
 
+    def _fused_step_params(self, wm_params, embed_size: int, action_size: int, batch: int):
+        """``(params, spec-with-impl)`` for the fused step, or None for the flax
+        path. A structure the fused contract does not cover is the flax path
+        only under ``kernels=auto``, with one warning; a named implementation
+        raises :class:`KernelUnsupported`."""
+        from sheeprl_tpu.ops.pallas import rssm_step as _fk
+
+        try:
+            spec = self._fused_spec(embed_size, action_size)
+            impl = _fk.select_impl(self.kernels, spec, batch)
+            if impl is None:
+                return None
+            return _fk.extract_step_params(wm_params, self.stoch_state_size), spec.with_impl(impl)
+        except _fk.KernelUnsupported as e:
+            if str(self.kernels).lower() != "auto":
+                raise
+            _fk.log_choice_once("auto", "flax scan", f"fused-step contract not met: {e}")
+            return None
+
     def _fused_dynamic_scan(self, wm_params, embedded_obs, actions, is_first, key):
         """Fused-path dispatch; None means fall back to the flax scan."""
         from sheeprl_tpu.ops.pallas import rssm_step as _fk
 
-        try:
-            spec = self._fused_spec(embedded_obs.shape[-1], actions.shape[-1])
-            impl = _fk.select_impl(self.kernels, spec, embedded_obs.shape[1])
-            if impl is None:
-                return None
-            p = _fk.extract_step_params(wm_params, self.stoch_state_size)
-        except _fk.KernelUnsupported:
+        fused = self._fused_step_params(
+            wm_params, embedded_obs.shape[-1], actions.shape[-1], embedded_obs.shape[1]
+        )
+        if fused is None:
             return None
+        p, spec = fused
         return _fk.fused_dynamic_scan(
             p,
-            spec.with_impl(impl),
+            spec,
             wm_params["initial_recurrent_state"],
             embedded_obs,
             actions,
@@ -828,16 +846,10 @@ class RSSM:
         if self.kernels != "off" and not self.decoupled:
             from sheeprl_tpu.ops.pallas import rssm_step as _fk
 
-            try:
-                spec = self._fused_spec(0, actions.shape[-1])
-                impl = _fk.select_impl(self.kernels, spec, recurrent_state.shape[0])
-                if impl is not None:
-                    p = _fk.extract_step_params(wm_params, self.stoch_state_size)
-                    return _fk.fused_imagination_step(
-                        p, spec.with_impl(impl), prior_flat, recurrent_state, actions, key
-                    )
-            except _fk.KernelUnsupported:
-                pass
+            fused = self._fused_step_params(wm_params, 0, actions.shape[-1], recurrent_state.shape[0])
+            if fused is not None:
+                p, spec = fused
+                return _fk.fused_imagination_step(p, spec, prior_flat, recurrent_state, actions, key)
         recurrent_state = self._recurrent(wm_params, prior_flat, actions, recurrent_state)
         _, imagined_prior = self._transition(wm_params, recurrent_state, key)
         return imagined_prior.reshape(*prior_flat.shape), recurrent_state

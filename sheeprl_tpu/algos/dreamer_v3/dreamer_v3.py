@@ -746,7 +746,8 @@ def main(runtime, cfg: Dict[str, Any]):
                         "State/moments_low",
                         "State/moments_high",
                         "Resilience/nonfinite_skips",
-                    )
+                    ),
+                    sharding=runtime.replicated,
                 ),
                 name="metric.drain",
             )

@@ -509,7 +509,8 @@ def main(runtime, cfg: Dict[str, Any]):
                         "Loss/entropy_loss",
                         "Resilience/nonfinite_skips",
                         "Grads/global_norm",
-                    )
+                    ),
+                    sharding=runtime.replicated,
                 ),
                 name="metric.drain",
             )
@@ -559,7 +560,8 @@ def main(runtime, cfg: Dict[str, Any]):
                         "Loss/entropy_loss",
                         "Resilience/nonfinite_skips",
                         "Grads/global_norm",
-                    )
+                    ),
+                    sharding=runtime.replicated,
                 ),
                 name="metric.drain",
             )

@@ -482,7 +482,8 @@ def _main_ingraph(runtime, cfg: Dict[str, Any]):
         if aggregator is not None:
             warmup.add_task(
                 lambda: aggregator.precompile_drain(
-                    ("Loss/value_loss", "Loss/policy_loss", "Loss/alpha_loss")
+                    ("Loss/value_loss", "Loss/policy_loss", "Loss/alpha_loss"),
+                    sharding=runtime.replicated,
                 )
             )
         warmup.start()

@@ -82,7 +82,7 @@ JIT_ENTRY_WRAPPERS: Tuple[str, ...] = (
 # --------------------------------------------------------------------------- #
 
 _DEFAULTS: Dict[str, Dict[str, Any]] = {
-    "cache": {"dir": None, "min_compile_time_secs": None},
+    "cache": {"min_compile_time_secs": None},
     "aot": {"enabled": True},
     "guard": {"policy": "warn"},
 }
@@ -123,6 +123,13 @@ def resolve(cfg: Any) -> _View:
             for k in defaults:
                 v = got.get(k, defaults[k]) if hasattr(got, "get") else getattr(got, k, defaults[k])
                 merged[section][k] = v
+    cache_group = group.get("cache") if group is not None and hasattr(group, "get") else None
+    if cache_group is not None and cache_group.get("dir"):
+        raise ValueError(
+            "compile.cache.dir is gone: the persistent compile cache lives where "
+            "JAX_COMPILATION_CACHE_DIR says, else at sheeprl_tpu.COMPILE_CACHE_DIR "
+            f"(got compile.cache.dir={cache_group.get('dir')!r})"
+        )
     policy = str(merged["guard"]["policy"]).lower()
     if policy not in _POLICIES:
         raise ValueError(f"compile.guard.policy must be one of {_POLICIES}; got {policy!r}")
@@ -144,6 +151,9 @@ _REGISTRY: List["GuardedFn"] = []
 _STEADY = False
 _GUARD_POLICY = "warn"
 _CACHE_COUNTS = {"cache_hits": 0, "cache_misses": 0}
+# AOT warmup jobs that raised (process total): the run survives them by
+# compiling on first call, which is exactly what a smoke must be able to see
+_WARMUP_ERRORS = 0
 _LISTENER_INSTALLED = False
 # snapshot of process totals at the last drain_compile_counters() call
 _DRAINED: Dict[str, float] = {}
@@ -170,16 +180,14 @@ def install_cache_listeners() -> None:
         if _LISTENER_INSTALLED:
             return
         _LISTENER_INSTALLED = True
-    try:
-        def _listener(event: str, **kwargs) -> None:
-            key = _CACHE_EVENTS.get(event)
-            if key is not None:
-                with _LOCK:
-                    _CACHE_COUNTS[key] += 1
 
-        jax.monitoring.register_event_listener(_listener)
-    except Exception:  # pragma: no cover - monitoring API drift
-        pass
+    def _listener(event: str, **kwargs) -> None:
+        key = _CACHE_EVENTS.get(event)
+        if key is not None:
+            with _LOCK:
+                _CACHE_COUNTS[key] += 1
+
+    jax.monitoring.register_event_listener(_listener)
 
 
 def configure(cfg: Any) -> _View:
@@ -187,16 +195,15 @@ def configure(cfg: Any) -> _View:
 
     Sets the retrace policy, clears the steady-state watermark (a fresh run's
     first traces are not retraces of the previous run), applies the
-    persistent-cache knobs to jax.config ONLY when explicitly set (never
-    clobbering the user's/env defaults — that is the whole point of the group),
-    and installs the cache-stats listeners.
+    persist threshold to jax.config ONLY when explicitly set, and installs the
+    cache-stats listeners. The cache DIRECTORY is never set here: it is
+    ``JAX_COMPILATION_CACHE_DIR`` or the fixed path ``sheeprl_tpu/__init__.py``
+    chose at import.
     """
     cc = resolve(cfg)
     global _GUARD_POLICY, _STEADY
     _GUARD_POLICY = cc.guard.policy
     _STEADY = False
-    if cc.cache.dir:
-        jax.config.update("jax_compilation_cache_dir", str(cc.cache.dir))
     if cc.cache.min_compile_time_secs is not None:
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", float(cc.cache.min_compile_time_secs)
@@ -240,9 +247,9 @@ def _leaf_sig(x: Any) -> Tuple:
 
 def abstract_signature(args: Tuple, kwargs: Dict[str, Any]) -> Tuple:
     """Hashable abstract call signature: pytree structure + per-leaf
-    (shape, dtype, weak_type). Shardings are deliberately excluded — the AOT
-    executables accept any input placement (XLA reshards), so routing on them
-    would only cause spurious fallbacks."""
+    (shape, dtype, weak_type). Shardings are not part of it: the warmup specs
+    carry the placement the call will use (:func:`spec_like`), and a committed
+    argument that disagrees with it is a counted fallback in ``__call__``."""
     leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
     return (tuple(_leaf_sig(leaf) for leaf in leaves), treedef)
 
@@ -273,22 +280,14 @@ def signature_diff(old: Optional[Tuple], new: Tuple) -> str:
 
 
 def spec_like(x: Any) -> Any:
-    """``jax.ShapeDtypeStruct`` mirroring one concrete array (shape, dtype and —
-    for multi-device arrays — sharding, so AOT compiles for the real placement).
-
-    Single-device shardings are deliberately dropped: mixing a device-committed
-    single-device spec with multi-device param specs makes ``.lower()`` reject
-    the computation as using incompatible devices, and baking "committed to
-    device 0" into the executable makes call-time placement stricter than the
-    jit path. Shape/dtype alone reproduces the jit behaviour there.
+    """``jax.ShapeDtypeStruct`` mirroring one concrete array: shape, dtype and,
+    for a COMMITTED array, its sharding, so AOT compiles for the placement the
+    call will use. That includes a single device that is not the default one
+    (the host-CPU player on a TPU host): a compiled executable rejects committed
+    arguments placed anywhere else. Uncommitted arrays and host values carry no
+    sharding, exactly as ``jit`` treats them: they follow the committed operands.
     """
-    sharding = None
-    if isinstance(x, jax.Array):
-        try:
-            if len(x.sharding.device_set) > 1:
-                sharding = x.sharding
-        except Exception:
-            sharding = None
+    sharding = x.sharding if isinstance(x, jax.Array) and x.committed else None
     return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype, sharding=sharding)
 
 
@@ -435,6 +434,13 @@ class GuardedFn:
         with _LOCK:
             return _routing_key(sig) in self._aot
 
+    def aot_executables(self) -> List[Any]:
+        """The live AOT executables (``jax.stages.Compiled``): what a smoke
+        reads to see WHERE the program runs (``input_shardings``, ``args_info``)
+        — a dispatch through one of these cannot have run anywhere else."""
+        with _LOCK:
+            return list(self._aot.values())
+
     # ----- call path ------------------------------------------------------------
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
         self.calls += 1
@@ -462,13 +468,14 @@ class GuardedFn:
                         self.first_call_s = time.perf_counter() - _T0
                     return out
                 except (TypeError, ValueError) as e:
-                    # input mismatch against the compiled executable: the
-                    # signature models shape/dtype only, so committed-ness or
-                    # sharding/layout differences land here. The jitted path
-                    # below is always correct; evict the executable so later
-                    # calls with this signature skip the failing dispatch
-                    if isinstance(e, ValueError) and "does not match" not in str(e):
-                        raise
+                    # the compiled executable validates its inputs BEFORE it
+                    # runs (nothing executed, nothing donated), and that is the
+                    # only place a dispatch raises these types: the signature
+                    # models shape/dtype only, so a placement or layout the
+                    # specs did not carry lands here. The jitted path below
+                    # either serves the call or raises the real error; evict
+                    # the executable so later calls skip the failing dispatch.
+                    # Counted: a smoke asserts aot_fallbacks == 0.
                     self.aot_fallbacks += 1
                     with _LOCK:
                         self._aot.pop(key, None)
@@ -606,11 +613,30 @@ def find(name: str) -> Optional[GuardedFn]:
     return None
 
 
+def release_executables() -> None:
+    """Drop every compiled executable this process pins: each guarded
+    function's AOT registry (this module keeps every instance alive for its
+    counters) and, through ``jax.clear_caches``, the jit caches. Counters stay;
+    a later call compiles again as a first trace, cheaply through the
+    persistent cache. For a long-lived process that runs many short runs (the
+    test suite): XLA:CPU mmaps each executable, and thousands of them cross
+    ``vm.max_map_count``."""
+    with _LOCK:
+        for gfn in _REGISTRY:
+            gfn._aot.clear()
+            gfn._aot_flops.clear()
+            gfn._had_any_compile = False
+            gfn.last_signature = None
+    jax.clear_caches()
+
+
 def process_stats() -> Dict[str, Any]:
-    """Totals across every guarded function plus persistent-cache counters."""
+    """Totals across every guarded function plus the persistent-cache counters
+    and the count of AOT warmup jobs that raised."""
     with _LOCK:
         fns = list(_REGISTRY)
         cache = dict(_CACHE_COUNTS)
+        warmup_errors = _WARMUP_ERRORS
     totals = {
         "calls": 0,
         "traces": 0,
@@ -627,6 +653,7 @@ def process_stats() -> Dict[str, Any]:
         for k in totals:
             totals[k] += s[k]
     totals.update(cache)
+    totals["warmup_errors"] = warmup_errors
     totals["functions"] = per_fn
     return totals
 
@@ -704,6 +731,7 @@ class AOTWarmup:
         return self
 
     def _run(self) -> None:
+        global _WARMUP_ERRORS
         for gfn, specs, kwspecs, ev in self._jobs:
             try:
                 if gfn is None:
@@ -714,6 +742,8 @@ class AOTWarmup:
             except Exception as e:  # warmup must never kill the run
                 name = specs[1] if gfn is None else gfn.name
                 self.errors.append((name, e))
+                with _LOCK:
+                    _WARMUP_ERRORS += 1
                 _logger.warning("[compile] AOT warmup of '%s' failed (%s: %s); falling back "
                                 "to JIT on first call", name, type(e).__name__, e)
             finally:

@@ -15,8 +15,8 @@ loop uses:
   synchronous step with identical call-site semantics.
 - :class:`PackedObsCodec` replaces the per-key ``device_put`` of ``prepare_obs`` with
   ONE packed ``device_put`` per step (the same byte-packing fusion as
-  ``DeviceRolloutBuffer.add_env``: remote/tunneled transports charge a fixed O(10ms)
-  per transfer), unpacked and normalized IN-GRAPH inside the jitted act function.
+  ``DeviceRolloutBuffer.add_env``: every transfer carries a fixed cost),
+  unpacked and normalized IN-GRAPH inside the jitted act function.
   uint8 pixel stacks travel as raw bytes (4x smaller than the float path) and become
   centered floats on device. The codec can piggyback extra float leaves (rewards /
   dones of the previous step) on the same transfer, so a steady-state pipelined

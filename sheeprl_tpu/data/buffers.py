@@ -67,7 +67,7 @@ def get_array(
     if dtype is not None:
         array = np.asarray(array, dtype=dtype)
     # Sharded host->device puts run through jax's batched_device_put, which blocks
-    # until the copy lands — a full round-trip per call on remote/tunneled backends.
+    # until the copy lands — a synchronous host<->device round trip per call.
     # A 1-device mesh's NamedSharding is equivalent to its single device, and a
     # plain-device put is fully asynchronous: unwrap so transfers overlap compute.
     if isinstance(device, jax.sharding.Sharding):

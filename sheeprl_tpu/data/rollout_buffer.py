@@ -12,8 +12,8 @@ the rollout stays resident on the player device:
   player step's device outputs — they never touch the host (:meth:`add_policy`);
 - env products (``obs``, ``rewards``, ``dones``): serialized host-side into ONE
   packed ``jax.device_put`` per step (the same 8-put -> 1-transfer fusion as
-  ``device_buffer.py``: remote/tunneled transports charge a fixed O(10ms) per
-  transfer) and unpacked + scattered in-graph (:meth:`add_env`);
+  ``device_buffer.py``: every transfer carries a fixed cost) and unpacked +
+  scattered in-graph (:meth:`add_env`);
 - at iteration end :meth:`rollout` hands the completed ``[T, B, *]`` arrays to
   the jitted train fn with zero bulk host->device transfer. Under the decoupled
   runtime the storage lives on the player CHIP, so the handoff is a direct
@@ -232,7 +232,7 @@ class DeviceRolloutBuffer:
 
         All leaves ride ONE ``jax.device_put`` of a packed uint8 buffer (index
         included), decoded and scattered by a donated jit — the fixed per-transfer
-        cost of remote/tunneled transports is paid once per step, not per key.
+        cost is paid once per step, not per key.
         """
         self._check_open_row()
         self._ensure(data)

@@ -26,6 +26,7 @@ values come back as ``[T, B]`` metrics leaves, pulled (and iterated with
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Iterator, Tuple
 
 import jax
@@ -86,16 +87,16 @@ class InGraphRolloutCollector:
                 return env_actions
             return env_actions[:, 0]
 
-        def one_step(carry: Carry, _):
+        def one_step(policy_params, step, carry: Carry, _):
             obs = carry.obs
             cat_actions, env_actions, logp, values, key = act_impl(
-                policy_params_ref[0], {obs_key: obs}, carry.key
+                policy_params, {obs_key: obs}, carry.key
             )
             key, sub = jax.random.split(key)
             # batch size from the traced obs, NOT the closed-over venv.num_envs:
             # under shard_map the same trace runs on the [B/n_shards] local block
             step_keys = jax.random.split(sub, obs.shape[0])
-            state, next_obs, reward, done, info = jax.vmap(step_ref[0])(
+            state, next_obs, reward, done, info = jax.vmap(step)(
                 step_keys, carry.state, to_env_action(env_actions)
             )
             reward = reward.astype(jnp.float32)
@@ -125,23 +126,21 @@ class InGraphRolloutCollector:
             aux = (info["terminal_obs"], info["truncated"].astype(jnp.float32))
             return new_carry, (out, step_metrics, aux)
 
-        # _act_impl closes over params positionally; a one-slot list lets the
-        # scan body read the traced params without re-deriving the closure.
-        # step_ref works the same way for the env step: the population trainer
-        # passes traced per-member EnvParams overrides (domain randomization)
-        # and the scan body must see the override-closed step at trace time.
-        policy_params_ref = [None]
-        step_ref = [base_step]
-
         def collect(policy_params, carry: Carry, env_overrides=None):
-            policy_params_ref[0] = policy_params
-            step_ref[0] = (
+            # the population trainer passes traced per-member EnvParams
+            # overrides (domain randomization): the scan body must see the
+            # override-closed step at trace time
+            step = (
                 base_step
                 if env_overrides is None
                 else autoreset_step(env, params.replace(**dict(env_overrides)))
             )
+            # A FRESH body per trace, with the traced params bound to it: scan
+            # caches the traced body by function identity, so one shared body
+            # reading the params through a side slot replays the FIRST trace's
+            # tracers on a retrace (UnexpectedTracerError under shard_map).
             carry, (data, metrics, aux) = jax.lax.scan(
-                one_step, carry, None, length=self.rollout_steps
+                functools.partial(one_step, policy_params, step), carry, None, length=self.rollout_steps
             )
             # truncation bootstrap, in-graph (host path: ppo.py final_obs branch)
             # — computed as ONE batched [T*B] critic call after the scan instead

@@ -5,16 +5,19 @@ replay-buffer sequence gather that feeds every Dreamer gradient step (SURVEY
 hot loop #4, reference buffers.py:467-526) — is C++ compiled on first use with
 the toolchain baked into the image (no pybind11: plain ``extern "C"`` + ctypes).
 
-The shared object is cached under ``~/.cache/sheeprl_tpu_native/`` keyed by a
-source hash, so rebuilds happen only when the source changes. Opt out entirely
-with ``SHEEPRL_TPU_NO_NATIVE=1`` (pure-numpy fallbacks are always available and
-tested for parity).
+The shared object is built from the committed ``seq_gather.cpp`` and nothing
+else, into ``native/_build/`` inside the checkout (``SHEEPRL_TPU_NATIVE_CACHE``
+moves it), keyed by a source hash, so rebuilds happen only when the source
+changes. Opt out entirely with ``SHEEPRL_TPU_NO_NATIVE=1``. The pure-numpy
+gather stays as the tested reference and takes over when the build fails —
+which is logged once, at warning level, with the compiler's stderr.
 """
 
 from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -22,7 +25,9 @@ from typing import Optional
 
 import numpy as np
 
-_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "seq_gather.cpp")
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_SRC = os.path.join(_HERE, "seq_gather.cpp")
+_logger = logging.getLogger("sheeprl_tpu.native")
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
@@ -46,10 +51,7 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         except Exception:
             target = b""
         digest = hashlib.sha256(src_bytes + platform.machine().encode() + target).hexdigest()[:16]
-        cache_dir = os.environ.get(
-            "SHEEPRL_TPU_NATIVE_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "sheeprl_tpu_native"),
-        )
+        cache_dir = os.environ.get("SHEEPRL_TPU_NATIVE_CACHE", os.path.join(_HERE, "_build"))
         os.makedirs(cache_dir, exist_ok=True)
         so_path = os.path.join(cache_dir, f"seq_gather_{digest}.so")
         if not os.path.exists(so_path):
@@ -76,7 +78,14 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
             ctypes.c_int32,  # n_threads
         ]
         return lib
-    except Exception:  # pragma: no cover - toolchain missing / build failure
+    except Exception as e:  # toolchain missing / build failure: numpy gather takes over
+        stderr = getattr(e, "stderr", None)  # CalledProcessError carries the compiler's output
+        _logger.warning(
+            "native seq_gather unavailable (%s: %s); falling back to the numpy gather%s",
+            type(e).__name__,
+            e,
+            "\n" + stderr.decode(errors="replace") if stderr else "",
+        )
         return None
 
 

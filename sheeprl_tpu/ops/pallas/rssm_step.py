@@ -10,13 +10,13 @@ between those stages.
 
 Three implementations of the SAME math (``RSSMStepSpec.impl``):
 
-- ``pallas``    — the real TPU kernel (whole step in VMEM, one grid cell;
-  gated by :func:`step_vmem_bytes` so oversized presets degrade instead of
-  OOMing the core);
+- ``pallas``    — the TPU kernel (whole step in VMEM, one grid cell; gated by
+  :func:`step_vmem_bytes`). Mosaic refuses it today (:data:`_MOSAIC_REFUSAL`):
+  forcing it fails the compile with that message;
 - ``interpret`` — the same kernel through the Pallas interpreter, runnable on
   CPU: the bit-parity harness (``tests/test_ops/test_pallas_rssm.py``);
-- ``reference`` — the fused formulation as plain jnp (what ``auto`` uses off
-  TPU). Identical op sequence, so interpret-vs-reference parity is bitwise.
+- ``reference`` — the fused formulation as plain jnp (what ``auto`` uses).
+  Identical op sequence, so interpret-vs-reference parity is bitwise.
 
 The backward is a hand-written ``custom_vjp`` whose residuals are the step
 *inputs only* (carries + scanned xs — arrays the scan materializes anyway);
@@ -49,11 +49,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+_logger = logging.getLogger("sheeprl_tpu.kernels")
 
 __all__ = [
     "KernelUnsupported",
@@ -61,14 +64,16 @@ __all__ = [
     "extract_step_params",
     "fused_dynamic_scan",
     "fused_imagination_step",
+    "log_choice_once",
     "select_impl",
     "step_vmem_bytes",
 ]
 
 
 class KernelUnsupported(Exception):
-    """The RSSM config/params don't match the fused-step contract; callers fall
-    back to the flax scan (never crash the train step over a kernel gap)."""
+    """The RSSM config/params don't match the fused-step contract. Under
+    ``kernels=auto`` callers log it once and use the flax scan; under a named
+    implementation it propagates."""
 
 
 #: fixed parameter ordering — the pallas kernels take these positionally.
@@ -79,11 +84,20 @@ PARAM_KEYS = (
     "wr_h", "wr_e", "ln_r_scale", "ln_r_bias", "wr_head", "br_head",
 )
 
-#: VMEM budget for the single-grid-cell kernel; beyond it ``auto``/``pallas``
-#: degrade to the reference formulation (v5e cores carry 128 MiB of VMEM, keep
-#: headroom for the compiler's own scratch).
+#: VMEM budget for the single-grid-cell kernel: handed to Mosaic as
+#: ``vmem_limit_bytes`` (its default scoped limit is ~16 MiB) and checked by the
+#: dispatch gate; a forced ``pallas`` beyond it raises. NOT established on a
+#: chip: Mosaic refuses the kernel before it allocates anything (PR 22).
 _VMEM_BUDGET_ENV = "SHEEPRL_TPU_KERNEL_VMEM_BUDGET"
 _VMEM_BUDGET_DEFAULT = 96 * 1024 * 1024
+
+#: Why ``auto`` does not pick the Pallas kernel on a TPU: the compiler's own
+#: words from the PR 22 chip run (TPU v5 lite, jax 0.9.0, libtpu 0.0.34) at the
+#: DV3-S recipe shapes, after the API renames were repaired.
+_MOSAIC_REFUSAL = (
+    "Mosaic failed to compile TPU kernel: infer-vector-layout: unsupported shape cast "
+    '("tpu.reshape"(...) : (vector<16x1024xf32>) -> vector<16x32x32xf32>)'
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,6 +215,26 @@ def extract_step_params(wm_params: Dict[str, Any], stoch_flat: int) -> Dict[str,
 # --------------------------------------------------------------------------- #
 
 
+def _mm(x_c: jax.Array, w_c: jax.Array) -> jax.Array:
+    """Compute-dtype matmul with an explicit f32 accumulator, rounded back to
+    the compute dtype. Mosaic rejects a bf16 accumulator ("Expected matmul acc
+    to be 32-bit"); XLA's own TPU bf16 dot accumulates in f32 as well, so this
+    is the same arithmetic spelled out. A no-op change under f32 compute."""
+    return jnp.dot(x_c, w_c, preferred_element_type=jnp.float32).astype(x_c.dtype)
+
+
+def _sigmoid(x_c: jax.Array) -> jax.Array:
+    """Logistic through an f32 island: the Pallas TPU lowering of
+    ``lax.logistic`` on bf16 fails verification in JAX 0.9.0 (an f32 scalar
+    broadcast into a bf16 vector), and v5e has no bf16 VPU, so XLA widens the
+    op the same way. A no-op change under f32 compute."""
+    return jax.nn.sigmoid(x_c.astype(jnp.float32)).astype(x_c.dtype)
+
+
+def _silu(x_c: jax.Array) -> jax.Array:
+    return x_c * _sigmoid(x_c)
+
+
 def _ln_f32(x_c: jax.Array, scale: jax.Array, bias: jax.Array, eps: float):
     """f32-island LayerNorm (stats in f32, like models.LayerNorm / the GRU cell).
     Returns (y32, xhat, inv) — xhat/inv feed the hand-written vjp."""
@@ -297,34 +331,34 @@ def _dyn_math(
     z0 = (1.0 - f_c) * z.astype(c) + f_c * init_z.astype(c)
 
     # input projection (RecurrentModel MLP, activation=None, no bias)
-    t0 = z0 @ p["wi_z"].astype(c) + a_m @ p["wi_a"].astype(c)
+    t0 = _mm(z0, p["wi_z"].astype(c)) + _mm(a_m, p["wi_a"].astype(c))
     t_ln32, xhat1, inv1 = _ln_f32(t0, p["ln_i_scale"], p["ln_i_bias"], spec.eps_in)
     feat = t_ln32.astype(c)
 
     # Hafner GRU: fused projection -> f32 LN -> (reset, cand, update)
-    u0 = h0 @ p["wg_h"].astype(c) + feat @ p["wg_f"].astype(c)
+    u0 = _mm(h0, p["wg_h"].astype(c)) + _mm(feat, p["wg_f"].astype(c))
     g_ln32, xhat2, inv2 = _ln_f32(u0, p["ln_g_scale"], p["ln_g_bias"], spec.eps_gru)
     gates = g_ln32.astype(c)
     r_pre, c_pre, u_pre = jnp.split(gates, 3, axis=-1)
-    r = jax.nn.sigmoid(r_pre)
+    r = _sigmoid(r_pre)
     cand = jnp.tanh(r * c_pre)
-    u = jax.nn.sigmoid(u_pre - 1.0)
+    u = _sigmoid(u_pre - 1.0)
     h_new = u * cand + (1.0 - u) * h0
 
     # prior head (transition): trunk -> f32 unimix logits
-    pt0 = h_new @ p["wt"].astype(c)
+    pt0 = _mm(h_new, p["wt"].astype(c))
     p_ln32, xhat3, inv3 = _ln_f32(pt0, p["ln_t_scale"], p["ln_t_bias"], spec.eps_trans)
     p_ln = p_ln32.astype(c)
-    pact = jax.nn.silu(p_ln)
-    prior_raw = pact @ p["wt_head"].astype(c) + p["bt_head"].astype(c)
+    pact = _silu(p_ln)
+    prior_raw = _mm(pact, p["wt_head"].astype(c)) + p["bt_head"].astype(c)
     prior_logits, q_prior, qm_prior = _unimix_logits(prior_raw, spec)
 
     # posterior head (representation) + straight-through sample
-    q0 = h_new @ p["wr_h"].astype(c) + e.astype(c) @ p["wr_e"].astype(c)
+    q0 = _mm(h_new, p["wr_h"].astype(c)) + _mm(e.astype(c), p["wr_e"].astype(c))
     q_ln32, xhat4, inv4 = _ln_f32(q0, p["ln_r_scale"], p["ln_r_bias"], spec.eps_repr)
     q_ln = q_ln32.astype(c)
-    qact = jax.nn.silu(q_ln)
-    post_raw = qact @ p["wr_head"].astype(c) + p["br_head"].astype(c)
+    qact = _silu(q_ln)
+    post_raw = _mm(qact, p["wr_head"].astype(c)) + p["br_head"].astype(c)
     post_logits, q_post, qm_post = _unimix_logits(post_raw, spec)
     z_new = _st_onehot(post_logits, g, c).reshape(h.shape[0], spec.stoch_flat)
 
@@ -354,23 +388,23 @@ def _imag_math(
     representation branch — the actor interleaves between steps, so only the
     single step fuses, not the whole horizon scan)."""
     c = spec.compute_dtype
-    t0 = z.astype(c) @ p["wi_z"].astype(c) + a.astype(c) @ p["wi_a"].astype(c)
+    t0 = _mm(z.astype(c), p["wi_z"].astype(c)) + _mm(a.astype(c), p["wi_a"].astype(c))
     t_ln32, xhat1, inv1 = _ln_f32(t0, p["ln_i_scale"], p["ln_i_bias"], spec.eps_in)
     feat = t_ln32.astype(c)
     h_c = h.astype(c)
-    u0 = h_c @ p["wg_h"].astype(c) + feat @ p["wg_f"].astype(c)
+    u0 = _mm(h_c, p["wg_h"].astype(c)) + _mm(feat, p["wg_f"].astype(c))
     g_ln32, xhat2, inv2 = _ln_f32(u0, p["ln_g_scale"], p["ln_g_bias"], spec.eps_gru)
     gates = g_ln32.astype(c)
     r_pre, c_pre, u_pre = jnp.split(gates, 3, axis=-1)
-    r = jax.nn.sigmoid(r_pre)
+    r = _sigmoid(r_pre)
     cand = jnp.tanh(r * c_pre)
-    u = jax.nn.sigmoid(u_pre - 1.0)
+    u = _sigmoid(u_pre - 1.0)
     h_new = u * cand + (1.0 - u) * h_c
-    pt0 = h_new @ p["wt"].astype(c)
+    pt0 = _mm(h_new, p["wt"].astype(c))
     p_ln32, xhat3, inv3 = _ln_f32(pt0, p["ln_t_scale"], p["ln_t_bias"], spec.eps_trans)
     p_ln = p_ln32.astype(c)
-    pact = jax.nn.silu(p_ln)
-    prior_raw = pact @ p["wt_head"].astype(c) + p["bt_head"].astype(c)
+    pact = _silu(p_ln)
+    prior_raw = _mm(pact, p["wt_head"].astype(c)) + p["bt_head"].astype(c)
     prior_logits, q_prior, qm_prior = _unimix_logits(prior_raw, spec)
     z_new = _st_onehot(prior_logits, g, c).reshape(h.shape[0], spec.stoch_flat)
     outs = (h_new, z_new)
@@ -414,12 +448,14 @@ def _imag_kernel(spec: RSSMStepSpec, *refs):
 
 
 @functools.lru_cache(maxsize=None)
-def _compiler_params():
+def _compiler_params(vmem_limit_bytes: int):
     """TPU compiler params, built lazily (the tpu submodule import is free on
-    CPU but kept out of module import for belt-and-braces)."""
+    CPU but kept out of module import for belt-and-braces). The kernel is one
+    grid cell with whole arrays resident, so Mosaic's default scoped-VMEM limit
+    is raised to the budget the dispatch gate admitted the step under."""
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _pallas_dyn_call(spec: RSSMStepSpec, p, init_h, init_z, h, z, a, e, f, g):
@@ -438,7 +474,7 @@ def _pallas_dyn_call(spec: RSSMStepSpec, p, init_h, init_z, h, z, a, e, f, g):
     # compiler params
     kwargs: Dict[str, Any] = {"interpret": spec.impl == "interpret"}
     if spec.impl != "interpret":
-        kwargs["compiler_params"] = _compiler_params()
+        kwargs["compiler_params"] = _compiler_params(_vmem_budget())
     call = pl.pallas_call(
         functools.partial(_dyn_kernel, spec),
         out_shape=out_shape,
@@ -458,7 +494,7 @@ def _pallas_imag_call(spec: RSSMStepSpec, p, h, z, a, g):
     )
     kwargs: Dict[str, Any] = {"interpret": spec.impl == "interpret"}
     if spec.impl != "interpret":
-        kwargs["compiler_params"] = _compiler_params()
+        kwargs["compiler_params"] = _compiler_params(_vmem_budget())
     call = pl.pallas_call(
         functools.partial(_imag_kernel, spec),
         out_shape=out_shape,
@@ -765,9 +801,10 @@ def fused_imagination_step(
 
 
 def step_vmem_bytes(spec: RSSMStepSpec, batch: int) -> int:
-    """Upper-bound VMEM footprint of one fused dynamic step: every parameter in
-    the compute dtype plus the activation set, resident at once (the kernel is
-    a single grid cell — that's the fusion's whole point)."""
+    """Upper-bound VMEM footprint of one fused dynamic step: every parameter as
+    stored (always f32) plus its compute-dtype cast, plus the activation set,
+    resident at once (the kernel is a single grid cell — that's the fusion's
+    whole point)."""
     c_bytes = jnp.dtype(spec.dtype).itemsize
     sd = spec.stoch_flat
     param_elems = (
@@ -790,15 +827,18 @@ def step_vmem_bytes(spec: RSSMStepSpec, batch: int) -> int:
         + spec.repr_hidden * 2
         + 2 * sd                     # both logits
     )
+    param_bytes = 4 + (c_bytes if c_bytes != 4 else 0)  # f32 storage + the cast copy
     # LN statistics and the f32 islands run at 4 bytes regardless of c
-    return param_elems * c_bytes + act_elems * max(c_bytes, 4)
+    return param_elems * param_bytes + act_elems * max(c_bytes, 4)
 
 
 def _vmem_budget() -> int:
-    try:
-        return int(os.environ.get(_VMEM_BUDGET_ENV, _VMEM_BUDGET_DEFAULT))
-    except ValueError:
-        return _VMEM_BUDGET_DEFAULT
+    return int(os.environ.get(_VMEM_BUDGET_ENV, _VMEM_BUDGET_DEFAULT))
+
+
+@functools.lru_cache(maxsize=None)
+def log_choice_once(kernels: str, impl: str, why: str) -> None:
+    _logger.warning("[kernels] world_model.kernels=%s -> %s: %s", kernels, impl, why)
 
 
 def select_impl(
@@ -808,14 +848,19 @@ def select_impl(
     platform: Optional[str] = None,
 ) -> Optional[str]:
     """Resolve the ``world_model.kernels`` knob to an implementation, or None
-    for the flax fallback.
+    for the flax scan.
 
-    ``off`` -> None. ``auto`` -> ``pallas`` on TPU when the step fits the VMEM
-    budget, else the fused ``reference`` formulation (same math + custom_vjp,
-    plain XLA — still removes the autodiff residual traffic). Forcing
-    ``pallas`` on an oversized step degrades to ``reference`` rather than
-    crashing the train fn. The ``train.kernel_dispatch`` failpoint forces the
-    flax fallback — the degradation drill for SA005-registered chaos runs.
+    ``off`` -> None. A NAMED implementation (``pallas``/``interpret``/
+    ``reference``) is what runs or the call raises: a forced ``pallas`` step
+    beyond the VMEM budget is a ``ValueError``, and a kernel the compiler
+    refuses fails the train step's compile with the compiler's own message —
+    never another implementation in silence. ``auto`` picks the fused
+    ``reference`` formulation (same math + custom_vjp, plain XLA — still
+    removes the autodiff residual traffic) and logs once why: off TPU there is
+    no Mosaic, and on TPU Mosaic refuses the kernel (:data:`_MOSAIC_REFUSAL`;
+    ROADMAP Speed 5 judges whether it is redesigned or deleted). The
+    ``train.kernel_dispatch`` failpoint forces the flax scan — the degradation
+    drill for SA005-registered chaos runs.
     """
     kernels = str(kernels).lower()
     if kernels in ("off", "false", "0", "none"):
@@ -828,17 +873,22 @@ def select_impl(
 
     if failpoints.failpoint("train.kernel_dispatch", requested=kernels, batch=batch):
         return None
-    if platform is None:
-        try:
-            platform = jax.default_backend()
-        except Exception:
-            platform = "cpu"
     if kernels == "auto":
-        if platform == "tpu" and step_vmem_bytes(spec, batch) <= _vmem_budget():
-            return "pallas"
+        platform = platform or jax.default_backend()
+        why = (
+            f"the compiler refuses the Pallas kernel ({_MOSAIC_REFUSAL})"
+            if platform == "tpu"
+            else f"no Mosaic on platform '{platform}'"
+        )
+        log_choice_once(kernels, "reference", why)
         return "reference"
     if kernels in ("on", "pallas"):
-        if step_vmem_bytes(spec, batch) > _vmem_budget():
-            return "reference"
+        need, budget = step_vmem_bytes(spec, batch), _vmem_budget()
+        if need > budget:
+            raise ValueError(
+                f"world_model.kernels={kernels}: the fused step needs ~{need / 2**20:.1f} MiB of "
+                f"VMEM at batch {batch}, over the {budget / 2**20:.1f} MiB budget "
+                f"(${_VMEM_BUDGET_ENV}); use kernels=reference or kernels=off"
+            )
         return "pallas"
     return kernels

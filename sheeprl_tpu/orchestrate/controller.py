@@ -205,9 +205,11 @@ class PopulationController:
         kind = {T.PENDING: "seed", T.RESUMED: "resume", T.RESOWN: "resow"}.get(trial.state, "seed")
         log_path = os.path.join(tdir, f"{run_name}.log")
         log_f = open(log_path, "ab")
+        # JAX_PLATFORMS passes through as the launcher's own environment has it
+        # (unset stays unset): trainees land on the backend the operator chose,
+        # never on a CPU nobody asked for
         env = dict(
             os.environ,
-            JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
             **{
                 READY_FILE_ENV_VAR: self._ready_file(trial.key),
                 FLAG_FILE_ENV_VAR: self._flag_file(trial.key),

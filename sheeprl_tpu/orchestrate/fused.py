@@ -77,9 +77,11 @@ class FusedPopulationController:
         self.incarnations += 1
         log_path = os.path.join(self.state_dir, f"trainee_inc{self.incarnations:02d}.log")
         self._log_f = open(log_path, "ab")
+        # JAX_PLATFORMS passes through as the launcher's own environment has it
+        # (unset stays unset): the trainee land on the backend the operator chose,
+        # never on a CPU nobody asked for
         env = dict(
             os.environ,
-            JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
             **{
                 READY_FILE_ENV_VAR: self._ready_file(),
                 FLAG_FILE_ENV_VAR: self._flag_file(),

@@ -105,26 +105,16 @@ _warned_unavailable = False
 
 
 def coordinator_client():
-    """The coordinator's key-value store client (None if unavailable).
+    """The coordinator's key-value store client (None before
+    ``jax.distributed.initialize``).
 
-    jax only exposes the client at a private path today; probe a public
-    location first so a future jax that promotes it keeps working even if the
-    private module moves (graceful degradation instead of a dead feature on
-    upgrade)."""
-    try:
-        import jax.distributed as jd
+    jax only exposes the client at a private path; it is imported plainly, so a
+    jax that moves it is an ImportError here and not a silently dead feature.
+    The import stays inside the function: launcher children use this module's
+    :class:`SocketKV` without jax."""
+    from jax._src import distributed
 
-        client = getattr(getattr(jd, "global_state", None), "client", None)
-        if client is not None:
-            return client
-    except Exception:  # pragma: no cover - future-API probe only
-        pass
-    try:
-        from jax._src import distributed
-
-        return getattr(distributed.global_state, "client", None)
-    except (ImportError, AttributeError):  # pragma: no cover - private-API drift
-        return None
+    return distributed.global_state.client
 
 
 def require_coordinator_client(what: str, counters: Optional[Dict[str, int]] = None):

@@ -210,9 +210,11 @@ class FleetSupervisor:
             "serve.reload.enabled=false",
             *self.serve_overrides,
         ]
+        # JAX_PLATFORMS passes through as the launcher's own environment has it
+        # (unset stays unset): replicas land on the backend the operator chose,
+        # never on a CPU nobody asked for
         env = dict(
             os.environ,
-            JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
             **{FLAG_FILE_ENV_VAR: handle.flag_file},
         )
         # the supervisor's own drill failpoints must not leak into replicas;

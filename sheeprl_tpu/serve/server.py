@@ -177,7 +177,18 @@ class PolicyServer:
             return
         tmp = f"{ready_file}.tmp"
         with open(tmp, "w") as f:
-            json.dump({"host": self.host, "port": self.port, "pid": os.getpid()}, f)
+            json.dump(
+                {
+                    "host": self.host,
+                    "port": self.port,
+                    "pid": os.getpid(),
+                    # where the policy step runs (fabric.player_on_host decides):
+                    # a supervisor or bench can see a CPU replica for what it is
+                    "policy_device": str(self.engine.runtime.player_device),
+                    "policy_platform": self.engine.runtime.player_device.platform,
+                },
+                f,
+            )
         os.replace(tmp, ready_file)
 
     def serve_until_stopped(self, stats_file: Optional[str] = None, drain_timeout_s: float = 30.0) -> None:

@@ -17,8 +17,9 @@ actually built for it. This module closes that gap: every AOT compile through
 - the **collective audit** — the optimized HLO is scanned for collective ops
   (``all-reduce``/``all-gather``/``reduce-scatter``/…), split into async
   ``*-start``/``*-done`` pairs (overlappable with compute by the latency-hiding
-  scheduler) vs plain sync forms (exposed), with total and exposed bytes and a
-  nominal exposed-time estimate; the ``diff`` CLI flags a collective that
+  scheduler) vs plain sync forms (exposed), with total and exposed bytes (a
+  count; exposed collective TIME comes from a profiler trace on the chip, not
+  from an assumed link rate); the ``diff`` CLI flags a collective that
   de-async'd (async pair -> sync op) or grew its bytes as a regression;
 - compile **wall-time**.
 
@@ -299,13 +300,6 @@ _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8,
     "c128": 16,
 }
-#: Nominal per-link ICI bandwidth used for the *exposed-collective-time
-#: estimate* (v5e-class, ~45 GB/s/direction). A planning number, not a
-#: measurement: it turns exposed (sync, unoverlapped) collective bytes into a
-#: comparable seconds figure across rows.
-_ICI_BYTES_PER_S = 4.5e10
-
-
 def _shape_bytes(segment: str) -> float:
     total = 0.0
     for dtype, dims in _SHAPE_RE.findall(segment):
@@ -359,7 +353,6 @@ def _collective_dict(compiled: Any) -> Optional[Dict[str, Any]]:
         "async_pairs": async_pairs,
         "sync_ops": sync_ops,
         "exposed_bytes": exposed_bytes,
-        "exposed_time_s": exposed_bytes / _ICI_BYTES_PER_S,
         "by_op": by_op,
     }
 

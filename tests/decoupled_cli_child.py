@@ -25,10 +25,6 @@ flags = [f for f in os.environ.get("XLA_FLAGS", "").split() if "host_platform_de
 flags.append("--xla_force_host_platform_device_count=2")
 os.environ["XLA_FLAGS"] = " ".join(flags)
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 

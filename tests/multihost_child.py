@@ -33,8 +33,6 @@ os.environ["XLA_FLAGS"] = " ".join(flags)
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from sheeprl_tpu.core.runtime import enable_cpu_collectives  # noqa: E402

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from sheeprl_tpu.envs.ingraph import CartPole, GridWorld, Pendulum, autoreset_step
 

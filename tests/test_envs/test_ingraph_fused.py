@@ -78,7 +78,10 @@ def _build_stack(cfg, runtime, name: str):
         venv, player, rollout_steps=T, gamma=float(cfg.algo.gamma), name=name
     )
     tx = with_clipping(instantiate(dict(cfg.algo.optimizer))(), cfg.algo.max_grad_norm)
-    opt_state = tx.init(params)
+    # placed like the loop places it (ppo.py): optax's step counter is born
+    # uncommitted on the default device, and an operand that changes placement
+    # between the first and second call is a jit-cache miss, i.e. a retrace
+    opt_state = runtime.place_params(tx.init(params))
     params_sync = PlayerParamsSync(player.params)
     return venv, agent, params, player, collector, tx, opt_state, params_sync
 
