@@ -41,7 +41,7 @@ from sheeprl_tpu.core import resilience
 from sheeprl_tpu.core.pipeline import AsyncEnvStepper, PackedObsCodec, pipeline_enabled
 from sheeprl_tpu.data.factory import make_sequential_replay
 from sheeprl_tpu.envs.wrappers import RestartOnException
-from sheeprl_tpu.telemetry import device as tel_device
+from sheeprl_tpu.telemetry import trace
 from sheeprl_tpu.ops.distributions import (
     BernoulliSafeMode,
     Independent,
@@ -75,6 +75,33 @@ PLAYER_WM_KEYS = (
     "transition_model",
     "initial_recurrent_state",
 )
+
+
+# ``jax.named_scope`` names inside ``dv3.train``: metadata only (the program's
+# outputs do not change), they name every device event of a profiler capture by
+# the model part it belongs to. The first ten are the parts that
+# ``benchmarks/chip/flops.py::dv3_step_flops`` counts, under its names, so a
+# part's FLOPs divide by the part's device time with no mapping table; the rest
+# is work it does not count.
+TRAIN_SCOPES = (
+    "encoder",
+    "dynamic_scan",
+    "decoder",
+    "reward_head",
+    "continue_head",
+    "imagination_rollout",
+    "imagination_actor",
+    "imagination_heads",
+    "critic_update",
+    "target_critic",
+    "world_opt",
+    "actor_opt",
+    "critic_opt",
+    "moments",
+    "target_ema",
+    "player_ravel",
+)
+_scope = jax.named_scope
 
 
 class DV3OptStates(NamedTuple):
@@ -145,9 +172,10 @@ def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, action
                 lambda p, tp: tau_eff * p + (1.0 - tau_eff) * tp, params["critic"], tc
             )
 
-        target_critic = jax.lax.cond(
-            counter % target_freq == 0, do_ema, lambda tc: tc, params["target_critic"]
-        )
+        with _scope("target_ema"):
+            target_critic = jax.lax.cond(
+                counter % target_freq == 0, do_ema, lambda tc: tc, params["target_critic"]
+            )
 
         # ---- batch prep (in-graph: uint8 pixels stay uint8 until HBM)
         # batch_obs stays f32: these are the reconstruction-loss TARGETS (an f32
@@ -167,31 +195,38 @@ def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, action
 
         # ---- world-model update (Eq. 4)
         def world_loss_fn(wm_params):
-            embedded = modules.encoder.apply(wm_params["encoder"], encoder_obs)
-            recurrent_states, posteriors, priors_logits, posteriors_logits = rssm.dynamic_scan(
-                wm_params, embedded, batch_actions, is_first, k_wm
-            )
+            with _scope("encoder"):
+                embedded = modules.encoder.apply(wm_params["encoder"], encoder_obs)
+            with _scope("dynamic_scan"):
+                recurrent_states, posteriors, priors_logits, posteriors_logits = rssm.dynamic_scan(
+                    wm_params, embedded, batch_actions, is_first, k_wm
+                )
             latent_states = jnp.concatenate(
                 [posteriors.reshape(*posteriors.shape[:-2], -1), recurrent_states], axis=-1
             )
-            reconstructed = modules.observation_model.apply(wm_params["observation_model"], latent_states)
-            po_log_probs = {
-                k: MSEDistribution(reconstructed[k], dims=reconstructed[k].ndim - 2).log_prob(batch_obs[k])
-                for k in cnn_keys_dec
-            }
-            po_log_probs.update(
-                {
-                    k: SymlogDistribution(reconstructed[k], dims=reconstructed[k].ndim - 2).log_prob(batch_obs[k])
-                    for k in mlp_keys_dec
+            with _scope("decoder"):
+                reconstructed = modules.observation_model.apply(wm_params["observation_model"], latent_states)
+                po_log_probs = {
+                    k: MSEDistribution(reconstructed[k], dims=reconstructed[k].ndim - 2).log_prob(batch_obs[k])
+                    for k in cnn_keys_dec
                 }
-            )
-            pr = TwoHotEncodingDistribution(
-                modules.reward_model.apply(wm_params["reward_model"], latent_states), dims=1
-            )
-            pc = Independent(
-                BernoulliSafeMode(logits=modules.continue_model.apply(wm_params["continue_model"], latent_states)),
-                1,
-            )
+                po_log_probs.update(
+                    {
+                        k: SymlogDistribution(reconstructed[k], dims=reconstructed[k].ndim - 2).log_prob(batch_obs[k])
+                        for k in mlp_keys_dec
+                    }
+                )
+            with _scope("reward_head"):
+                pr = TwoHotEncodingDistribution(
+                    modules.reward_model.apply(wm_params["reward_model"], latent_states), dims=1
+                )
+            with _scope("continue_head"):
+                pc = Independent(
+                    BernoulliSafeMode(
+                        logits=modules.continue_model.apply(wm_params["continue_model"], latent_states)
+                    ),
+                    1,
+                )
             loss, kl, state_loss, reward_loss, observation_loss, continue_loss = reconstruction_loss(
                 po_log_probs,
                 pr.log_prob(rewards),
@@ -218,16 +253,17 @@ def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, action
             return loss, aux
 
         (world_loss, aux), world_grads = jax.value_and_grad(world_loss_fn, has_aux=True)(params["world_model"])
-        world_grad_norm = optax_global_norm(world_grads)
-        world_updates, world_opt = world_tx.update(world_grads, opt_states.world, params["world_model"])
-        new_wm = apply_updates(params["world_model"], world_updates)
-        if nonfinite_guard:
-            # a skipped world update also feeds the OLD world model to imagination below
-            (new_wm, world_opt), wm_skipped = resilience.finite_or_skip(
-                (world_loss, world_grad_norm), (new_wm, world_opt), (params["world_model"], opt_states.world)
-            )
-        else:
-            wm_skipped = jnp.float32(0.0)
+        with _scope("world_opt"):
+            world_grad_norm = optax_global_norm(world_grads)
+            world_updates, world_opt = world_tx.update(world_grads, opt_states.world, params["world_model"])
+            new_wm = apply_updates(params["world_model"], world_updates)
+            if nonfinite_guard:
+                # a skipped world update also feeds the OLD world model to imagination below
+                (new_wm, world_opt), wm_skipped = resilience.finite_or_skip(
+                    (world_loss, world_grad_norm), (new_wm, world_opt), (params["world_model"], opt_states.world)
+                )
+            else:
+                wm_skipped = jnp.float32(0.0)
 
         # ---- behaviour learning: imagination with the freshly-updated world model
         posteriors = jax.lax.stop_gradient(aux["posteriors"])  # [T, B, S, D]
@@ -240,21 +276,26 @@ def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, action
             """H+1-step differentiable imagination -> (trajectories, clipped actions,
             raw pre-clip samples — the score-function evaluation points)."""
             latent0 = jnp.concatenate([start_prior, start_recurrent], axis=-1)
-            out0 = ActorOutput(modules.actor, modules.actor.apply(actor_params, jax.lax.stop_gradient(latent0)))
-            acts0, raws0 = out0.sample_actions_with_raw(key0)
+            with _scope("imagination_actor"):
+                out0 = ActorOutput(
+                    modules.actor, modules.actor.apply(actor_params, jax.lax.stop_gradient(latent0))
+                )
+                acts0, raws0 = out0.sample_actions_with_raw(key0)
             actions0 = jnp.concatenate(acts0, axis=-1)
             raw0 = jnp.concatenate(raws0, axis=-1)
 
             def step(carry, k):
                 prior_flat, rec_state, act = carry
                 k_img_step, k_act_step = jax.random.split(k)
-                prior, rec_state = rssm.imagination_step(new_wm, prior_flat, rec_state, act, k_img_step)
+                with _scope("imagination_rollout"):
+                    prior, rec_state = rssm.imagination_step(new_wm, prior_flat, rec_state, act, k_img_step)
                 prior_flat = prior.reshape(prior_flat.shape)
                 latent = jnp.concatenate([prior_flat, rec_state], axis=-1)
-                out = ActorOutput(
-                    modules.actor, modules.actor.apply(actor_params, jax.lax.stop_gradient(latent))
-                )
-                new_acts, new_raws = out.sample_actions_with_raw(k_act_step)
+                with _scope("imagination_actor"):
+                    out = ActorOutput(
+                        modules.actor, modules.actor.apply(actor_params, jax.lax.stop_gradient(latent))
+                    )
+                    new_acts, new_raws = out.sample_actions_with_raw(k_act_step)
                 new_act = jnp.concatenate(new_acts, axis=-1)
                 new_raw = jnp.concatenate(new_raws, axis=-1)
                 return (prior_flat, rec_state, new_act), (latent, new_act, new_raw)
@@ -271,36 +312,44 @@ def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, action
 
         def actor_loss_fn(actor_params):
             trajectories, im_actions, im_actions_raw = imagine(actor_params, k_img0, img_keys)
-            predicted_values = TwoHotEncodingDistribution(
-                modules.critic.apply(params["critic"], trajectories), dims=1
-            ).mean
-            predicted_rewards = TwoHotEncodingDistribution(
-                modules.reward_model.apply(new_wm["reward_model"], trajectories), dims=1
-            ).mean
-            continues = Independent(
-                BernoulliSafeMode(logits=modules.continue_model.apply(new_wm["continue_model"], trajectories)), 1
-            ).base.mode
-            continues = jnp.concatenate([true_continue[None], continues[1:]], axis=0)
-            lambda_values = compute_lambda_values(
-                predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda=lmbda
-            )
-            discount = jax.lax.stop_gradient(jnp.cumprod(continues * gamma, axis=0) / gamma)
+            with _scope("imagination_heads"):
+                predicted_values = TwoHotEncodingDistribution(
+                    modules.critic.apply(params["critic"], trajectories), dims=1
+                ).mean
+                predicted_rewards = TwoHotEncodingDistribution(
+                    modules.reward_model.apply(new_wm["reward_model"], trajectories), dims=1
+                ).mean
+                continues = Independent(
+                    BernoulliSafeMode(
+                        logits=modules.continue_model.apply(new_wm["continue_model"], trajectories)
+                    ),
+                    1,
+                ).base.mode
+                continues = jnp.concatenate([true_continue[None], continues[1:]], axis=0)
+                lambda_values = compute_lambda_values(
+                    predicted_rewards[1:], predicted_values[1:], continues[1:] * gamma, lmbda=lmbda
+                )
+                discount = jax.lax.stop_gradient(jnp.cumprod(continues * gamma, axis=0) / gamma)
 
-            offset, invscale, new_moments = update_moments(
-                moments_state,
-                lambda_values,
-                decay=float(moments_cfg.decay),
-                max_=float(moments_cfg.max),
-                percentile_low=float(moments_cfg.percentile.low),
-                percentile_high=float(moments_cfg.percentile.high),
-            )
+            with _scope("moments"):
+                offset, invscale, new_moments = update_moments(
+                    moments_state,
+                    lambda_values,
+                    decay=float(moments_cfg.decay),
+                    max_=float(moments_cfg.max),
+                    percentile_low=float(moments_cfg.percentile.low),
+                    percentile_high=float(moments_cfg.percentile.high),
+                )
             baseline = predicted_values[:-1]
             normed_lambda = (lambda_values - offset) / invscale
             normed_baseline = (baseline - offset) / invscale
             advantage = normed_lambda - normed_baseline
-            policies = ActorOutput(
-                modules.actor, modules.actor.apply(actor_params, jax.lax.stop_gradient(trajectories))
-            )
+            # the actor over the whole imagined trajectory, for the score function:
+            # flops.py counts the actor once, the program applies it here again
+            with _scope("imagination_actor"):
+                policies = ActorOutput(
+                    modules.actor, modules.actor.apply(actor_params, jax.lax.stop_gradient(trajectories))
+                )
             if is_continuous and actor_objective != "reinforce":
                 # reference parity: direct advantage (dynamics backprop) for
                 # continuous actions. The walker_walk forensics measured this
@@ -337,15 +386,16 @@ def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, action
             return policy_loss, aux_a
 
         (policy_loss, aux_a), actor_grads = jax.value_and_grad(actor_loss_fn, has_aux=True)(params["actor"])
-        actor_grad_norm = optax_global_norm(actor_grads)
-        actor_updates, actor_opt = actor_tx.update(actor_grads, opt_states.actor, params["actor"])
-        new_actor = apply_updates(params["actor"], actor_updates)
-        if nonfinite_guard:
-            (new_actor, actor_opt), actor_skipped = resilience.finite_or_skip(
-                (policy_loss, actor_grad_norm), (new_actor, actor_opt), (params["actor"], opt_states.actor)
-            )
-        else:
-            actor_skipped = jnp.float32(0.0)
+        with _scope("actor_opt"):
+            actor_grad_norm = optax_global_norm(actor_grads)
+            actor_updates, actor_opt = actor_tx.update(actor_grads, opt_states.actor, params["actor"])
+            new_actor = apply_updates(params["actor"], actor_updates)
+            if nonfinite_guard:
+                (new_actor, actor_opt), actor_skipped = resilience.finite_or_skip(
+                    (policy_loss, actor_grad_norm), (new_actor, actor_opt), (params["actor"], opt_states.actor)
+                )
+            else:
+                actor_skipped = jnp.float32(0.0)
 
         # ---- critic update (Eq. 10) on the pre-update-actor trajectories
         trajectories = jax.lax.stop_gradient(aux_a["trajectories"])
@@ -353,25 +403,29 @@ def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, action
         discount = aux_a["discount"]
 
         def critic_loss_fn(critic_params):
-            qv = TwoHotEncodingDistribution(modules.critic.apply(critic_params, trajectories[:-1]), dims=1)
-            predicted_target_values = TwoHotEncodingDistribution(
-                modules.critic.apply(target_critic, trajectories[:-1]), dims=1
-            ).mean
-            value_loss = -qv.log_prob(lambda_values) - qv.log_prob(
-                jax.lax.stop_gradient(predicted_target_values)
-            )
-            return jnp.mean(value_loss * discount[:-1][..., 0])
+            with _scope("critic_update"):
+                qv = TwoHotEncodingDistribution(modules.critic.apply(critic_params, trajectories[:-1]), dims=1)
+            with _scope("target_critic"):
+                predicted_target_values = TwoHotEncodingDistribution(
+                    modules.critic.apply(target_critic, trajectories[:-1]), dims=1
+                ).mean
+            with _scope("critic_update"):
+                value_loss = -qv.log_prob(lambda_values) - qv.log_prob(
+                    jax.lax.stop_gradient(predicted_target_values)
+                )
+                return jnp.mean(value_loss * discount[:-1][..., 0])
 
         value_loss, critic_grads = jax.value_and_grad(critic_loss_fn)(params["critic"])
-        critic_grad_norm = optax_global_norm(critic_grads)
-        critic_updates, critic_opt = critic_tx.update(critic_grads, opt_states.critic, params["critic"])
-        new_critic = apply_updates(params["critic"], critic_updates)
-        if nonfinite_guard:
-            (new_critic, critic_opt), critic_skipped = resilience.finite_or_skip(
-                (value_loss, critic_grad_norm), (new_critic, critic_opt), (params["critic"], opt_states.critic)
-            )
-        else:
-            critic_skipped = jnp.float32(0.0)
+        with _scope("critic_opt"):
+            critic_grad_norm = optax_global_norm(critic_grads)
+            critic_updates, critic_opt = critic_tx.update(critic_grads, opt_states.critic, params["critic"])
+            new_critic = apply_updates(params["critic"], critic_updates)
+            if nonfinite_guard:
+                (new_critic, critic_opt), critic_skipped = resilience.finite_or_skip(
+                    (value_loss, critic_grad_norm), (new_critic, critic_opt), (params["critic"], opt_states.critic)
+                )
+            else:
+                critic_skipped = jnp.float32(0.0)
 
         # f32 island: entropy is a sum of p*log p terms over discrete*stoch
         # categories — accumulate in f32 even when the RSSM emits bf16 logits
@@ -444,7 +498,8 @@ def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, action
         }
         # raveled player subset computed in-graph: the host-player refresh is one
         # flat transfer, not a per-leaf pull (see DreamerPlayerSync)
-        flat_player = psync.ravel(params) if psync is not None else None
+        with _scope("player_ravel"):
+            flat_player = psync.ravel(params) if psync is not None else None
         return params, opt_states, moments_state, counter, flat_player, named
 
     return init_opt, jax_compile.guarded_jit(train, name="dv3.train", donate_argnums=(0, 1, 2))
@@ -590,7 +645,6 @@ def main(runtime, cfg: Dict[str, Any]):
     train_step = 0
     last_train = 0
     train_calls = 0
-    last_train_calls = 0
     start_iter = (state["iter_num"] // world_size) + 1 if state else 1
     policy_step = state["iter_num"] * cfg.env.num_envs if state else 0
     last_log = state["last_log"] if state else 0
@@ -902,30 +956,34 @@ def main(runtime, cfg: Dict[str, Any]):
                     # health-sentinel backoff: shrink this round's gradient grant
                     per_rank_gradient_steps = max(1, int(per_rank_gradient_steps * sentinel.ratio_scale))
                 if per_rank_gradient_steps > 0:
-                    # steady-state: this consumes the batch prefetched during the previous
-                    # train step and immediately starts speculating the next one
-                    batches = prefetcher.get(
-                        batch_size=cfg.algo.per_rank_batch_size * world_size,
-                        sequence_length=cfg.algo.per_rank_sequence_length,
-                        n_samples=per_rank_gradient_steps,
-                    )
-                    with timer("Time/train_time", SumMetric()):
-                        # no-op once the warmup thread finished (first train
-                        # call at the latest; usually hidden behind prefill)
-                        warmup.wait()
-                        rng, train_key = jax.random.split(rng)
-                        params, opt_states, moments_state, counter, flat_player, train_metrics = train_fn(
-                            params, opt_states, moments_state, counter, batches, train_key
+                    # one span for the train call; the prefetcher's, the guarded
+                    # function's and the player sync's own spans nest under it
+                    with trace.span("train.call", step=train_calls, gradient_steps=per_rank_gradient_steps):
+                        # steady-state: this consumes the batch prefetched during the previous
+                        # train step and immediately starts speculating the next one
+                        batches = prefetcher.get(
+                            batch_size=cfg.algo.per_rank_batch_size * world_size,
+                            sequence_length=cfg.algo.per_rank_sequence_length,
+                            n_samples=per_rank_gradient_steps,
                         )
-                        if not timer.disabled:
-                            # fence ONLY when timing: Time/train_time must include the
-                            # device work, but an unconditional sync would serialize the
-                            # loop on the dispatch round-trip
-                            jax.block_until_ready(params)
-                        psync.push(player, params, flat=flat_player)
-                        cumulative_per_rank_gradient_steps += per_rank_gradient_steps
-                        train_step += world_size * per_rank_gradient_steps
-                        train_calls += 1
+                        with timer("Time/train_time", SumMetric()):
+                            # no-op once the warmup thread finished (first train
+                            # call at the latest; usually hidden behind prefill)
+                            warmup.wait()
+                            rng, train_key = jax.random.split(rng)
+                            params, opt_states, moments_state, counter, flat_player, train_metrics = train_fn(
+                                params, opt_states, moments_state, counter, batches, train_key
+                            )
+                            if not timer.disabled:
+                                # fence ONLY when timing: Time/train_time must include the
+                                # device work, but an unconditional sync would serialize the
+                                # loop on the dispatch round-trip
+                                with trace.span("train.fence"):
+                                    jax.block_until_ready(params)
+                            psync.push(player, params, flat=flat_player)
+                            cumulative_per_rank_gradient_steps += per_rank_gradient_steps
+                            train_step += world_size * per_rank_gradient_steps
+                            train_calls += 1
                     if aggregator:
                         aggregator.update_from_device(train_metrics)
                     resilience.enforce_nonfinite_policy(ft, train_metrics)
@@ -995,16 +1053,8 @@ def main(runtime, cfg: Dict[str, Any]):
                             {"Time/sps_train": (train_step - last_train) / timer_metrics["Time/train_time"]},
                             policy_step,
                         )
-                        # model FLOPs utilization from the AOT cost analysis of the
-                        # G-step train program (same contract as ppo/a2c/sac)
-                        _mfu = tel_device.mfu(
-                            getattr(train_fn, "last_step_flops", None),
-                            timer_metrics["Time/train_time"]
-                            / max(train_calls - last_train_calls, 1),
-                            runtime.device,
-                        )
-                        if _mfu is not None:
-                            logger.log_metrics({"Time/mfu": _mfu}, policy_step)
+                        # no Time/mfu row here: cost_analysis counts a lax.scan body
+                        # once, and this program is scans (howto/observability.md)
                     if logger and timer_metrics.get("Time/env_interaction_time", 0) > 0:
                         logger.log_metrics(
                             {
@@ -1018,7 +1068,6 @@ def main(runtime, cfg: Dict[str, Any]):
                     timer.reset()
                 last_log = policy_step
                 last_train = train_step
-                last_train_calls = train_calls
 
             # ---- checkpoint
             if (cfg.checkpoint.every > 0 and policy_step - last_checkpoint >= cfg.checkpoint.every) or (

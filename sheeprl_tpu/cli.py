@@ -155,6 +155,9 @@ def _apply_global_flags(cfg: dotdict, plane: str = "train") -> None:
     tel_cfg = cfg.get("metric", {}).get("telemetry") if "metric" in cfg else None
     if tel_cfg and bool(tel_cfg.get("trace", False)) and not os.environ.get(trace.ENV_VAR):
         trace.configure(plane=plane, capacity=int(tel_cfg.get("capacity", 16384)))
+    # Spans follow a profiler capture whether or not a tracer is configured:
+    # trace.py never imports jax, so the plane that has it hands the probe over.
+    trace.follow_captures(jax.profiler.TraceAnnotation)
 
     # Compiled-program ledger: same env-wins contract as the tracer. With no
     # explicit path the train loops default it into the run's log dir.

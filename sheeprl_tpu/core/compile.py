@@ -44,6 +44,8 @@ import numpy as np
 
 import jax
 
+from sheeprl_tpu.telemetry import trace
+
 _logger = logging.getLogger("sheeprl_tpu.compile")
 
 # process-relative clock zero for ``first_call_s`` (time-to-first-step metrics)
@@ -358,7 +360,18 @@ class GuardedFn:
         self.retraces = 0
         self.aot_compiles = 0
         self.aot_fallbacks = 0
+        # compile_seconds: trace + lower + compile (or load from the persistent
+        # cache), AOT and jit path alike; lower_seconds: the trace-and-lower part
+        # of the AOT compiles, which no cache saves
         self.compile_seconds = 0.0
+        self.lower_seconds = 0.0
+        # host time of the calls that compiled nothing, split at the point where
+        # the executable is known: finding it (abstract_signature over every
+        # leaf, lookup, a wait for a pending warmup) and the executable's own call
+        self.route_seconds = 0.0
+        self.execute_seconds = 0.0
+        # span names built once: the disabled tracer's fast path must not format strings
+        self._span_names = {k: f"{self.name}.{k}" for k in ("route", "execute", "lower", "compile")}
         self.first_call_s: Optional[float] = None  # seconds since module import
         self.last_signature: Optional[Tuple] = None
         self.last_diff: Optional[str] = None
@@ -394,6 +407,9 @@ class GuardedFn:
             "aot_compiles": self.aot_compiles,
             "aot_fallbacks": self.aot_fallbacks,
             "compile_seconds": self.compile_seconds,
+            "lower_seconds": self.lower_seconds,
+            "route_seconds": self.route_seconds,
+            "execute_seconds": self.execute_seconds,
             "first_call_s": self.first_call_s,
             "flops_dispatched": self.flops_dispatched,
             "step_flops": self.last_step_flops,
@@ -406,8 +422,11 @@ class GuardedFn:
         the specs' abstract signature; matching calls then never trace."""
         sig = abstract_signature(specs, kwspecs)
         t0 = time.perf_counter()
-        lowered = jax.jit(self.fun, **self._jit_kwargs).lower(*specs, **kwspecs)
-        exe = lowered.compile()
+        with trace.span(self._span_names["lower"]):
+            lowered = jax.jit(self.fun, **self._jit_kwargs).lower(*specs, **kwspecs)
+        t_lowered = time.perf_counter()
+        with trace.span(self._span_names["compile"]):
+            exe = lowered.compile()
         dt = time.perf_counter() - t0
         flops = _cost_flops(exe)
         bytes_accessed = _cost_bytes(exe)
@@ -421,6 +440,7 @@ class GuardedFn:
                 self.last_step_bytes = bytes_accessed
             self.aot_compiles += 1
             self.compile_seconds += dt
+            self.lower_seconds += t_lowered - t0
             self._had_any_compile = True
             self.last_signature = sig
         _logger.debug("[compile] AOT %s compiled in %.3fs", self.name, dt)
@@ -445,54 +465,65 @@ class GuardedFn:
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
         self.calls += 1
         sig: Optional[Tuple] = None
+        exe = key = None
+        t0 = time.perf_counter()
         if self._aot or self._aot_pending:
-            sig = abstract_signature(args, kwargs)
-            key = _routing_key(sig)
-            exe = self._aot.get(key)
-            if exe is None and self._aot_pending:
-                # a background warmup for this fn is (probably) compiling the
-                # executable this call needs: waiting is never slower than
-                # tracing+compiling the same signature here, and keeps the
-                # jit-path compile from registering as a spurious retrace
-                for ev in list(self._aot_pending):
-                    ev.wait(timeout=600.0)
-                self._aot_pending = []
+            with trace.span(self._span_names["route"]):
+                sig = abstract_signature(args, kwargs)
+                key = _routing_key(sig)
                 exe = self._aot.get(key)
-            if exe is not None:
-                try:
+                if exe is None and self._aot_pending:
+                    # a background warmup for this fn is (probably) compiling the
+                    # executable this call needs: waiting is never slower than
+                    # tracing+compiling the same signature here, and keeps the
+                    # jit-path compile from registering as a spurious retrace
+                    for ev in list(self._aot_pending):
+                        ev.wait(timeout=600.0)
+                    self._aot_pending = []
+                    exe = self._aot.get(key)
+        t_routed = time.perf_counter()
+        self.route_seconds += t_routed - t0
+        if exe is not None:
+            try:
+                with trace.span(self._span_names["execute"]):
                     out = exe(*args, **kwargs)
-                    fl = self._aot_flops.get(key)
-                    if fl is not None:
-                        self.flops_dispatched += fl
-                    if self.first_call_s is None:
-                        self.first_call_s = time.perf_counter() - _T0
-                    return out
-                except (TypeError, ValueError) as e:
-                    # the compiled executable validates its inputs BEFORE it
-                    # runs (nothing executed, nothing donated), and that is the
-                    # only place a dispatch raises these types: the signature
-                    # models shape/dtype only, so a placement or layout the
-                    # specs did not carry lands here. The jitted path below
-                    # either serves the call or raises the real error; evict
-                    # the executable so later calls skip the failing dispatch.
-                    # Counted: a smoke asserts aot_fallbacks == 0.
-                    self.aot_fallbacks += 1
-                    with _LOCK:
-                        self._aot.pop(key, None)
-                        self._aot_flops.pop(key, None)
-                    _logger.warning(
-                        "[compile] AOT executable for '%s' rejected its inputs (%s); "
-                        "falling back to JIT for this signature",
-                        self.name,
-                        str(e).splitlines()[0][:200],
-                    )
+                self.execute_seconds += time.perf_counter() - t_routed
+                fl = self._aot_flops.get(key)
+                if fl is not None:
+                    self.flops_dispatched += fl
+                if self.first_call_s is None:
+                    self.first_call_s = time.perf_counter() - _T0
+                return out
+            except (TypeError, ValueError) as e:
+                # the compiled executable validates its inputs BEFORE it
+                # runs (nothing executed, nothing donated), and that is the
+                # only place a dispatch raises these types: the signature
+                # models shape/dtype only, so a placement or layout the
+                # specs did not carry lands here. The jitted path below
+                # either serves the call or raises the real error; evict
+                # the executable so later calls skip the failing dispatch.
+                # Counted: a smoke asserts aot_fallbacks == 0.
+                self.aot_fallbacks += 1
+                with _LOCK:
+                    self._aot.pop(key, None)
+                    self._aot_flops.pop(key, None)
+                _logger.warning(
+                    "[compile] AOT executable for '%s' rejected its inputs (%s); "
+                    "falling back to JIT for this signature",
+                    self.name,
+                    str(e).splitlines()[0][:200],
+                )
         before = self._trace_count
         t0 = time.perf_counter()
-        out = self._jitted(*args, **kwargs)
+        with trace.span(self._span_names["execute"]):
+            out = self._jitted(*args, **kwargs)
+        dt = time.perf_counter() - t0
         if self._trace_count != before:
             if sig is None:
                 sig = abstract_signature(args, kwargs)
-            self._on_compile(sig, time.perf_counter() - t0)
+            self._on_compile(sig, dt)  # a call that traced is compile time, not execute time
+        else:
+            self.execute_seconds += dt
         if self.first_call_s is None:
             self.first_call_s = time.perf_counter() - _T0
         return out
@@ -644,6 +675,7 @@ def process_stats() -> Dict[str, Any]:
         "aot_compiles": 0,
         "aot_fallbacks": 0,
         "compile_seconds": 0.0,
+        "lower_seconds": 0.0,
         "flops_dispatched": 0.0,
     }
     per_fn = {}

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from sheeprl_tpu.data.buffers import get_array
+from sheeprl_tpu.telemetry import trace
 
 __all__ = ["DevicePrefetcher", "InlineSampler"]
 
@@ -114,6 +115,7 @@ class DevicePrefetcher:
         # results so a stale (discarded) speculation can never satisfy a newer get()
         self._job_id = 0
         self._job_kwargs: Optional[Dict[str, Any]] = None
+        self._job_parent = ""
         self._done_id = 0
         self._result: Optional[Dict[str, Any]] = None
         self._error: Optional[BaseException] = None
@@ -127,23 +129,32 @@ class DevicePrefetcher:
     FENCE_BYTES = 4 * 1024 * 1024
 
     # ----- worker --------------------------------------------------------------------
-    def _transfer(self, batch: Dict[str, np.ndarray]) -> Dict[str, Any]:
+    def _transfer(self, batch: Dict[str, np.ndarray], parent_id: Optional[str]) -> Dict[str, Any]:
         # device_put returns immediately; the async copy completes while the
         # consumer is still dispatching/awaiting the previous train step.
         total_bytes = sum(getattr(v, "nbytes", 0) for v in batch.values())
-        out = {k: get_array(v, dtype=self._dtype, device=self._device) for k, v in batch.items()}
+        with trace.span("prefetch.h2d", parent_id=parent_id, bytes=total_bytes):
+            out = {k: get_array(v, dtype=self._dtype, device=self._device) for k, v in batch.items()}
         if self._device is not None and out and total_bytes >= self.FENCE_BYTES:
             # Fence: block THIS worker thread until the batch is device-resident,
             # bounding in-flight transfers to the double-buffer depth. Without it
             # the consumer outruns the copies and the host transfer queue grows
             # without bound. The fence is a real host pull of a probe that depends
             # on every leaf, so ONE round trip fences them all.
-            import jax
             import jax.numpy as jnp
 
-            probe = jnp.stack([v[(0,) * v.ndim].astype(jnp.float32) for v in out.values()])
-            np.asarray(jax.device_get(probe))
+            with trace.span("prefetch.h2d_fence", parent_id=parent_id):
+                probe = jnp.stack([v[(0,) * v.ndim].astype(jnp.float32) for v in out.values()])
+                np.asarray(jax.device_get(probe))
         return out
+
+    def _sample(self, kwargs: Dict[str, Any], parent_id: Optional[str] = None) -> Dict[str, np.ndarray]:
+        """``sample_fn`` under the IO lock. ``parent_id`` is the ``prefetch.get``
+        span that launched the job when this runs on the worker thread: its
+        spans hang under that span across the threads."""
+        with self._io_lock:
+            with trace.span("prefetch.sample", parent_id=parent_id, n_samples=kwargs.get("n_samples")):
+                return self._sample_fn(**kwargs)
 
     def _run(self) -> None:
         while True:
@@ -155,12 +166,16 @@ class DevicePrefetcher:
                     self._cond.wait()
                 if self._closed:
                     return
-                job_id, kwargs = self._job_id, dict(self._job_kwargs or {})
+                job_id, kwargs, parent_id = self._job_id, dict(self._job_kwargs or {}), self._job_parent
             try:
-                with self._io_lock:
-                    batch = self._sample_fn(**kwargs)
+                # ``batch`` is this loop's own name on purpose: the last chunk's host arrays
+                # (50 MB at DV3-XL) are released when it is bound anew, while the train loop
+                # sits in its fence. Released right after the transfer's fence, which ends
+                # when the train step does, the unmapping falls on the loop's next dispatch
+                # (measured on the v5e: +4 ms on every fourth step, PERF.md PR 27).
+                batch = self._sample(kwargs, parent_id)
                 result: Tuple[Optional[Dict[str, Any]], Optional[BaseException]] = (
-                    self._transfer(batch),
+                    self._transfer(batch, parent_id),
                     None,
                 )
             except BaseException as e:  # surfaced on the consumer thread in get()
@@ -176,6 +191,7 @@ class DevicePrefetcher:
     def _launch_locked(self, kwargs: Dict[str, Any]) -> None:
         self._job_id += 1
         self._job_kwargs = dict(kwargs)
+        self._job_parent = trace.current_span_id()  # the worker's spans hang under the get() that launched them
         self._result = None
         self._error = None
         self._cond.notify_all()
@@ -222,8 +238,14 @@ class DevicePrefetcher:
 
     def get(self, **kwargs) -> Dict[str, Any]:
         """Return a (device-resident) batch for ``kwargs``; speculate the next one."""
-        if self._chunkable(kwargs):
-            return self._get_chunked(kwargs)
+        # served: "piece" of a transferred chunk, a "speculated" batch (waited for
+        # if need be), or sampled in line ("sync": first call, or the kwargs changed)
+        with trace.span("prefetch.get") as sp:
+            if self._chunkable(kwargs):
+                return self._get_chunked(kwargs, sp)
+            return self._get_single(kwargs, sp)
+
+    def _get_single(self, kwargs: Dict[str, Any], sp: Any) -> Dict[str, Any]:
         with self._cond:
             if self._closed:
                 raise RuntimeError("DevicePrefetcher is closed")
@@ -241,7 +263,9 @@ class DevicePrefetcher:
                 self._job_id += 1
                 self._job_kwargs = None
         if not speculated:
+            sp.set(served="sync")
             return self._sample_now(kwargs, kwargs)
+        sp.set(served="speculated")
         if err is not None:
             raise err
         return result
@@ -250,9 +274,7 @@ class DevicePrefetcher:
         """Sample+transfer synchronously on the consumer thread, then speculate
         ``speculate_kwargs`` (the scaled kwargs in chunked mode)."""
         try:
-            with self._io_lock:
-                batch = self._sample_fn(**kwargs)
-            result, err = self._transfer(batch), None
+            result, err = self._transfer(self._sample(kwargs), None), None
         except BaseException as e:
             result, err = None, e
         with self._cond:
@@ -262,16 +284,18 @@ class DevicePrefetcher:
             raise err
         return result
 
-    def _get_chunked(self, kwargs: Dict[str, Any]) -> Dict[str, Any]:
+    def _get_chunked(self, kwargs: Dict[str, Any], sp: Any) -> Dict[str, Any]:
         scaled = self._scaled(kwargs)
         with self._cond:
             if self._closed:
                 raise RuntimeError("DevicePrefetcher is closed")
             # steady state: serve a ready piece of the current superbatch
             if self._pieces and self._pieces_kwargs == kwargs:
+                sp.set(served="piece")
                 return self._pieces.pop(0)
             speculated = self._job_id > 0 and self._job_kwargs == scaled
             if speculated:
+                sp.set(served="speculated")
                 while self._done_id != self._job_id and not self._closed:
                     self._cond.wait()
                 if self._closed:
@@ -292,6 +316,7 @@ class DevicePrefetcher:
             self._pieces_kwargs = None
             self._job_id += 1
             self._job_kwargs = None
+        sp.set(served="sync")
         return self._sample_now(kwargs, scaled)
 
     def guard(self) -> threading.Lock:

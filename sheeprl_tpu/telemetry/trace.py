@@ -21,6 +21,19 @@ programmatically via :func:`configure`::
     SHEEPRL_TPU_TRACE=1
     SHEEPRL_TPU_TRACE="plane=serve;capacity=8192;trace_id=ab12cd34ef56"
 
+Spans also **follow a profiler capture**. The train plane hands this module
+``jax.profiler.TraceAnnotation`` once (:func:`follow_captures`, from
+``cli._apply_global_flags``; this module never imports jax, because the router
+and the controller never do). From then on, while a ``jax.profiler`` session
+is open (``metric.profiler``, SIGUSR2, ``CaptureWindow``), every span is
+recorded whether or not a tracer is configured, and every :func:`span` also
+enters ``TraceAnnotation("sheeprl.<name>")`` on the thread that made it, so it
+lies in the capture's ``.xplane.pb`` on the device events' clock. With no
+tracer configured the spans of a capture land in a ring of their own, made
+anew when a capture opens: after the capture, ``get_tracer().events()`` holds
+exactly its spans. With no session open that path costs one more call
+(``TraceAnnotation.is_enabled()``, about 0.1 us) and still allocates nothing.
+
 Completed spans land in a bounded ring (``collections.deque(maxlen=...)``):
 steady-state memory is O(capacity), the newest events win, and
 ``Telemetry/spans_dropped`` counts what the ring evicted. :func:`export`
@@ -75,20 +88,44 @@ _NOOP = _NoopSpan()
 _tracer: Optional["Tracer"] = None
 _tls = threading.local()
 
+ANNOTATION_PREFIX = "sheeprl."
+# ``jax.profiler.TraceAnnotation`` once the train plane handed it over
+# (follow_captures), else None: ``.is_enabled()`` says whether a profiler
+# session is open, and an instance is the span inside the capture.
+_annotation: Any = None
+# The ring of the spans of a capture taken with no tracer configured: made
+# when the first span of an open session arrives, kept after the session so
+# that it can be read, replaced by the next capture's.
+_capture: Optional["Tracer"] = None
+_capture_open = False
+_capture_lock = threading.Lock()
+
 
 class Span:
     """A live span: context manager recording [enter, exit) into the ring."""
 
-    __slots__ = ("name", "plane", "span_id", "parent_id", "args", "_t0", "_tracer", "_tid")
+    __slots__ = ("name", "plane", "span_id", "parent_id", "args", "_t0", "_tracer", "_tid", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, plane: Optional[str], args: Dict[str, Any]):
+    def __init__(
+        self,
+        tracer: "Tracer",
+        name: str,
+        plane: Optional[str],
+        args: Dict[str, Any],
+        parent_id: Optional[str] = None,
+        annotation: Any = None,
+    ):
         self._tracer = tracer
         self.name = name
         self.plane = plane or tracer.plane
         self.span_id = tracer._next_span_id()
+        # None: the enclosing span of this thread (found at entry); a string: the
+        # parent a caller on another thread handed over
+        self.parent_id = parent_id
         self.args = args or None
         self._t0 = 0.0
         self._tid = 0
+        self._ann = annotation
 
     @property
     def trace_id(self) -> str:
@@ -104,14 +141,19 @@ class Span:
 
     def __enter__(self) -> "Span":
         stack = _span_stack()
-        self.parent_id = stack[-1] if stack else ""
+        if self.parent_id is None:
+            self.parent_id = stack[-1] if stack else ""
         stack.append(self.span_id)
         self._tid = threading.get_ident()
+        if self._ann is not None:  # outside the timed interval: the ring's span lies inside the capture's
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = _span_stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -268,21 +310,41 @@ class Tracer:
 # --------------------------------------------------------------------------- #
 
 
-def span(name: str, plane: Optional[str] = None, **args: Any) -> Any:
-    """A context-manager span. Returns the shared no-op singleton when tracing
-    is disabled — the fast path is one identity check, zero allocation.
-    ``plane`` overrides the tracer's default category (e.g. a serve-side span
-    recorded from a process whose tracer was configured for train)."""
+def _recorder() -> Optional["Tracer"]:
+    """The tracer that records now: the configured one, else the capture's ring
+    while a profiler session is open, else None."""
     t = _tracer
+    if t is not None:
+        return t
+    a = _annotation
+    if a is None:
+        return None
+    if not a.is_enabled():
+        if _capture_open:
+            _close_capture()
+        return None
+    return _capture_ring()
+
+
+def span(name: str, plane: Optional[str] = None, *, parent_id: Optional[str] = None, **args: Any) -> Any:
+    """A context-manager span. Returns the shared no-op singleton when tracing
+    is disabled and no profiler session is open — the fast path is one identity
+    check (and, once :func:`follow_captures` ran, one ``is_enabled()``), zero
+    allocation. ``plane`` overrides the tracer's default category (e.g. a
+    serve-side span recorded from a process whose tracer was configured for
+    train). ``parent_id`` names the parent when it lives on another thread
+    (:func:`current_span_id` there, handed over with the work); left out, the
+    parent is the enclosing span of this thread."""
+    t = _recorder()
     if t is None:  # the entire production cost of an instrumentation seam
         return _NOOP
-    return _begin(t, name, plane, args)
+    return _begin(t, name, plane, args, parent_id)
 
 
 def instant(name: str, **args: Any) -> None:
     """A zero-duration marker event (e.g. a failpoint fire, a trial state
     transition). No-op while disabled."""
-    t = _tracer
+    t = _recorder()
     if t is None:
         return None
     return _record_instant(t, name, args)
@@ -304,8 +366,10 @@ def add_span(
     by the serve request lifecycle, where admit and respond happen on
     different threads than the batch compute. A caller that pre-allocated an
     id with :func:`new_span_id` (to hand children a parent before the parent
-    closes) passes it as ``span_id``. No-op while disabled."""
-    t = _tracer
+    closes) passes it as ``span_id``. No-op while disabled. A span added after
+    the fact reaches the ring only: a capture holds what was annotated while it
+    ran (:func:`span`)."""
+    t = _recorder()
     if t is None:
         return None
     return _record_span(t, name, start_s, end_s, clock, plane, parent_id, span_id, args)
@@ -315,15 +379,34 @@ def new_span_id() -> str:
     """Pre-allocate a span id for a later :func:`add_span` (lets cross-thread
     children link to a parent that has not closed yet); ``""`` while
     disabled."""
-    t = _tracer
+    t = _recorder()
     return t._next_span_id() if t is not None else ""
 
 
 # Kept module-level (not methods) so the disabled-mode zero-cost test can
 # monkeypatch them to raise and prove span()/instant()/add_span() never reach
 # past the `_tracer is None` guard — the same pattern as failpoints._fire.
-def _begin(t: Tracer, name: str, plane: Optional[str], args: Dict[str, Any]) -> Span:
-    return Span(t, name, plane, args)
+def _begin(t: Tracer, name: str, plane: Optional[str], args: Dict[str, Any], parent_id: Optional[str] = None) -> Span:
+    a = _annotation
+    ann = a(ANNOTATION_PREFIX + name) if a is not None and a.is_enabled() else None
+    return Span(t, name, plane, args, parent_id, ann)
+
+
+def _capture_ring() -> Tracer:
+    """The ring of the open capture, made when its first span arrives."""
+    global _capture, _capture_open
+    with _capture_lock:
+        if not _capture_open:
+            _capture = Tracer(plane="train")
+            _capture_open = True
+        return _capture
+
+
+def _close_capture() -> None:
+    """The session ended: the ring stays to be read, the next capture starts a new one."""
+    global _capture_open
+    with _capture_lock:
+        _capture_open = False
 
 
 def _record_instant(t: Tracer, name: str, args: Dict[str, Any]) -> None:
@@ -422,6 +505,18 @@ def configure_from_env(environ: Optional[Dict[str, str]] = None) -> Optional[Tra
     )
 
 
+def follow_captures(annotation: Any) -> None:
+    """Hand over ``jax.profiler.TraceAnnotation`` (None takes it back): from now
+    on spans are recorded and annotated while a profiler session is open (module
+    docstring). Called by ``cli._apply_global_flags``, so by every train loop and
+    by anything that sets a process up the way ``cli.run`` does."""
+    global _annotation, _capture, _capture_open
+    with _capture_lock:
+        _annotation = annotation
+        if annotation is None:
+            _capture, _capture_open = None, False
+
+
 def disable() -> None:
     configure(False)
 
@@ -431,7 +526,8 @@ def enabled() -> bool:
 
 
 def get_tracer() -> Optional[Tracer]:
-    return _tracer
+    """The configured tracer, else the ring of the newest capture (or None)."""
+    return _tracer if _tracer is not None else _capture
 
 
 def current_trace_id() -> str:
@@ -442,8 +538,8 @@ def current_trace_id() -> str:
 
 
 def current_span_id() -> str:
-    t = _tracer
-    if t is None:
+    """The innermost open span of this thread, ``""`` where there is none."""
+    if _tracer is None and not _capture_open:
         return ""
     stack = _span_stack()
     return stack[-1] if stack else ""

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.core import compile as jax_compile
+from sheeprl_tpu.telemetry import trace
 
 
 class dotdict(dict):
@@ -260,7 +261,8 @@ class PlayerParamsSync:
 
     def pull(self, flat: jax.Array, device):
         """One cross-backend transfer + on-device unflatten -> player param tree."""
-        return self._unravel_jit(jax.device_put(flat, device))
+        with trace.span("player.pull", bytes=flat.nbytes):
+            return self._unravel_jit(jax.device_put(flat, device))
 
 
 class DreamerPlayerSync:
@@ -310,21 +312,25 @@ class DreamerPlayerSync:
         ``flat`` is the train step's raveled output (avoids an extra dispatch);
         ``force`` bypasses the cadence (initial placement, final pre-test flush).
         """
-        if not self.enabled:
-            player.wm_params = params["world_model"]
-            player.actor_params = params[self._actor_name]
-            return
-        if force:
-            self._calls = 0  # the player is fresh: restart the staleness window
-        else:
-            self._calls += 1
-            if self._calls % self._every != 0:
+        with trace.span("player.push") as sp:
+            if not self.enabled:
+                sp.set(skipped="rebind")
+                player.wm_params = params["world_model"]
+                player.actor_params = params[self._actor_name]
                 return
-        if flat is None:
-            flat = self._ravel_jit(self.subset(params))
-        wm, actor = self._sync.pull(flat, self._runtime.player_device)
-        player.wm_params = wm
-        player.actor_params = actor
+            if force:
+                self._calls = 0  # the player is fresh: restart the staleness window
+            else:
+                self._calls += 1
+                if self._calls % self._every != 0:
+                    sp.set(skipped="cadence")
+                    return
+            if flat is None:
+                with trace.span("player.ravel"):
+                    flat = self._ravel_jit(self.subset(params))
+            wm, actor = self._sync.pull(flat, self._runtime.player_device)
+            player.wm_params = wm
+            player.actor_params = actor
 
 
 # --------------------------------------------------------------------------------------

@@ -162,3 +162,54 @@ def test_bucketed_pad_rejects_empty_and_ragged():
         jax_compile.bucketed_pad({"x": []}, lengths=[], length=4)
     with pytest.raises(ValueError):
         jax_compile.bucketed_pad({"x": [np.ones((2, 1))]}, lengths=[2, 3], length=4)
+
+
+def test_call_counters_split_route_execute_and_lower():
+    """What `train_route_ms` / `train_execute_ms` / `setup_lower_s` read: the time of
+    a call is split where the executable is known, an AOT compile where the program
+    is lowered, and a call that compiled counts as compile time, not as execute time."""
+    from sheeprl_tpu.telemetry import trace
+
+    gfn = jax_compile.guarded_jit(lambda x: jnp.tanh(x @ x), name="t.counters")
+    spec = jax.ShapeDtypeStruct((16, 16), jnp.float32)
+    before = jax_compile.process_stats()["lower_seconds"]
+    gfn.aot_compile(spec)
+    s0 = gfn.stats()
+    assert 0.0 < s0["lower_seconds"] <= s0["compile_seconds"]
+    assert s0["route_seconds"] == s0["execute_seconds"] == 0.0
+    assert jax_compile.process_stats()["lower_seconds"] == pytest.approx(before + s0["lower_seconds"])
+    tracer = trace.configure(plane="train", trace_id="counters")
+    try:
+        x = jnp.ones((16, 16), jnp.float32)
+        seen = [s0]
+        for _ in range(3):
+            gfn(x)
+            seen.append(gfn.stats())
+        assert gfn.traces == 0
+        for a, b in zip(seen, seen[1:]):
+            assert b["route_seconds"] > a["route_seconds"] and b["execute_seconds"] > a["execute_seconds"]
+            assert b["lower_seconds"] == a["lower_seconds"] and b["compile_seconds"] == a["compile_seconds"]
+        assert jax_compile.process_stats()["functions"]["t.counters"]["route_seconds"] == seen[-1]["route_seconds"]
+        names = [ev[trace._EV_NAME] for ev in tracer.events()]
+        assert names == ["t.counters.route", "t.counters.execute"] * 3
+        # the jit path: the call that traces is compile time; the next one is execute time
+        gfn(jnp.ones((8, 8), jnp.float32))
+        traced = gfn.stats()
+        assert traced["compile_seconds"] > seen[-1]["compile_seconds"]
+        assert traced["execute_seconds"] == seen[-1]["execute_seconds"]
+        gfn(jnp.ones((8, 8), jnp.float32))
+        assert gfn.stats()["execute_seconds"] > traced["execute_seconds"]
+    finally:
+        trace.disable()
+
+
+def test_aot_compile_records_lower_and_compile_spans():
+    from sheeprl_tpu.telemetry import trace
+
+    tracer = trace.configure(plane="train", trace_id="aot")
+    try:
+        gfn = jax_compile.guarded_jit(lambda x: x + 1, name="t.aotspans")
+        gfn.aot_compile(jax.ShapeDtypeStruct((4,), jnp.float32))
+        assert [ev[trace._EV_NAME] for ev in tracer.events()] == ["t.aotspans.lower", "t.aotspans.compile"]
+    finally:
+        trace.disable()

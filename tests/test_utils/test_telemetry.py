@@ -436,7 +436,154 @@ def test_span_recording_adds_no_host_transfers_to_a_warm_fused_iteration():
         jax.block_until_ready(flat)  # fence only — not a transfer
         with pytest.raises(Exception):
             jnp.add(flat, 1.0)  # implicit host->device upload: guard is live
-    assert tracer.stats()["Telemetry/spans_recorded"] == 4
     names = [ev[trace._EV_NAME] for ev in tracer.events()]
     assert names.count("train/update") == 2 and names.count("train/iter_done") == 2
+    # besides the loop's own four, each guarded call records its execute span (and no route: nothing was AOT-warmed)
+    assert sorted(set(names) - {"train/update", "train/iter_done"}) == ["tel_zt.ingraph_train.execute"]
+    assert tracer.stats()["Telemetry/spans_recorded"] == 6
     venv.close()
+
+
+# --------------------------------------------------------------------------- #
+# spans follow a profiler capture (no tracer configured)
+# --------------------------------------------------------------------------- #
+
+
+@pytest.fixture
+def follow_jax_captures():
+    import jax
+
+    trace.follow_captures(jax.profiler.TraceAnnotation)
+    yield jax
+    trace.follow_captures(None)
+
+
+def _capture_span_names(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    return [
+        ev.name
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith(trace.ANNOTATION_PREFIX)
+    ]
+
+
+def test_spans_are_recorded_and_annotated_only_while_a_profiler_session_is_open(follow_jax_captures, tmp_path):
+    jax = follow_jax_captures
+    with trace.span("before"):
+        pass
+    assert trace.get_tracer() is None and not trace.enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("train.call", step=3) as outer:
+            assert trace.current_span_id() == outer.span_id
+            with trace.span("train.fence"):
+                pass
+        worker = threading.Thread(target=lambda: trace.span("prefetch.sample", parent_id=outer.span_id).__enter__().__exit__(None, None, None))
+        worker.start()
+        worker.join(10)
+        assert not worker.is_alive()
+        trace.add_span("added", 0.0, 1.0)  # after the fact: the ring only
+    finally:
+        jax.profiler.stop_trace()
+    with trace.span("after"):
+        pass
+    assert not trace.enabled()  # following a capture configures no tracer: no trace id, no export
+    ring = trace.get_tracer().events()
+    assert [ev[trace._EV_NAME] for ev in ring] == ["train.fence", "train.call", "prefetch.sample", "added"]
+    by_name = {ev[trace._EV_NAME]: ev for ev in ring}
+    assert by_name["train.fence"][trace._EV_PARENT] == by_name["train.call"][trace._EV_SID]
+    assert by_name["prefetch.sample"][trace._EV_PARENT] == by_name["train.call"][trace._EV_SID]
+    assert by_name["train.call"][trace._EV_ARGS] == {"step": 3}
+    assert sorted(_capture_span_names(str(tmp_path))) == [
+        "sheeprl.prefetch.sample", "sheeprl.train.call", "sheeprl.train.fence",
+    ]
+    # the next capture starts a ring of its own
+    second = tmp_path / "second"
+    jax.profiler.start_trace(str(second))
+    try:
+        with trace.span("again"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [ev[trace._EV_NAME] for ev in trace.get_tracer().events()] == ["again"]
+
+
+def test_no_session_and_no_tracer_never_reaches_the_recording_layer(follow_jax_captures, monkeypatch):
+    def boom(*a, **k):
+        raise AssertionError("a span was recorded with no tracer configured and no profiler session open")
+
+    for name in ("_begin", "_record_instant", "_record_span", "_capture_ring"):
+        monkeypatch.setattr(trace, name, boom)
+    assert trace.span("train.call", step=1) is trace._NOOP
+    assert trace.instant("x") is None and trace.add_span("y", 0.0, 1.0) is None
+    assert trace.new_span_id() == "" and trace.current_span_id() == ""
+    assert trace.get_tracer() is None
+
+
+def test_a_configured_tracer_also_annotates_an_open_capture(follow_jax_captures, tmp_path):
+    jax = follow_jax_captures
+    t = trace.configure(plane="train", trace_id="both")
+    with trace.span("outside"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("inside"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [ev[trace._EV_NAME] for ev in t.events()] == ["outside", "inside"]
+    assert trace.get_tracer() is t
+    assert _capture_span_names(str(tmp_path)) == ["sheeprl.inside"]
+
+
+def test_prefetch_sample_hangs_under_the_get_that_launched_it():
+    import numpy as np
+
+    from sheeprl_tpu.data.prefetch import DevicePrefetcher
+
+    t = trace.configure(plane="train", trace_id="prefetch")
+    sampled = threading.Event()
+
+    def sample_fn(n_samples, **_):
+        sampled.set()
+        return {"x": np.zeros((n_samples, 4), np.float32)}
+
+    with DevicePrefetcher(sample_fn, device=None, chunk=2, chunk_key="n_samples") as pf:
+        pf.get(n_samples=1)  # sync: sampled in line, launches the speculation of two
+        pf.get(n_samples=1)  # speculated: waits for the worker's chunk, launches the next
+        pf.get(n_samples=1)  # piece
+    rows = t.events()
+    gets = [ev for ev in rows if ev[trace._EV_NAME] == "prefetch.get"]
+    assert [ev[trace._EV_ARGS]["served"] for ev in gets] == ["sync", "speculated", "piece"]
+    samples = [ev for ev in rows if ev[trace._EV_NAME] == "prefetch.sample"]
+    in_line = [ev for ev in samples if ev[trace._EV_TID] == gets[0][trace._EV_TID]]
+    on_worker = [ev for ev in samples if ev[trace._EV_TID] != gets[0][trace._EV_TID]]
+    assert [ev[trace._EV_PARENT] for ev in in_line] == [gets[0][trace._EV_SID]]
+    assert in_line[0][trace._EV_ARGS] == {"n_samples": 1}
+    # the worker's first job was launched by the first get, and carries its id across the thread
+    assert on_worker and on_worker[0][trace._EV_PARENT] == gets[0][trace._EV_SID]
+    assert on_worker[0][trace._EV_ARGS] == {"n_samples": 2}
+    h2d = [ev for ev in rows if ev[trace._EV_NAME] == "prefetch.h2d"]
+    assert len(h2d) == len(samples) and {ev[trace._EV_ARGS]["bytes"] for ev in h2d} == {16, 32}
+    assert {ev[trace._EV_PARENT] for ev in h2d} <= {ev[trace._EV_SID] for ev in gets}
+
+
+def test_player_push_says_why_it_moved_nothing():
+    from types import SimpleNamespace
+
+    from sheeprl_tpu.utils.utils import DreamerPlayerSync
+
+    t = trace.configure(plane="train", trace_id="psync")
+    params = {"world_model": {"encoder": 1}, "actor": 2}
+    psync = DreamerPlayerSync(SimpleNamespace(player_on_host=False), params, wm_keys=("encoder",))
+    player = SimpleNamespace()
+    psync.push(player, params)
+    assert player.wm_params is params["world_model"] and player.actor_params == 2
+    (ev,) = t.events()
+    assert ev[trace._EV_NAME] == "player.push" and ev[trace._EV_ARGS] == {"skipped": "rebind"}
