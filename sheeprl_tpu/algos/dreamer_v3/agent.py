@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sheeprl_tpu.core import compile as jax_compile
-from sheeprl_tpu.models.models import MLP, CNN, DeCNN, LayerNorm, LayerNormGRUCell
+from sheeprl_tpu.models.models import MLP, TAPS, CNN, DeCNN, LayerNorm, LayerNormGRUCell, TapDot, kernel_taps
 from sheeprl_tpu.ops.distributions import (
     Independent,
     Normal,
@@ -320,6 +320,7 @@ class RecurrentModel(nn.Module):
             dtype=self.dtype,
             param_dtype=self.param_dtype,
             kernel_init=hafner_trunc_init,
+            dot_general_cls=TapDot,
         )(x)
         return LayerNormGRUCell(
             hidden_size=self.recurrent_state_size,
@@ -329,6 +330,7 @@ class RecurrentModel(nn.Module):
             dtype=self.dtype,
             param_dtype=self.param_dtype,
             kernel_init=hafner_trunc_init,
+            dot_general_cls=TapDot,
         )(feat, recurrent_state)
 
 
@@ -361,6 +363,7 @@ class MLPWithHead(nn.Module):
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 kernel_init=hafner_trunc_init,
+                dot_general_cls=TapDot,
             )(x)
         head_init = (
             hafner_uniform_init(self.head_init_scale)
@@ -372,6 +375,7 @@ class MLPWithHead(nn.Module):
             dtype=self.dtype,
             param_dtype=self.param_dtype,
             kernel_init=head_init,
+            dot_general_cls=TapDot,
             name="head",
         )(x)
 
@@ -623,9 +627,10 @@ class RSSM:
         self.unimix = unimix
         self.learnable_initial_recurrent_state = learnable_initial_recurrent_state
         self.decoupled = decoupled
-        # lax.scan unroll factor for the T-step dynamic scan: the per-step matmuls
-        # ([B,~1.5k]x[~1.5k,512] at the S preset) are small for the MXU, so unrolling
-        # lets XLA overlap/pipeline consecutive steps' HBM reads and MXU work
+        # lax.scan unroll factor for the T-step dynamic scan: a step's matmuls have
+        # B rows, so at every preset the step is bound by reading its kernels
+        # ([5120, 12288] for XL's GRU), not by the MXU; unrolling lets XLA overlap
+        # consecutive steps' HBM reads and MXU work
         self.dynamic_scan_unroll = int(dynamic_scan_unroll)
         # world_model.kernels knob: off/auto/pallas/interpret/reference. Anything
         # but "off" routes the dynamic/imagination steps through the fused Pallas
@@ -710,11 +715,17 @@ class RSSM:
         embedded_obs: jax.Array,
         is_first: jax.Array,
         key: jax.Array,
+        initial_states: Optional[Tuple[jax.Array, jax.Array]] = None,
     ):
-        """One step of dynamic learning (reference agent.py:396-435)."""
+        """One step of dynamic learning (reference agent.py:396-435).
+
+        ``initial_states`` is what `initial_states` returns for this batch; a
+        scan computes it once and passes it to every step."""
         k_prior, k_post = jax.random.split(key)
         action = (1 - is_first) * action
-        init_rec, init_post = self.initial_states(wm_params, recurrent_state.shape[:-1])
+        if initial_states is None:
+            initial_states = self.initial_states(wm_params, recurrent_state.shape[:-1])
+        init_rec, init_post = initial_states
         recurrent_state = (1 - is_first) * recurrent_state + is_first * init_rec
         posterior_flat = (1 - is_first) * posterior_flat + is_first * init_post
         recurrent_state = self._recurrent(wm_params, posterior_flat, action, recurrent_state)
@@ -723,6 +734,16 @@ class RSSM:
             wm_params, embedded_obs, k_post, recurrent_state=recurrent_state
         )
         return recurrent_state, posterior, prior, posterior_logits, prior_logits
+
+    def _scan_taps(self, wm_params, names: Sequence[str], T: int, B: int):
+        """The taps (`kernel_taps`) of the models ``names``, each of which the
+        step of a scan of T steps at batch B applies ONCE: they go into the
+        scan's ``xs``, and `_with_taps` puts a step's slice beside the params."""
+        return {name: kernel_taps(wm_params[name]["params"], (T, B), getattr(self, name).dtype) for name in names}
+
+    @staticmethod
+    def _with_taps(wm_params, taps_t):
+        return {**wm_params, **{name: {**wm_params[name], TAPS: taps} for name, taps in taps_t.items()}}
 
     def dynamic_scan(
         self,
@@ -733,6 +754,24 @@ class RSSM:
         key: jax.Array,
     ):
         """lax.scan over the sequence dim: the hot loop of world-model learning.
+
+        **What leaves the scan stacked, and why.** The step closes over
+        ``wm_params``, and the transpose of a ``lax.scan`` sums the cotangent of
+        what its step closes over in the backward scan's carry. For a dense
+        kernel that is a read and a write of the whole float32 kernel in every
+        step, to add a product of B rows: at XL's GRU kernel (``[5120, 12288]``,
+        252 MB) 0.5 GB a step, 64 times, where the same gradient as one
+        contraction over ``[T, B]`` writes it once. So every dense kernel of the
+        step (GRU, its input projection, the representation and transition
+        trunks and heads: each is a `TapDot`) enters its product under
+        ``stop_gradient``, and the backward scan emits, stacked over T, that
+        product's input ``[B, in]`` and the cotangent at its output ``[B, out]``
+        as the cotangent of per-step zeros (`kernel_taps`), whose own backward
+        is the contraction. LayerNorm scales, biases and
+        ``initial_recurrent_state`` are kilobytes and stay in the carry. The
+        learned initial state is computed once, before the scan, for the same
+        reason: inside the step it would apply the transition model a second
+        time with the same tap.
 
         With ``kernels != off`` the non-decoupled path dispatches to the fused
         step (ops/pallas/rssm_step.py): same return contract, logits in f32,
@@ -749,6 +788,7 @@ class RSSM:
         keys = jax.random.split(key, T)
         init_rec = jnp.zeros((B, self.recurrent_model.recurrent_state_size), dtype=embedded_obs.dtype)
         init_post = jnp.zeros((B, self.stoch_state_size), dtype=embedded_obs.dtype)
+        initial_states = self.initial_states(wm_params, (B,))
 
         if self.decoupled:
             # representation is independent of the recurrent state: batch it over [T,B]
@@ -760,20 +800,22 @@ class RSSM:
             posteriors_logits, posteriors = jax.vmap(rep)(embedded_obs, post_keys)
             posteriors_flat = posteriors.reshape(T, B, -1)
             prev_posts = jnp.concatenate([jnp.zeros_like(posteriors_flat[:1]), posteriors_flat[:-1]], axis=0)
+            taps = self._scan_taps(wm_params, ("recurrent_model", "transition_model"), T, B)
 
             def step(carry, xs):
                 recurrent_state = carry
-                prev_post, action, is_f, k = xs
+                prev_post, action, is_f, k, taps_t = xs
+                params_t = self._with_taps(wm_params, taps_t)
                 action = (1 - is_f) * action
-                init_r, init_p = self.initial_states(wm_params, recurrent_state.shape[:-1])
+                init_r, init_p = initial_states
                 recurrent_state = (1 - is_f) * recurrent_state + is_f * init_r
                 prev_post = (1 - is_f) * prev_post + is_f * init_p
-                recurrent_state = self._recurrent(wm_params, prev_post, action, recurrent_state)
-                prior_logits, _ = self._transition(wm_params, recurrent_state, k)
+                recurrent_state = self._recurrent(params_t, prev_post, action, recurrent_state)
+                prior_logits, _ = self._transition(params_t, recurrent_state, k)
                 return recurrent_state, (recurrent_state, prior_logits)
 
             _, (recurrent_states, priors_logits) = jax.lax.scan(
-                step, init_rec, (prev_posts, actions, is_first, keys), unroll=self.dynamic_scan_unroll
+                step, init_rec, (prev_posts, actions, is_first, keys, taps), unroll=self.dynamic_scan_unroll
             )
             # logits leave flat [T,B,S*D]; expose factorized [T,B,S,D] (the shape the
             # KL-balance loss and entropy metrics expect, reference loss.py:45-70)
@@ -781,17 +823,20 @@ class RSSM:
             posteriors_logits = posteriors_logits.reshape(T, B, self.stochastic_size, self.discrete_size)
             return recurrent_states, posteriors, priors_logits, posteriors_logits
 
+        taps = self._scan_taps(wm_params, ("recurrent_model", "representation_model", "transition_model"), T, B)
+
         def step(carry, xs):
             recurrent_state, posterior_flat = carry
-            action, embedded, is_f, k = xs
+            action, embedded, is_f, k, taps_t = xs
+            params_t = self._with_taps(wm_params, taps_t)
             recurrent_state, posterior, prior, post_logits, prior_logits = self.dynamic_step(
-                wm_params, posterior_flat, recurrent_state, action, embedded, is_f, k
+                params_t, posterior_flat, recurrent_state, action, embedded, is_f, k, initial_states
             )
             new_carry = (recurrent_state, posterior.reshape(*posterior.shape[:-2], -1))
             return new_carry, (recurrent_state, posterior, post_logits, prior_logits)
 
         _, (recurrent_states, posteriors, posteriors_logits, priors_logits) = jax.lax.scan(
-            step, (init_rec, init_post), (actions, embedded_obs, is_first, keys), unroll=self.dynamic_scan_unroll
+            step, (init_rec, init_post), (actions, embedded_obs, is_first, keys, taps), unroll=self.dynamic_scan_unroll
         )
         # factorized logits [T,B,S,D]: categorical_kl and the entropy metrics softmax
         # per-categorical over D, not over the flat S*D vector

@@ -15,12 +15,15 @@ LayerNormChannelLast (:507), LayerNorm (:521) — re-designed for TPU:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax import traverse_util
+from jax import lax
 
 ModuleType = Any
 Dtype = Any
@@ -62,6 +65,113 @@ def _per_layer(spec, n: int) -> Sequence:
 
 def orthogonal_init(scale: float = 2**0.5):
     return nn.initializers.orthogonal(scale)
+
+
+# ---- Weight gradients of a scanned step, taken outside the scan -------------
+#
+# A ``lax.scan`` whose step closes over a kernel sums that kernel's gradient in
+# the backward scan's carry: every step reads and writes the whole float32
+# kernel to add a product of rank B. `TapDot` and `kernel_taps` are the two
+# halves of the way around it. Inside the step the kernel enters the product
+# under ``stop_gradient``, so the scan has nothing to carry for it, and the
+# product's input and the cotangent at its output leave the backward scan as the
+# "cotangent" of a per-step tap (the cotangent of a scanned input is stacked,
+# never summed). Outside, `kernel_taps` made those taps from the kernels, and its
+# backward contracts the two stacks over every leading axis: one matmul.
+
+TAPS = "taps"  # the flax collection a scan step passes beside ``params``
+
+
+@jax.custom_vjp
+def _tapped_matmul(x: jax.Array, w: jax.Array, tap: Tuple[jax.Array, jax.Array]) -> jax.Array:
+    return lax.dot_general(x, w, (((x.ndim - 1,), (0,)), ((), ())))
+
+
+def _tapped_matmul_fwd(x, w, tap):
+    return _tapped_matmul(x, w, tap), (x, w)
+
+
+def _tapped_matmul_bwd(res, dy):
+    x, w = res
+    dx = lax.dot_general(dy, w, (((dy.ndim - 1,), (1,)), ((), ())))
+    # the tap's "cotangent" is the pair `_kernel_taps_bwd` multiplies; ``w``
+    # came in under stop_gradient, so its zeros are dropped, never summed
+    return dx, jnp.zeros_like(w), (x, dy)
+
+
+_tapped_matmul.defvjp(_tapped_matmul_fwd, _tapped_matmul_bwd)
+
+
+class TapDot(nn.Module):
+    """``dot_general_cls`` of a dense layer whose kernel a scan's step closes over.
+
+    It is ``lax.dot_general`` unless the caller's variables hold a tap for it
+    (`kernel_taps`): then the kernel's gradient does not flow through the
+    product but through the tap, as the stacked pair (input, output cotangent).
+    One tap serves ONE product: a module applied twice with the same tap would
+    have its two inputs and its two cotangents added before they are multiplied.
+    """
+
+    def __call__(self, lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):
+        if not self.has_variable(TAPS, "x"):
+            return lax.dot_general(
+                lhs, rhs, dimension_numbers, precision=precision, preferred_element_type=preferred_element_type
+            )
+        if dimension_numbers != (((lhs.ndim - 1,), (0,)), ((), ())) or precision is not None:
+            raise ValueError(f"a tapped product is a plain `x @ w`, got {dimension_numbers}, precision={precision}")
+        tap = (self.get_variable(TAPS, "x"), self.get_variable(TAPS, "y"))
+        return _tapped_matmul(lhs, lax.stop_gradient(rhs), tap)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _kernel_taps(kernels: Dict[Tuple[str, ...], jax.Array], lead_shape: Tuple[int, ...], dtype: Any):
+    return {
+        path: (jnp.zeros((*lead_shape, w.shape[0]), dtype), jnp.zeros((*lead_shape, w.shape[1]), dtype))
+        for path, w in kernels.items()
+    }
+
+
+def _kernel_taps_fwd(kernels, lead_shape, dtype):
+    return _kernel_taps(kernels, lead_shape, dtype), kernels
+
+
+def _kernel_taps_bwd(lead_shape, dtype, kernels, cts):
+    lead = tuple(range(len(lead_shape)))
+    return (
+        {
+            # the operands are the ones a per-step product has (compute dtype);
+            # the sum over every leading axis is in float32 and rounded once
+            path: lax.dot_general(*cts[path], ((lead, lead), ((), ())), preferred_element_type=jnp.float32).astype(
+                w.dtype
+            )
+            for path, w in kernels.items()
+        },
+    )
+
+
+_kernel_taps.defvjp(_kernel_taps_fwd, _kernel_taps_bwd)
+
+
+def kernel_taps(params: Dict[str, Any], lead_shape: Sequence[int], dtype: Any) -> Dict[str, Any]:
+    """The `TAPS` collection for the `TapDot` of every dense kernel in ``params``.
+
+    Zeros of shape ``[*lead_shape, in]`` and ``[*lead_shape, out]`` in the
+    compute ``dtype``, with ``lead_shape = (T, B)`` for a scan of T steps at
+    batch B: scan over them, and pass each step's slice to ``apply`` beside
+    ``params``. Their values are never read. Their cotangent is, by this
+    function's backward, each kernel's gradient: ``einsum("tbk,tbn->kn")`` of
+    what the products sent back. A kernel whose product is no `TapDot` keeps its
+    ordinary gradient, and a tap nothing uses adds zero to it.
+    """
+    kernels = {
+        path[:-1]: w
+        for path, w in traverse_util.flatten_dict(params).items()
+        if path[-1] == "kernel" and w.ndim == 2
+    }
+    taps = _kernel_taps(kernels, tuple(lead_shape), jnp.dtype(dtype))
+    return traverse_util.unflatten_dict(
+        {(*path, f"{TapDot.__name__}_0", name): tap for path, xy in taps.items() for name, tap in zip("xy", xy)}
+    )
 
 
 class LayerNorm(nn.Module):
@@ -121,6 +231,7 @@ class MLP(nn.Module):
     param_dtype: Dtype = jnp.float32
     kernel_init: Optional[Callable] = None
     bias_init: Callable = nn.initializers.zeros_init()
+    dot_general_cls: Any = None  # handed to every ``nn.Dense`` (`TapDot` for a scanned MLP)
 
     @property
     def out_features(self) -> int:
@@ -152,6 +263,7 @@ class MLP(nn.Module):
                 param_dtype=self.param_dtype,
                 kernel_init=kernel_init,
                 bias_init=self.bias_init,
+                dot_general_cls=self.dot_general_cls,
             )(x)
             if drops[i]:
                 x = nn.Dropout(rate=drops[i])(x, deterministic=deterministic)
@@ -165,6 +277,7 @@ class MLP(nn.Module):
                 param_dtype=self.param_dtype,
                 kernel_init=kernel_init,
                 bias_init=self.bias_init,
+                dot_general_cls=self.dot_general_cls,
             )(x)
         return x
 
@@ -343,6 +456,7 @@ class LayerNormGRUCell(nn.Module):
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
     kernel_init: Optional[Callable] = None
+    dot_general_cls: Any = None  # as ``nn.Dense``'s (`TapDot` for a scanned cell)
 
     @nn.compact
     def __call__(self, x: jax.Array, h: jax.Array) -> jax.Array:
@@ -360,7 +474,8 @@ class LayerNormGRUCell(nn.Module):
             ln_bias = self.param("ln_bias", nn.initializers.zeros_init(), (n,), jnp.float32)
 
         xh = jnp.concatenate([h.astype(self.dtype), x.astype(self.dtype)], axis=-1)
-        fused = xh @ kernel.astype(self.dtype)
+        dot_general = self.dot_general_cls() if self.dot_general_cls is not None else lax.dot_general
+        fused = dot_general(xh, kernel.astype(self.dtype), (((xh.ndim - 1,), (0,)), ((), ())))
         if bias is not None:
             fused = fused + bias.astype(self.dtype)
         if self.layer_norm:
