@@ -15,6 +15,7 @@ import flax.linen as nn
 import gymnasium
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from sheeprl_tpu.core import compile as jax_compile
 from sheeprl_tpu.algos.ppo.agent import CNNEncoder, MLPEncoder, evaluate_actions, sample_actions
@@ -83,9 +84,34 @@ class RecurrentPPOAgent(nn.Module):
     critic_cfg: Dict[str, Any]
     dtype: Any = jnp.float32
 
+    # ----- the sequence-policy seam of ppo_recurrent (token_agent.TokenPolicy is the other side of it)
+    starts_at_reset = False  # the state is stored per step, so a training sequence may start anywhere
+
     @property
     def rnn_hidden_size(self) -> int:
         return self.rnn_cfg["lstm"]["hidden_size"]
+
+    @property
+    def action_width(self) -> int:
+        """Numbers a stored action takes: the concatenated one-hot / continuous vector."""
+        return sum(self.actions_dim)
+
+    def loss_mask(self, batch: Dict[str, jax.Array]) -> jax.Array:
+        return batch["mask"]
+
+    def evaluate(self, params, batch: Dict[str, jax.Array], norm_obs: Dict[str, jax.Array]):
+        """(log-prob, entropy, value) of the batch's actions, each [T, B, 1], from the
+        stored state of each sequence's first step; no extra metrics."""
+        actions = (
+            jnp.split(batch["actions"], np.cumsum(self.actions_dim)[:-1].tolist(), axis=-1)
+            if len(self.actions_dim) > 1
+            else [batch["actions"]]
+        )
+        actor_outs, values, _ = self.apply(
+            params, norm_obs, batch["prev_actions"], (batch["prev_hx"][0], batch["prev_cx"][0]), batch["mask"]
+        )
+        new_logprobs, entropy = evaluate_actions(actor_outs, actions, self.is_continuous, self.distribution)
+        return new_logprobs, entropy, values, {}
 
     def setup(self) -> None:
         cnn_encoder = (
@@ -207,11 +233,20 @@ class RecurrentPPOPlayer:
         self._act_impl = _act
         self._packed_act_fns: Dict[Any, Any] = {}
 
-    def initial_states(self, hidden_size: int):
+    def initial_states(self, hidden_size: Optional[int] = None, num_envs: Optional[int] = None):
+        hidden_size = hidden_size or self.agent.rnn_hidden_size
+        n = num_envs or self.num_envs
         return (
-            jnp.zeros((self.num_envs, hidden_size), dtype=jnp.float32),
-            jnp.zeros((self.num_envs, hidden_size), dtype=jnp.float32),
+            jnp.zeros((n, hidden_size), dtype=jnp.float32),
+            jnp.zeros((n, hidden_size), dtype=jnp.float32),
         )
+
+    def state_rows(self, states) -> Dict[str, jax.Array]:
+        """The state a step was taken from, as the rollout's rows of that step."""
+        return {"prev_hx": states[0], "prev_cx": states[1]}
+
+    def reset_states(self, states, not_done: jax.Array):
+        return tuple(not_done * s for s in states)
 
     def __call__(self, obs, prev_actions, prev_states, key, greedy: bool = False):
         return self._act(self.params, obs, prev_actions, prev_states, key, greedy)
@@ -249,6 +284,10 @@ def build_agent(
     obs_space: gymnasium.spaces.Dict,
     agent_state: Optional[Dict[str, Any]] = None,
 ) -> Tuple[RecurrentPPOAgent, Any, RecurrentPPOPlayer]:
+    if str(cfg.algo.get("policy", "lstm")).lower() == "lm":
+        from sheeprl_tpu.algos.ppo_recurrent.token_agent import build_token_agent
+
+        return build_token_agent(runtime, actions_dim, is_continuous, cfg, obs_space, agent_state)
     distribution = cfg.distribution.get("type", "auto").lower()
     if distribution == "auto":
         distribution = "normal" if is_continuous else "discrete"

@@ -9,6 +9,7 @@ masked losses) retraces only on bucket growth — not every iteration.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import warnings
 from typing import Any, Dict, List
@@ -29,6 +30,7 @@ from sheeprl_tpu.core import health as health_mod
 from sheeprl_tpu.core import resilience
 from sheeprl_tpu.core.pipeline import AsyncEnvStepper, PackedObsCodec, pipeline_enabled
 from sheeprl_tpu.data.factory import make_rollout_buffer
+from sheeprl_tpu.telemetry import trace
 from sheeprl_tpu.utils.env import finished_episodes, make_env
 from sheeprl_tpu.utils.logger import get_log_dir, get_logger
 from sheeprl_tpu.utils.metric import MetricAggregator, SumMetric
@@ -51,16 +53,8 @@ def make_train_fn(agent, tx, cfg, runtime, obs_keys, cnn_keys, params_sync=None)
 
     def loss_fn(params, batch, clip_coef, ent_coef):
         norm_obs = normalize_obs(batch, cnn_keys, obs_keys)
-        actions = (
-            jnp.split(batch["actions"], np.cumsum(agent.actions_dim)[:-1].tolist(), axis=-1)
-            if len(agent.actions_dim) > 1
-            else [batch["actions"]]
-        )
-        mask = batch["mask"]
-        actor_outs, values, _ = agent.apply(
-            params, norm_obs, batch["prev_actions"], (batch["prev_hx"], batch["prev_cx"]), mask
-        )
-        new_logprobs, entropy = evaluate_actions(actor_outs, actions, agent.is_continuous, agent.distribution)
+        mask = agent.loss_mask(batch)
+        new_logprobs, entropy, values, extras = agent.evaluate(params, batch, norm_obs)
         advantages = batch["advantages"]
         if cfg.algo.normalize_advantages:
             # masked normalization (reference ppo_recurrent.py:77-81)
@@ -79,7 +73,7 @@ def make_train_fn(agent, tx, cfg, runtime, obs_keys, cnn_keys, params_sync=None)
             v_loss = _masked_mean((values - batch["returns"]) ** 2, mask)
         ent_loss = -_masked_mean(entropy, mask)
         total = pg_loss + cfg.algo.vf_coef * v_loss + cfg.algo.ent_coef * ent_loss
-        return total, (pg_loss, v_loss, ent_loss)
+        return total, ((pg_loss, v_loss, ent_loss), extras)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -97,25 +91,23 @@ def make_train_fn(agent, tx, cfg, runtime, obs_keys, cnn_keys, params_sync=None)
             batch = jax.tree_util.tree_map(
                 lambda v: jax.lax.with_sharding_constraint(jnp.take(v, idx, axis=1), data_sharding), data
             )
-            # initial LSTM states of each sequence: [B, H]
-            batch = dict(batch)
-            batch["prev_hx"] = batch["prev_hx"][0]
-            batch["prev_cx"] = batch["prev_cx"][0]
-            (loss, (pg, vl, ent)), grads = grad_fn(params, batch, clip_coef, ent_coef)
-            gnorm = optax.global_norm(grads)
-            updates, new_opt_state = tx.update(grads, opt_state, params)
-            # health-sentinel LR backoff: traced scalar operand; 1.0 is IEEE-exact
-            updates = jax.tree_util.tree_map(lambda u: u * lr_scale, updates)
-            new_params = optax.apply_updates(params, updates)
-            if nonfinite_guard:
-                (params, opt_state), skipped = resilience.finite_or_skip(
-                    (loss, gnorm), (new_params, new_opt_state), (params, opt_state)
-                )
-            else:
-                params, opt_state, skipped = new_params, new_opt_state, jnp.float32(0.0)
-            return (params, opt_state), jnp.stack([pg, vl, ent, skipped, gnorm])
+            with jax.named_scope("ppo.loss"):
+                (loss, ((pg, vl, ent), extras)), grads = grad_fn(params, batch, clip_coef, ent_coef)
+            with jax.named_scope("ppo.opt"):
+                gnorm = optax.global_norm(grads)
+                updates, new_opt_state = tx.update(grads, opt_state, params)
+                # health-sentinel LR backoff: traced scalar operand; 1.0 is IEEE-exact
+                updates = jax.tree_util.tree_map(lambda u: u * lr_scale, updates)
+                new_params = optax.apply_updates(params, updates)
+                if nonfinite_guard:
+                    (params, opt_state), skipped = resilience.finite_or_skip(
+                        (loss, gnorm), (new_params, new_opt_state), (params, opt_state)
+                    )
+                else:
+                    params, opt_state, skipped = new_params, new_opt_state, jnp.float32(0.0)
+            return (params, opt_state), (jnp.stack([pg, vl, ent, skipped, gnorm]), extras)
 
-        (params, opt_state), losses = jax.lax.scan(minibatch_step, (params, opt_state), perms)
+        (params, opt_state), (losses, extras) = jax.lax.scan(minibatch_step, (params, opt_state), perms)
         metrics = losses.mean(axis=0)
         flat_params = params_sync.ravel(params) if params_sync is not None else jnp.zeros(())
         return params, opt_state, flat_params, {
@@ -124,22 +116,37 @@ def make_train_fn(agent, tx, cfg, runtime, obs_keys, cnn_keys, params_sync=None)
             "Loss/entropy_loss": metrics[2],
             "Resilience/nonfinite_skips": losses[:, 3].sum(),
             "Grads/global_norm": metrics[4],
+            # the policy's own counters (a language model's expert layers), of the last minibatch
+            **{k: v[-1] for k, v in extras.items()},
         }
 
     return jax_compile.guarded_jit(train, name="ppo_recurrent.train", donate_argnums=(0, 1))
 
 
-def _chunk_and_pad(local_data: Dict[str, np.ndarray], dones: np.ndarray, sl: int, n_envs: int):
+def _chunk_and_pad(
+    local_data: Dict[str, np.ndarray], dones: np.ndarray, sl: int, n_envs: int, starts_at_reset: bool = False
+):
     """Split the rollout into per-env episodes, chunk to length <= sl, pad + mask.
 
     Returns dict of arrays [sl, n_seq_padded, ...] with a `mask` key; n_seq is
     bucketed to the next power of two (zero-mask padding) for jit-shape stability.
+    ``starts_at_reset``: the policy evaluates every sequence from its empty state,
+    so each sequence has to be a whole episode; anything else is an error here.
     """
     sequences: Dict[str, List[np.ndarray]] = {k: [] for k in local_data.keys()}
     lengths: List[int] = []
     T = next(iter(local_data.values())).shape[0]
     for env_id in range(n_envs):
         ends = np.nonzero(dones[:, env_id, 0])[0].tolist()
+        if starts_at_reset:
+            episode_lengths = np.diff([-1] + ends)
+            if not ends or ends[-1] != T - 1 or episode_lengths.max() > sl:
+                raise ValueError(
+                    "This policy keeps no state per step, so every training sequence must start at a reset and hold "
+                    f"a whole episode: env {env_id} ended episodes at steps {ends[:8]} of a rollout of {T} steps with "
+                    f"algo.per_rank_sequence_length={sl}. Make algo.rollout_steps a multiple of the episode length "
+                    "and algo.per_rank_sequence_length at least the episode length."
+                )
         ends.append(T - 1)
         start = 0
         for stop in ends:
@@ -154,6 +161,46 @@ def _chunk_and_pad(local_data: Dict[str, np.ndarray], dones: np.ndarray, sl: int
                 lengths.append(s1 - s0)
             start = stop + 1
     return jax_compile.bucketed_pad(sequences, lengths, sl)
+
+
+# `utils.gae` called eagerly traces and lowers its reverse scan anew in every call: 45-53 ms for a rollout
+# of 8,192 steps, on whichever device (my runs, PR 29). Compiled once it takes 0.4 ms, and gives the same bits.
+_gae = jax_compile.guarded_jit(gae, name="ppo_recurrent.gae", static_argnums=(4, 5, 6))
+
+
+def rollout_feed(
+    local_data: Dict[str, np.ndarray],
+    next_values: np.ndarray,
+    cfg,
+    n_envs: int,
+    starts_at_reset: bool = False,
+    host_device: Any = None,
+) -> Dict[str, jax.Array]:
+    """A finished rollout ``[T, n_envs, ...]`` on the host -> the train call's data on
+    the device: GAE, the split into sequences (padded, masked, bucketed) and one
+    transfer per key. ``main()`` and the chip benchmark's window both call this.
+
+    GAE runs where the rollout is, on ``host_device``: its reverse scan is one tiny
+    step for each of the rollout's steps, nothing for an accelerator, and the rollout
+    need not travel there and back for it."""
+    with trace.span("rollout.feed") as sp:
+        with jax.default_device(host_device) if host_device is not None else contextlib.nullcontext():
+            returns, advantages = _gae(
+                jnp.asarray(local_data["rewards"]),
+                jnp.asarray(local_data["values"]),
+                jnp.asarray(local_data["dones"]),
+                next_values,
+                cfg.algo.rollout_steps,
+                cfg.algo.gamma,
+                cfg.algo.gae_lambda,
+            )
+        local_data["returns"] = np.asarray(returns, dtype=np.float32)
+        local_data["advantages"] = np.asarray(advantages, dtype=np.float32)
+        padded = _chunk_and_pad(
+            local_data, local_data["dones"], cfg.algo.per_rank_sequence_length, n_envs, starts_at_reset
+        )
+        sp.set(bytes=sum(v.nbytes for v in padded.values()))
+        return {k: jnp.asarray(v) for k, v in padded.items()}
 
 
 @register_algorithm()
@@ -200,6 +247,22 @@ def main(runtime, cfg: Dict[str, Any]):
             "The rollout steps must be a multiple of the per_rank_sequence_length, got "
             f"{cfg.algo.rollout_steps} and {cfg.algo.per_rank_sequence_length}"
         )
+    if str(cfg.algo.get("policy", "lstm")).lower() == "lm":
+        # a policy whose sequences must start at resets: it stores no state per step, so a training
+        # sequence is a whole episode, evaluated from the empty state, and fits the model's positions
+        if not cfg.algo.reset_recurrent_state_on_done:
+            raise ValueError(
+                "algo.policy=lm evaluates every training sequence from the empty state, so its state has to be "
+                "reset when an episode ends: set algo.reset_recurrent_state_on_done=True"
+            )
+        if cfg.algo.per_rank_sequence_length > cfg.algo.lm.max_positions:
+            raise ValueError(
+                "algo.policy=lm: a training sequence is a whole episode and has to fit the model's positions, got "
+                f"algo.per_rank_sequence_length={cfg.algo.per_rank_sequence_length} and "
+                f"algo.lm.max_positions={cfg.algo.lm.max_positions}"
+            )
+        if cfg.buffer.get("backend", "host") != "host":
+            raise ValueError("algo.policy=lm keeps its rollout on the host (buffer.backend=host)")
 
     is_continuous = isinstance(envs.single_action_space, gym.spaces.Box)
     is_multidiscrete = isinstance(envs.single_action_space, gym.spaces.MultiDiscrete)
@@ -241,15 +304,21 @@ def main(runtime, cfg: Dict[str, Any]):
     last_log = state["last_log"] if state else 0
     last_checkpoint = state["last_checkpoint"] if state else 0
 
-    params_sync = PlayerParamsSync(player.params)
+    # a player on the mesh device acts with the learner's own arrays (rebound after every train
+    # call, as DreamerPlayerSync does): no flat copy of the parameters is made or moved
+    params_sync = PlayerParamsSync(player.params) if runtime.player_on_host else None
+    if params_sync is None:
+        player.params = params  # from the first step on, not only after the first update: one sharding, one trace
     train_fn = make_train_fn(agent, tx, cfg, runtime, obs_keys, cnn_keys, params_sync)
     profiler = TraceProfiler(cfg.metric.get("profiler"), log_dir if runtime.is_global_zero else None)
     rng = jax.random.PRNGKey(cfg.seed)
-    player_rng = jax.device_put(jax.random.PRNGKey(cfg.seed + 1), runtime.player_device)
+    # where the player's key lives: beside its parameters, under their sharding, so that the act program is
+    # traced once (a player on the mesh device has the learner's replicated arrays, not single-device ones)
+    player_placement = runtime.player_device if runtime.player_on_host else runtime.replicated
+    player_rng = jax.device_put(jax.random.PRNGKey(cfg.seed + 1), player_placement)
     if state and "rng" in state:
         rng = jnp.asarray(state["rng"])
-        player_rng = jax.device_put(jnp.asarray(state["player_rng"]), runtime.player_device)
-    h = cfg.algo.rnn.lstm.hidden_size
+        player_rng = jax.device_put(jnp.asarray(state["player_rng"]), player_placement)
 
     step_data = {}
     reset_obs = envs.reset(seed=cfg.seed)[0]
@@ -260,8 +329,8 @@ def main(runtime, cfg: Dict[str, Any]):
             _obs = _obs.reshape(n_envs, -1, *_obs.shape[-2:])
         next_obs[k] = _obs
         step_data[k] = _obs[np.newaxis]
-    prev_states = player.initial_states(h)
-    prev_actions = np.zeros((n_envs, sum(actions_dim)), dtype=np.float32)
+    prev_states = player.initial_states()
+    prev_actions = np.zeros((n_envs, agent.action_width), dtype=np.float32)
 
     # ----- software pipeline (core/pipeline.py): same structure as ppo.py; the
     # recurrent state feedback (prev_actions/prev_states) stays immediate after
@@ -295,8 +364,8 @@ def main(runtime, cfg: Dict[str, Any]):
             step_data["actions"] = np.asarray(pending["cat_actions"]).reshape(1, n_envs, -1)
             step_data["logprobs"] = np.asarray(pending["logprobs"]).reshape(1, n_envs, 1)
             step_data["rewards"] = pending["rewards"][np.newaxis]
-            step_data["prev_hx"] = np.asarray(pending["prev_hx"]).reshape(1, n_envs, -1)
-            step_data["prev_cx"] = np.asarray(pending["prev_cx"]).reshape(1, n_envs, -1)
+            for k, v in pending["state_rows"].items():
+                step_data[k] = np.asarray(v).reshape(1, n_envs, -1)
             step_data["prev_actions"] = np.asarray(pending["prev_actions"]).reshape(1, n_envs, -1)
             rb.add(step_data, validate_args=cfg.buffer.validate_args)
             for k in obs_keys:
@@ -344,14 +413,16 @@ def main(runtime, cfg: Dict[str, Any]):
                         if pending
                         else zero_extra,
                     )
-                    cat_actions, env_actions, logprobs, values, states, player_rng = player.act_packed(
-                        codec,
-                        packed,
-                        prev_actions,
-                        prev_states,
-                        player_rng,
-                    )
-                    real_actions = np.asarray(env_actions)
+                    state_rows = player.state_rows(prev_states)  # before the act: it may donate the state
+                    with trace.span("player.decode"):
+                        cat_actions, env_actions, logprobs, values, states, player_rng = player.act_packed(
+                            codec,
+                            packed,
+                            prev_actions,
+                            prev_states,
+                            player_rng,
+                        )
+                        real_actions = np.asarray(env_actions)
                     stepper.step_async(real_actions.reshape(envs.action_space.shape))
 
                     # ---- overlap window: env workers are stepping; close out the
@@ -365,8 +436,7 @@ def main(runtime, cfg: Dict[str, Any]):
                                 "values": jnp.reshape(values, (n_envs, 1)),
                                 "actions": jnp.reshape(cat_actions, (n_envs, -1)),
                                 "logprobs": jnp.reshape(logprobs, (n_envs, 1)),
-                                "prev_hx": jnp.reshape(prev_states[0], (n_envs, -1)),
-                                "prev_cx": jnp.reshape(prev_states[1], (n_envs, -1)),
+                                **{k: jnp.reshape(v, (n_envs, -1)) for k, v in state_rows.items()},
                                 "prev_actions": jnp.reshape(jnp.asarray(prev_actions), (n_envs, -1)),
                             }
                         )
@@ -387,7 +457,7 @@ def main(runtime, cfg: Dict[str, Any]):
                                 if k in cnn_keys:
                                     v = v.reshape(-1, *v.shape[-2:]) / 255.0 - 0.5
                                 f_obs[k] = jnp.asarray(v)[None, None]
-                            te_states = tuple(s[te : te + 1] for s in states)
+                            te_states = jax.tree_util.tree_map(lambda s: s[te : te + 1], states)
                             te_prev_act = jnp.asarray(cat_actions).reshape(n_envs, -1)[te : te + 1][None]
                             val, _ = player.get_values(f_obs, te_prev_act, te_states)
                             rewards[te] += cfg.algo.gamma * float(np.asarray(val).reshape(-1)[0])
@@ -405,8 +475,7 @@ def main(runtime, cfg: Dict[str, Any]):
                     values=values,
                     cat_actions=cat_actions,
                     logprobs=logprobs,
-                    prev_hx=prev_states[0],
-                    prev_cx=prev_states[1],
+                    state_rows=state_rows,
                     prev_actions=prev_actions,
                 )
 
@@ -422,7 +491,7 @@ def main(runtime, cfg: Dict[str, Any]):
                 # reset recurrent state on done (reference :356-371)
                 if cfg.algo.reset_recurrent_state_on_done:
                     not_done = jnp.asarray(1.0 - dones, dtype=jnp.float32)
-                    prev_states = tuple(not_done * s for s in states)
+                    prev_states = player.reset_states(states, not_done)
                 else:
                     prev_states = states
 
@@ -450,21 +519,9 @@ def main(runtime, cfg: Dict[str, Any]):
                         prev_states,
                     )[0]
                 )
-                returns, advantages = gae(
-                    jnp.asarray(local_data["rewards"]),
-                    jnp.asarray(local_data["values"]),
-                    jnp.asarray(local_data["dones"]),
-                    next_values,
-                    cfg.algo.rollout_steps,
-                    cfg.algo.gamma,
-                    cfg.algo.gae_lambda,
+                device_data = rollout_feed(
+                    local_data, next_values, cfg, n_envs, agent.starts_at_reset, runtime.host_device
                 )
-                local_data["returns"] = np.asarray(returns, dtype=np.float32)
-                local_data["advantages"] = np.asarray(advantages, dtype=np.float32)
-                padded = _chunk_and_pad(
-                    local_data, local_data["dones"], cfg.algo.per_rank_sequence_length, n_envs
-                )
-                device_data = {k: jnp.asarray(v) for k, v in padded.items()}
                 rng, train_key = jax.random.split(rng)
                 params, opt_state, flat_params, train_metrics = train_fn(
                     params,
@@ -475,7 +532,9 @@ def main(runtime, cfg: Dict[str, Any]):
                     jnp.float32(cfg.algo.ent_coef),
                     jnp.float32(sentinel.lr_scale),
                 )
-                player.params = params_sync.pull(flat_params, runtime.player_device)
+                player.params = (
+                    params_sync.pull(flat_params, runtime.player_device) if params_sync is not None else params
+                )
                 if not timer.disabled:  # sync only when the train phase is being timed
                     jax.block_until_ready(params)
             train_step += world_size
@@ -547,9 +606,13 @@ def main(runtime, cfg: Dict[str, Any]):
                     if "rng" in rb_state:
                         rng = jnp.asarray(rb_state["rng"])
                         player_rng = jax.device_put(
-                            jnp.asarray(rb_state["player_rng"]), runtime.player_device
+                            jnp.asarray(rb_state["player_rng"]), player_placement
                         )
-                    player.params = params_sync.pull(params_sync.ravel(params), runtime.player_device)
+                    player.params = (
+                        params_sync.pull(params_sync.ravel(params), runtime.player_device)
+                        if params_sync is not None
+                        else params
+                    )
                     if sentinel.reseed_envs:
                         # fresh episode streams AND a clean recurrent state: the
                         # in-flight hidden state was produced by the poisoned policy
@@ -562,8 +625,8 @@ def main(runtime, cfg: Dict[str, Any]):
                                 _obs = _obs.reshape(n_envs, -1, *_obs.shape[-2:])
                             next_obs[k] = _obs
                             step_data[k] = _obs[np.newaxis]
-                        prev_states = player.initial_states(h)
-                        prev_actions = np.zeros((n_envs, sum(actions_dim)), dtype=np.float32)
+                        prev_states = player.initial_states()
+                        prev_actions = np.zeros((n_envs, agent.action_width), dtype=np.float32)
                     runtime.print(
                         f"Health rollback at policy_step={policy_step}: restored certified "
                         "checkpoint, training continues."

@@ -38,10 +38,10 @@ def test(player, runtime, cfg, log_dir: str) -> None:
     done = False
     cumulative_rew = 0.0
     obs = env.reset(seed=cfg.seed)[0]
-    key = jax.random.PRNGKey(cfg.seed)
-    h = player.agent.rnn_hidden_size
-    states = (jnp.zeros((1, h)), jnp.zeros((1, h)))
-    prev_actions = jnp.zeros((1, 1, sum(player.actions_dim)), dtype=jnp.float32)
+    # committed where the player is, like the key every later step returns: the act program is traced once
+    key = jax.device_put(jax.random.PRNGKey(cfg.seed), runtime.player_device)
+    states = player.initial_states(num_envs=1)
+    prev_actions = jnp.zeros((1, 1, player.agent.action_width), dtype=jnp.float32)
     while not done:
         jax_obs = prepare_obs(runtime, obs, cnn_keys=cfg.algo.cnn_keys.encoder)
         jax_obs = {k: v[None] for k, v in jax_obs.items()}

@@ -1,0 +1,96 @@
+"""The language-model policy's two costly parts compile for a described TPU v5e at the
+published widths and the benchmark cell's shapes: the attention layer through the stock
+Pallas flash-attention kernel (Mosaic has to take it: heads of 64, blocks of 1,024), and an
+expert layer through the stock Pallas grouped matmul with a row for every (token, slot) pair. No chip is
+attached: nothing runs, and nothing here is a device number.
+
+The topology is described inside a fixture, never while a module is imported (only one
+process may load the TPU's library, and every xdist worker imports every test file), and
+this is the only test file that describes one.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from sheeprl_tpu.models import lm
+
+LAYER_TYPES = ("conv", "conv", "full_attention", "conv", "conv", "conv") + ("full_attention", "conv", "conv", "conv") * 3 + (
+    "full_attention", "conv", "conv", "full_attention", "conv", "conv")
+B, T, D = 2, 8192, 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def cut():
+    return lm.LMConfig(
+        hidden_size=D, layers=(0, 2, 3, 4, 5), layer_types=LAYER_TYPES, num_dense_layers=2, intermediate_size=7168,
+        moe_intermediate_size=1792, num_experts=32, num_experts_per_tok=4, experts_held=8, vocab_held=16384,
+        num_attention_heads=32, num_key_value_heads=8, head_dim=64, max_positions=T,
+    )
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *specs):
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # such an entry cannot be read back without a chip
+    try:
+        # as the CLI sets it for every run (`float32_matmul_precision: high`): Mosaic has no "high", and the
+        # kernels have to be traced under their own default all the same
+        with jax.default_matmul_precision("high"):
+            return jax.jit(fn).lower(*specs).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def test_attention_layer_through_the_flash_kernel_compiles_for_v5e(one_chip, cut, monkeypatch):
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)  # no chip is attached here: the program would take its CPU branch
+    bf = jnp.bfloat16
+    p = {
+        "q": _spec((D, 2048), bf, one_chip), "k": _spec((D, 512), bf, one_chip), "v": _spec((D, 512), bf, one_chip),
+        "o": _spec((2048, D), bf, one_chip), "q_norm": _spec((64,), jnp.float32, one_chip), "k_norm": _spec((64,), jnp.float32, one_chip),
+    }
+    grad = jax.grad(lambda p, n: lm.attn_op(p, n, cut)[0].astype(jnp.float32).sum(), argnums=(0, 1))
+    text = _compile(grad, p, _spec((B, T, D), bf, one_chip)).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3  # the forward, dq and dkv kernels are in the program
+
+
+def test_expert_layer_compiles_for_v5e_and_fits(one_chip, cut, monkeypatch):
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)  # the Pallas grouped matmul, as on the chip
+    bf = jnp.bfloat16
+    p = {
+        "router": _spec((D, 32), jnp.float32, one_chip), "bias": _spec((32,), jnp.float32, one_chip),
+        "w1": _spec((8, D, 1792), bf, one_chip), "w3": _spec((8, D, 1792), bf, one_chip), "w2": _spec((8, 1792, D), bf, one_chip),
+    }
+    grad = jax.grad(lambda p, x: lm.moe_ffn(p, x, cut)[0].astype(jnp.float32).sum(), argnums=(0, 1), allow_int=True)
+    compiled = _compile(grad, p, _spec((B * T, D), bf, one_chip))
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 4e9  # a row for every pair, forwards and backwards, beside 9 GB of state
+    # three products forwards and, backwards, two for each of them: nine grouped-matmul kernels
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 9
+
+
+def test_a_decode_step_s_expert_layer_compiles_for_v5e(one_chip, cut, monkeypatch):
+    """Acting is two tokens a step: eight (token, slot) pairs, padded to one tile of the grouped matmul."""
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)
+    bf = jnp.bfloat16
+    p = {
+        "router": _spec((D, 32), jnp.float32, one_chip), "bias": _spec((32,), jnp.float32, one_chip),
+        "w1": _spec((8, D, 1792), bf, one_chip), "w3": _spec((8, D, 1792), bf, one_chip), "w2": _spec((8, 1792, D), bf, one_chip),
+    }
+    compiled = _compile(lambda p, x: lm.moe_ffn(p, x, cut)[0], p, _spec((2, D), bf, one_chip))
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 3
