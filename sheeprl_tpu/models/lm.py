@@ -14,8 +14,19 @@ final normed state.
 told which experts it holds (``expert_lo``, ``experts_held``), it routes over all
 ``num_experts``, computes its own experts' part of the result and leaves the
 other experts' part out. No token is dropped and there is no capacity factor: the
-(token, slot) pairs routed to the held experts are sorted by expert and are
-multiplied as ragged groups (:func:`grouped_dot`). On a TPU the grouped products
+(token, slot) pairs routed to the held experts are sorted by expert, in front of
+the others, and are multiplied as ragged groups (:func:`grouped_dot`). Rows are
+moved only for the pairs held here: the buffer between the routing and the
+products has :func:`compact_rows` rows, :data:`SLACK` times the held experts' even
+share of all pairs in whole row tiles of the grouped matmul, a width fixed by the
+shapes (no option sets it). How many pairs are held is the data's, so a layer
+whose held pairs pass that width takes the width of all pairs instead, under a
+``lax.cond`` (:func:`_held_experts`): the same result at the older speed, which is
+what keeps the layer dropless. ``Moe/compact_share`` (:func:`moe_metrics`) is the
+share of the expert layers that stayed within the buffer; under 1.0 it says that
+this chip's experts draw half as many pairs again as an even share, and the cure
+is the placement (deal the experts to the chips by their load, as the benchmark's
+set-up does), not a wider buffer. On a TPU the grouped products
 and the attention over whole sequences are the stock Pallas kernels (megablox
 ``gmm`` / ``tgmm``, flash attention); elsewhere ``jax.lax.ragged_dot`` and a
 blocked plain-JAX attention compute the same (:func:`on_tpu`). ``vocab_held`` is the slice of
@@ -355,36 +366,85 @@ def route(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig):
         return chosen, w * cfg.routed_scaling_factor
 
 
-@jax.custom_vjp
-def _rows_to_pairs(x: jax.Array, order: jax.Array, inverse: jax.Array) -> jax.Array:
-    """``x`` [N, D] -> a row for every (token, slot) pair in sorted order [N * k, D]. Going
-    backwards the pairs' cotangents come back by the inverse permutation and are summed over
-    a token's slots: a gather and a sum, where the gather's own transpose would scatter-add."""
-    return x[order // (order.shape[0] // x.shape[0])]
+def _sum_of_slots(rows: jax.Array, slots: jax.Array, here: jax.Array, weight: Optional[jax.Array] = None) -> jax.Array:
+    """``out[n] = sum_s rows[slots[n, s]]`` over the slots ``s`` where ``here[n, s]``, each times ``weight[n, s]``:
+    ``rows`` [R, D], the others [N, k] -> [N, D]. A gather of N rows a slot, added up in float32 and
+    rounded once, as ``jnp.sum`` does; one gather of N * k rows reshaped to [N, k, D] is the same sum,
+    but the chip lays k = 4 on a tiled axis and the reshape is a copy of every row (my chip runs, PR 30)."""
+    total = None
+    for s in range(slots.shape[1]):
+        term = jnp.where(here[:, s, None], rows[slots[:, s]], 0)
+        if weight is not None:
+            term = term * weight[:, s, None]
+        term = term.astype(jnp.float32)
+        total = term if total is None else total + term
+    return total.astype(rows.dtype)
 
 
-def _rows_to_pairs_fwd(x, order, inverse):
-    return _rows_to_pairs(x, order, inverse), (inverse, x.shape[0])
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_to_pairs(x: jax.Array, order: jax.Array, inverse: jax.Array, rows: int) -> jax.Array:
+    """``x`` [N, D] -> a row for each of the first ``rows`` (token, slot) pairs in sorted order
+    [rows, D]. Going backwards a pair finds its row's cotangent by the inverse permutation (the
+    pairs sorted past ``rows`` have none) and a token's slots are summed: a gather and a sum,
+    where the gather's own transpose would scatter-add."""
+    return x[order[:rows] // (order.shape[0] // x.shape[0])]
 
 
-def _rows_to_pairs_bwd(res, g):
+def _rows_to_pairs_fwd(x, order, inverse, rows):
+    return _rows_to_pairs(x, order, inverse, rows), (inverse, x.shape[0])
+
+
+def _rows_to_pairs_bwd(rows, res, g):
     inverse, n_rows = res
-    return jnp.sum(g[inverse].reshape(n_rows, -1, g.shape[-1]), axis=1), None, None
+    slots = inverse.reshape(n_rows, -1)
+    return _sum_of_slots(g, jnp.minimum(slots, rows - 1), slots < rows), None, None
 
 
 _rows_to_pairs.defvjp(_rows_to_pairs_fwd, _rows_to_pairs_bwd)
 
 
 @jax.custom_vjp
-def _pairs_to_slots(ys: jax.Array, order: jax.Array, inverse: jax.Array) -> jax.Array:
-    """The sorted pairs' results [N * k, D] back in (token, slot) order; backwards, the other permutation."""
-    return ys[inverse]
+def _pairs_to_rows(ys: jax.Array, weight: jax.Array, order: jax.Array, inverse: jax.Array, held: jax.Array) -> jax.Array:
+    """The sorted pairs' results ``ys`` [rows, D] summed into their tokens' rows [N, D], each by its
+    routing weight: ``weight`` and ``held`` are [N, k], and a pair that is not held adds nothing,
+    whatever the row its clamped index finds holds. Going backwards nothing is as wide as all
+    pairs but the weights' cotangent, a number a pair: row ``j`` gets its weight times its token's
+    cotangent (a gather of ``rows`` rows), and its weight's cotangent is the two rows' dot product."""
+    return _sum_of_slots(ys, jnp.minimum(inverse, ys.shape[0] - 1).reshape(held.shape), held, weight)
 
 
-_pairs_to_slots.defvjp(lambda ys, order, inverse: (ys[inverse], order), lambda order, g: (g[order], None, None))
+def _pairs_to_rows_fwd(ys, weight, order, inverse, held):
+    return _pairs_to_rows(ys, weight, order, inverse, held), (ys, weight, order, inverse, held)
+
+
+def _pairs_to_rows_bwd(res, g):
+    ys, weight, order, inverse, held = res
+    rows = ys.shape[0]
+    first = order[:rows]
+    g_rows = g[first // held.shape[1]]
+    d_ys = g_rows * weight.reshape(-1)[first][:, None]
+    dots = jnp.sum(ys.astype(jnp.float32) * g_rows.astype(jnp.float32), axis=-1)
+    d_weight = jnp.where(held, dots[jnp.minimum(inverse, rows - 1)].reshape(held.shape), 0)
+    return d_ys.astype(ys.dtype), d_weight.astype(weight.dtype), None, None, None
+
+
+_pairs_to_rows.defvjp(_pairs_to_rows_fwd, _pairs_to_rows_bwd)
 
 
 GMM_TILES = (512, 1024, 1024)  # rows, contraction, columns of the Pallas grouped matmul; its default 128s are 8x slower (my chip runs, PR 29)
+# The room of the compact row buffer over an even share of the pairs (`compact_rows`). As drawn, a chip's share of
+# a layer's pairs had a standard deviation of 3.3% around its even quarter; placed by load it starts within 0.6%
+# of it and falls 15% over a window as the routers train (PERF.md, PR 29): half as much again is out of reach of
+# either, and a layer that passes it all the same takes the full width (`_held_experts`), slower and exact.
+SLACK = 1.5
+
+
+def compact_rows(n_pairs: int, held_n: int, num_experts: int) -> int:
+    """Rows of the buffer between the routing and the grouped products: ``SLACK`` times the
+    even share of ``n_pairs`` that ``held_n`` of ``num_experts`` experts get, in whole row tiles
+    of the grouped matmul, and never more than all pairs (every expert held; a decode step)."""
+    tile = GMM_TILES[0]
+    return min(n_pairs, -(-math.ceil(SLACK * n_pairs * held_n / num_experts) // tile) * tile)
 
 
 def _group_products(xs, w, group_sizes, cotangent=None):
@@ -441,13 +501,71 @@ def _grouped_dot_bwd(res, g):
 grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
+@functools.partial(jax.jit, static_argnames="rows")
+def _experts_at_width(x, weight, kernels, routing, rows: int):
+    """The held experts' partial sum [N, D] through a buffer of the first ``rows`` sorted pairs,
+    which has to hold every held pair. Under ``jit`` (as :func:`_experts_at_width_vjp`) so that the
+    expert layers of a model, alike in their shapes, are traced once between them: a program traces
+    two widths forwards and backwards, and a launch pays for each trace (2 s a layer, my chip runs, PR 30)."""
+    w1, w3, w2 = kernels
+    order, inverse, held, group_sizes = routing
+    xs = _rows_to_pairs(x, order, inverse, rows)  # the groups cover the held pairs, in front
+    hidden = jax.nn.silu(grouped_dot(xs, w1, group_sizes)) * grouped_dot(xs, w3, group_sizes)
+    return _pairs_to_rows(grouped_dot(hidden, w2, group_sizes), weight, order, inverse, held)
+
+
+@functools.partial(jax.jit, static_argnames="rows")
+def _experts_at_width_vjp(x, weight, kernels, routing, g, rows: int):
+    """The cotangents of ``x``, ``weight`` and ``kernels`` for the cotangent ``g`` of :func:`_experts_at_width`,
+    which is computed again for it."""
+    return jax.vjp(lambda *inputs: _experts_at_width(*inputs, routing, rows=rows), x, weight, kernels)[1](g)
+
+
+def _at_either_width(rows: int, routing, at_width):
+    """``at_width(rows)`` where the held pairs fit the compact buffer, else ``at_width(all pairs)``:
+    the branch follows the data, and where ``rows`` is all pairs there is none."""
+    order, _, _, group_sizes = routing
+    if rows == order.shape[0]:
+        return at_width(rows)
+    return jax.lax.cond(jnp.sum(group_sizes) <= rows, lambda: at_width(rows), lambda: at_width(order.shape[0]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _held_experts(x, weight, kernels, routing, rows: int):
+    """:func:`_experts_at_width` at ``rows`` where the held pairs fit, else at the width of all
+    pairs: dropless at any load. One ``custom_vjp`` whose residuals are its inputs and whose two
+    rules each choose the width for themselves, so that nothing is differentiated through the
+    ``cond`` (which would have the compact branch write the full width's residuals as zeros, over
+    1 GB a layer at the benchmark's sizes) and a layer computed again going backwards
+    (``jax.checkpoint``) does not run the products a third time."""
+    return _at_either_width(rows, routing, lambda r: _experts_at_width(x, weight, kernels, routing, rows=r))
+
+
+def _held_experts_fwd(x, weight, kernels, routing, rows):
+    return _held_experts(x, weight, kernels, routing, rows), (x, weight, kernels, routing)
+
+
+def _held_experts_bwd(rows, res, g):
+    x, weight, kernels, routing = res
+    return (*_at_either_width(rows, routing, lambda r: _experts_at_width_vjp(x, weight, kernels, routing, g, rows=r)), None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 def moe_ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig):
     """The held experts' part of the expert layer over the rows ``x`` [N, D].
 
     Routing is over all ``num_experts``; the (token, slot) pairs whose expert is
-    held here are sorted by expert and multiplied as ragged groups, the others
-    are left out. Returns the partial sum [N, D], the choices [N, k] and the
-    layer's counters."""
+    held here are sorted by expert, in front of the others, and multiplied as
+    ragged groups; the others are left out. The rows between the routing and the
+    products are those of the first :func:`compact_rows` sorted pairs (24,576 of
+    65,536 with 8 of 32 experts held and 16,384 tokens): a static width, from the
+    shapes alone. The number of held pairs is the data's, and a layer whose held
+    pairs do not fit takes the width of all pairs instead: no token is dropped or
+    capped at any load, the step is only slower. Returns the partial sum [N, D],
+    the choices [N, k] and the layer's counters, ``compact`` (1 where the held
+    pairs fitted) among them."""
     lo = cfg.expert_lo
     held_n = p["w1"].shape[0]
     n_rows, k = x.shape[0], cfg.num_experts_per_tok
@@ -459,19 +577,18 @@ def moe_ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig):
         order = jnp.argsort(sort_key, stable=True)
         inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
         group_sizes = jnp.sum(sort_key[:, None] == jnp.arange(held_n)[None, :], axis=0, dtype=jnp.int32)
+    rows_wide = compact_rows(n_rows * k, held_n, cfg.num_experts)
     with jax.named_scope("lm.moe.experts"):
-        xs = _rows_to_pairs(x, order, inverse)  # a row for every pair; the groups cover the held pairs, in front
-        hidden = jax.nn.silu(grouped_dot(xs, p["w1"], group_sizes)) * grouped_dot(xs, p["w3"], group_sizes)
-        ys = grouped_dot(hidden, p["w2"], group_sizes)
-        back = _pairs_to_slots(ys, order, inverse).reshape(n_rows, k, -1)
-        weight = jnp.where(held, w, 0.0).astype(back.dtype)
-        out = jnp.sum(jnp.where(held[..., None], back, 0) * weight[..., None], axis=1)
+        weight = jnp.where(held, w, 0.0).astype(x.dtype)
+        kernels, routing = (p["w1"], p["w3"], p["w2"]), (order, inverse, held, group_sizes)
+        out = _held_experts(x, weight, kernels, routing, rows_wide)
     rows = group_sizes.astype(jnp.float32)
     counters = {
         "pairs_here": jnp.sum(rows),
         "pairs_total": jnp.float32(n_rows * k),
         "load_max_over_mean": jnp.max(rows) / jnp.maximum(jnp.mean(rows), 1.0),
         "rows_per_expert_min": jnp.min(rows),
+        "compact": (jnp.sum(rows) <= rows_wide).astype(jnp.float32),
     }
     return out, chosen, counters
 
@@ -550,6 +667,7 @@ def moe_metrics(aux: Dict[str, Any]) -> Dict[str, jax.Array]:
         "Moe/pairs_total": jnp.sum(c["pairs_total"]),
         "Moe/load_max_over_mean": jnp.max(c["load_max_over_mean"]),
         "Moe/rows_per_expert_min": jnp.min(c["rows_per_expert_min"]),
+        "Moe/compact_share": jnp.mean(c["compact"]),
     }
 
 

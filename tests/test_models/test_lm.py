@@ -3,6 +3,8 @@ reference (``benchmarks/chip/reference/lfm2_ppo.py``, float32, nothing imported 
 program), at small sizes on the CPU: each part, the whole pass, the chip's share of an
 expert layer, and acting through the carried state."""
 
+import functools
+import importlib
 import os
 import sys
 
@@ -146,24 +148,26 @@ def test_grouped_dot_with_rows_that_no_group_covers():
     _close(d_w, want_d_w)
 
 
+def _take_the_tpu_branch_interpreted(monkeypatch):
+    """From here the program takes the branch a TPU takes, its Pallas kernels run by the interpreter."""
+    megablox = importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)
+    monkeypatch.setattr(megablox, "gmm", functools.partial(megablox.gmm, interpret=True))
+    monkeypatch.setattr(megablox, "tgmm", functools.partial(megablox.tgmm, interpret=True))
+
+
 @pytest.mark.parametrize("rows,sizes", [(8, [2, 0, 3, 1]), (64, [5, 0, 17, 9]), (600, [100, 200, 50, 150])])
 def test_the_tpu_branch_of_the_grouped_products_in_interpret_mode(monkeypatch, rows, sizes):
     """The branch a TPU takes (the stock Pallas grouped matmul: its tiles, the rows padded to whole
     tiles, a decode step's eight rows among them, the two transposes) run by the Pallas interpreter on
     the CPU gives what ``ragged_dot`` gives, forwards and backwards."""
-    import functools
-    import importlib
-
-    megablox = importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
     xs = jax.random.normal(jax.random.PRNGKey(0), (rows, 32))
     w = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 48)) / 6
     ct = jax.random.normal(jax.random.PRNGKey(2), (rows, 48))
     group_sizes, used = jnp.asarray(sizes, jnp.int32), sum(sizes)
     want, want_vjp = jax.vjp(lambda a, b: lm.grouped_dot(a, b, group_sizes), xs, w)
     want_d = want_vjp(ct)
-    monkeypatch.setattr(lm, "on_tpu", lambda: True)
-    monkeypatch.setattr(megablox, "gmm", functools.partial(megablox.gmm, interpret=True))
-    monkeypatch.setattr(megablox, "tgmm", functools.partial(megablox.tgmm, interpret=True))
+    _take_the_tpu_branch_interpreted(monkeypatch)
     got, got_vjp = jax.vjp(lambda a, b: lm.grouped_dot(a, b, group_sizes), xs, w)
     got_d = got_vjp(ct)
     _close(got[:used], want[:used], 1e-4)
@@ -223,3 +227,152 @@ def test_bfloat16_working_copy_keeps_router_and_norms_in_float32(uncut):
     tokens = jax.random.randint(jax.random.PRNGKey(8), (B, T), 0, 64)
     final, _ = lm.forward(params, tokens, cfg, jnp.bfloat16)
     assert final.dtype == jnp.bfloat16 and bool(jnp.all(jnp.isfinite(final.astype(jnp.float32))))
+
+
+# ------------------------------------------------- the compact row buffer of the expert layer
+
+
+@jax.custom_vjp
+def _parent_rows_to_pairs(x, order, inverse):
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+_parent_rows_to_pairs.defvjp(
+    lambda x, order, inverse: (_parent_rows_to_pairs(x, order, inverse), (inverse, x.shape[0])),
+    lambda res, g: (jnp.sum(g[res[0]].reshape(res[1], -1, g.shape[-1]), axis=1), None, None),
+)
+
+
+@jax.custom_vjp
+def _parent_pairs_to_slots(ys, order, inverse):
+    return ys[inverse]
+
+
+_parent_pairs_to_slots.defvjp(lambda ys, order, inverse: (ys[inverse], order), lambda order, g: (g[order], None, None))
+
+
+def _parent_moe_ffn(p, x, cfg):
+    """``lm.moe_ffn`` as it stood before the compact buffer (commit 5e33a18), kept word for word
+    but for its counters: every array between the routing and the sum has a row for every pair."""
+    lo = cfg.expert_lo
+    held_n = p["w1"].shape[0]
+    n_rows, k = x.shape[0], cfg.num_experts_per_tok
+    chosen, w = lm.route(p, x, cfg)
+    local = chosen - lo
+    held = (local >= 0) & (local < held_n)
+    sort_key = jnp.where(held, local, held_n).reshape(-1)
+    order = jnp.argsort(sort_key, stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+    group_sizes = jnp.sum(sort_key[:, None] == jnp.arange(held_n)[None, :], axis=0, dtype=jnp.int32)
+    xs = _parent_rows_to_pairs(x, order, inverse)
+    hidden = jax.nn.silu(lm.grouped_dot(xs, p["w1"], group_sizes)) * lm.grouped_dot(xs, p["w3"], group_sizes)
+    ys = lm.grouped_dot(hidden, p["w2"], group_sizes)
+    back = _parent_pairs_to_slots(ys, order, inverse).reshape(n_rows, k, -1)
+    weight = jnp.where(held, w, 0.0).astype(back.dtype)
+    return jnp.sum(jnp.where(held[..., None], back, 0) * weight[..., None], axis=1)
+
+
+QUARTER = dict(experts_held=2, expert_lo=2)  # experts 2 and 3 of 8 are held: a quarter, as in the benchmark's cell
+TOKENS = 512  # 1,024 pairs; the compact buffer is one row tile of the grouped matmul, 512 rows
+# held pairs -> whether the layer takes the compact width
+WIDTH_CASES = {"well_under": (100, 1.0), "exactly_full": (512, 1.0), "one_over": (513, 0.0), "all_pairs": (1024, 0.0)}
+
+
+def _quarter_layer(held_pairs):
+    """An expert layer whose router reads each token's two experts off the token's first eight
+    features, and rows that send exactly ``held_pairs`` (token, slot) pairs to experts 2 and 3."""
+    p = {
+        "router": jnp.zeros((32, 8)).at[:8].set(4.0 * jnp.eye(8)),
+        "bias": jax.random.uniform(jax.random.PRNGKey(20), (8,), jnp.float32, -0.02, 0.02),
+        **{
+            name: jax.random.normal(jax.random.PRNGKey(21 + i), shape) / 6
+            for i, (name, shape) in enumerate({"w1": (2, 32, 24), "w3": (2, 32, 24), "w2": (2, 24, 32)}.items())
+        },
+    }
+    both = max(held_pairs - TOKENS, 0)  # tokens with both of their experts held
+    one = held_pairs - 2 * both  # tokens with one
+    first = np.where(np.arange(TOKENS) < both + one, 2 + np.arange(TOKENS) % 2, 4)
+    second = np.where(np.arange(TOKENS) < both, 5 - first, 5 + np.arange(TOKENS) % 3)
+    rng = np.random.default_rng(held_pairs)
+    spread = rng.permutation(TOKENS)  # held pairs all over the rows, not in front
+    x = rng.normal(size=(TOKENS, 32)).astype(np.float32)
+    x[:, :8] = 0.0
+    x[spread, first] = 3.0
+    x[spread, second] = 2.0
+    return p, jnp.asarray(x)
+
+
+def _out_and_grads(layer, p, x, cfg, ct):
+    def loss(p, x):
+        return jnp.sum(layer(p, x, cfg) * ct)
+
+    with jax.default_matmul_precision("highest"):
+        out = jax.jit(lambda p, x: layer(p, x, cfg))(p, x)
+        d_p, d_x = jax.jit(jax.grad(loss, argnums=(0, 1)))(p, x)
+    return {"out": out, "rows": d_x, **{k: d_p[k] for k in ("w1", "w3", "w2", "router")}}
+
+
+def test_the_compact_width_follows_the_shapes():
+    assert lm.compact_rows(65536, 8, 32) == 24576  # the benchmark's cell: 16,384 tokens, 8 of 32 experts held
+    assert lm.compact_rows(8, 8, 32) == 8  # a decode step of two tokens: one tile would hold all pairs
+    assert lm.compact_rows(1024, 8, 8) == 1024  # every expert held
+    assert lm.compact_rows(1024, 2, 8) == 512 and lm.compact_rows(3000, 2, 8) == 1536  # whole row tiles
+
+
+@pytest.mark.parametrize("case", list(WIDTH_CASES))
+def test_a_quarter_of_the_experts_through_the_compact_buffer_equals_the_full_width(case):
+    """With 2 of 8 experts held the buffer has 512 of 1,024 rows. Outputs and gradients (rows,
+    three kernels, router) are the parent's full-width formulation's, whether the held pairs fit
+    (the compact width) or not (the fallback, which is that formulation: to the last bit); the
+    case of every pair held is ``test_every_token_routed_to_one_expert_loses_none``'s, and loses none."""
+    held_pairs, compact = WIDTH_CASES[case]
+    p, x = _quarter_layer(held_pairs)
+    cfg = config(**QUARTER)
+    assert lm.compact_rows(2 * TOKENS, 2, 8) == 512
+    with jax.default_matmul_precision("highest"):
+        _, chosen, counters = lm.moe_ffn(p, x, cfg)
+    assert float(counters["pairs_here"]) == held_pairs and float(counters["compact"]) == compact
+    assert int(jnp.sum((chosen == 2) | (chosen == 3))) == held_pairs
+    ct = jax.random.normal(jax.random.PRNGKey(30), x.shape)
+    layer = lambda p, x, cfg: lm.moe_ffn(p, x, cfg)[0]  # noqa: E731
+    got, want = _out_and_grads(layer, p, x, cfg, ct), _out_and_grads(_parent_moe_ffn, p, x, cfg, ct)
+    for name in want:
+        assert float(jnp.max(jnp.abs(want[name]))) > 1e-3
+        np.testing.assert_allclose(np.asarray(got[name]), np.asarray(want[name]), rtol=1e-5, atol=1e-6, err_msg=name)
+    # and to the last bit, op by op (compiled whole, XLA:CPU fuses the two programs' sums over a token's slots apart)
+    with jax.disable_jit():
+        got, want = _out_and_grads(layer, p, x, cfg, ct), _out_and_grads(_parent_moe_ffn, p, x, cfg, ct)
+    for name in want:
+        assert np.array_equal(np.asarray(got[name]), np.asarray(want[name])), name
+    if case == "all_pairs":  # nothing lost: the uncut reference given the same share computes the same
+        s = {**ref.sizes_from(SIZES), "experts_held": 2, "expert_lo": 2}
+        with jax.default_matmul_precision("highest"):
+            _close(got["out"], ref.moe_ffn(p, x, s, None)[0])
+
+
+@pytest.mark.parametrize("case", ["well_under", "one_over"])
+def test_the_tpu_branch_of_the_expert_layer_in_interpret_mode_through_both_widths(monkeypatch, case):
+    """The Pallas grouped matmul on a buffer of 512 rows (the held pairs fit) and of 1,024 (they do
+    not), forwards and backwards, gives what the CPU's branch gives."""
+    p, x = _quarter_layer(WIDTH_CASES[case][0])
+    cfg = config(**QUARTER)
+    ct = jax.random.normal(jax.random.PRNGKey(31), x.shape)
+    layer = lambda p, x, cfg: lm.moe_ffn(p, x, cfg)[0]  # noqa: E731
+    want = _out_and_grads(layer, p, x, cfg, ct)
+    _take_the_tpu_branch_interpreted(monkeypatch)
+    got = _out_and_grads(layer, p, x, cfg, ct)
+    for name in want:
+        _close(got[name], want[name], 1e-4)
+
+
+@pytest.mark.parametrize("experts_held", [8, 2])
+def test_the_token_policy_s_metrics_carry_the_compact_share(experts_held):
+    from sheeprl_tpu.algos.ppo_recurrent.token_agent import TokenPolicy
+
+    cfg = config(experts_held=experts_held)
+    policy = TokenPolicy(cfg, jnp.float32)
+    params = lm.init_params(cfg, jax.random.PRNGKey(9))
+    ids = jax.random.randint(jax.random.PRNGKey(10), (T, B, 1), 0, 64).astype(jnp.float32)
+    *_, metrics = policy.evaluate(params, {"actions": ids}, {"tokens": ids})
+    assert float(metrics["Moe/compact_share"]) == 1.0  # four expert layers, each within its buffer
+    assert float(metrics["Moe/pairs_here"]) <= float(metrics["Moe/pairs_total"]) == 4 * B * T * 2

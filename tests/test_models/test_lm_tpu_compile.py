@@ -1,13 +1,16 @@
 """The language-model policy's two costly parts compile for a described TPU v5e at the
 published widths and the benchmark cell's shapes: the attention layer through the stock
 Pallas flash-attention kernel (Mosaic has to take it: heads of 64, blocks of 1,024), and an
-expert layer through the stock Pallas grouped matmul with a row for every (token, slot) pair. No chip is
-attached: nothing runs, and nothing here is a device number.
+expert layer through the stock Pallas grouped matmul at both of its widths (the compact row buffer, and a
+row for every (token, slot) pair as the fallback). No chip is attached: nothing runs, and nothing here is a
+device number.
 
 The topology is described inside a fixture, never while a module is imported (only one
 process may load the TPU's library, and every xdist worker imports every test file), and
 this is the only test file that describes one.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,28 +72,55 @@ def test_attention_layer_through_the_flash_kernel_compiles_for_v5e(one_chip, cut
     assert text.count('custom_call_target="tpu_custom_call"') >= 3  # the forward, dq and dkv kernels are in the program
 
 
-def test_expert_layer_compiles_for_v5e_and_fits(one_chip, cut, monkeypatch):
-    monkeypatch.setattr(lm, "on_tpu", lambda: True)  # the Pallas grouped matmul, as on the chip
+def _expert_layer(one_chip):
+    """The shapes of one expert layer's share: the router whole, 8 of 32 experts' kernels in bfloat16."""
     bf = jnp.bfloat16
-    p = {
+    return {
         "router": _spec((D, 32), jnp.float32, one_chip), "bias": _spec((32,), jnp.float32, one_chip),
         "w1": _spec((8, D, 1792), bf, one_chip), "w3": _spec((8, D, 1792), bf, one_chip), "w2": _spec((8, 1792, D), bf, one_chip),
     }
+
+
+def _computations(text):
+    """name -> body of every computation of a compiled program's text."""
+    bodies = {}
+    for block in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)", text):
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(", block)
+        if head:
+            bodies[head.group(1)] = block
+    return bodies
+
+
+def test_expert_layer_compiles_for_v5e_and_fits(one_chip, cut, monkeypatch):
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)  # the Pallas grouped matmul, as on the chip
+    p = _expert_layer(one_chip)
+    assert lm.compact_rows(B * T * 4, 8, 32) == 24576
     grad = jax.grad(lambda p, x: lm.moe_ffn(p, x, cut)[0].astype(jnp.float32).sum(), argnums=(0, 1), allow_int=True)
-    compiled = _compile(grad, p, _spec((B * T, D), bf, one_chip))
-    memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 4e9  # a row for every pair, forwards and backwards, beside 9 GB of state
-    # three products forwards and, backwards, two for each of them: nine grouped-matmul kernels
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 9
+    compiled = _compile(grad, p, _spec((B * T, D), jnp.bfloat16, one_chip))
+    text = compiled.as_text()
+    # The compiler plans for the wider of the two widths, so the figure does not fall with the compact
+    # buffer: 1,519,029,760 B here against the parent's 1,248,751,104 (5e33a18, a row for every pair and no
+    # branch; my compiles, PR 30). ISSUE 30 expected a third less; that holds for the compact branch's own
+    # arrays (asserted below), not for a program that also holds the fallback.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    # three products forwards and, backwards, two for each of them: nine grouped-matmul kernels a width
+    assert text.count('custom_call_target="tpu_custom_call"') >= 18
+    # one branch on the data, in the backward rule (the forward one is dead code where only the gradient is
+    # asked for, as it is in a layer that `jax.checkpoint` computes again): nothing is differentiated through it
+    branches = re.findall(r" conditional\(.*branch_computations=\{(%[\w.\-]+), (%[\w.\-]+)\}", text)
+    assert len(branches) == 1
+    bodies = _computations(text)
+    compact, full = sorted(branches[0], key=lambda name: "[65536,1792]" in bodies[name])
+    assert "[24576,1792]" in bodies[compact] and "[65536,1792]" not in bodies[compact]
+    assert "[65536,1792]" in bodies[full] and "[24576,1792]" not in bodies[full]
+    for name in (compact, full):
+        assert bodies[name].count('custom_call_target="tpu_custom_call"') == 9
 
 
 def test_a_decode_step_s_expert_layer_compiles_for_v5e(one_chip, cut, monkeypatch):
     """Acting is two tokens a step: eight (token, slot) pairs, padded to one tile of the grouped matmul."""
     monkeypatch.setattr(lm, "on_tpu", lambda: True)
-    bf = jnp.bfloat16
-    p = {
-        "router": _spec((D, 32), jnp.float32, one_chip), "bias": _spec((32,), jnp.float32, one_chip),
-        "w1": _spec((8, D, 1792), bf, one_chip), "w3": _spec((8, D, 1792), bf, one_chip), "w2": _spec((8, 1792, D), bf, one_chip),
-    }
-    compiled = _compile(lambda p, x: lm.moe_ffn(p, x, cut)[0], p, _spec((2, D), bf, one_chip))
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 3
+    p = _expert_layer(one_chip)
+    text = _compile(lambda p, x: lm.moe_ffn(p, x, cut)[0], p, _spec((2, D), jnp.bfloat16, one_chip)).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert " conditional(" not in text  # eight pairs round up to all of them: one width, the parent's program
