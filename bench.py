@@ -1,8 +1,8 @@
 """Benchmark entrypoint for the driver: prints ONE JSON line.
 
 One process for each chip. A target whose work runs in this process
-(``ppo``, ``dv3``, ``all``, ``ingraph``, ``ingraph_train``, ``telemetry``,
-``rssm``) initialises JAX here, must find an accelerator, and starts no child
+(``ppo``, ``dv3``, ``all``, ``ingraph``, ``ingraph_train``, ``telemetry``)
+initialises JAX here, must find an accelerator, and starts no child
 that needs it. A target whose work runs in children (``compile``, ``serve``
 on the session's backend; the declared CPU drills ``health``, ``orchestrate``,
 ``serve_fleet``, ``transport``, ``fsdp``, ``checkpoint``, ``population``)
@@ -46,7 +46,7 @@ def _chip_peak_flops(device):
 
 #: targets whose work runs IN THIS PROCESS on the session's backend; every other
 #: target runs its work in children and keeps this process off the chip
-_INPROC_TARGETS = ("ppo", "dv3", "all", "ingraph", "ingraph_train", "telemetry", "rssm")
+_INPROC_TARGETS = ("ppo", "dv3", "all", "ingraph", "ingraph_train", "telemetry")
 
 
 def _require_accelerator(platform: str, what: str) -> None:
@@ -553,11 +553,12 @@ def bench_dv3(
 ) -> dict:
     """Time the fused DreamerV3-S train step at the measured-best TPU config.
 
-    Defaults follow scripts/mfu_sweep.py on the v5e: batch 128 with the H=15
-    imagination scan fully unrolled measures ~27.7% MFU (XLA-estimated flops;
-    the T=64 dynamic scan's flops are NOT trip-count-scaled by XLA cost
-    analysis, so true model-flops MFU is higher — see
-    benchmarks/DV3_MFU_NOTES.md). ``key_prefix`` lets a second call report the
+    Defaults follow a 2026-08 sweep through the plug-in path that is gone (its
+    scripts too): batch 128 with the H=15 imagination scan fully unrolled read
+    ~27.7% MFU there (XLA-estimated flops; the T=64 dynamic scan's flops are
+    NOT trip-count-scaled by XLA cost analysis). No cell of the chip benchmark
+    has judged ``imagination_scan_unroll=15`` (ROADMAP Design 2a); see
+    benchmarks/DV3_MFU_NOTES.md. ``key_prefix`` lets a second call report the
     batch-16 Atari-100K recipe shape as ``dv3_recipe_*``."""
     import gymnasium as gym
     import jax
@@ -1213,136 +1214,6 @@ def bench_serve_fleet(
     return result
 
 
-def bench_rssm(
-    batch: int = 16,
-    seq_len: int = 64,
-    iters: int = 3,
-    stochastic: int = 16,
-    discrete: int = 16,
-    recurrent: int = 256,
-    dense_units: int = 256,
-    hidden: int = 256,
-    action: int = 6,
-    embed: int = 256,
-) -> dict:
-    """Fused RSSM step-kernel microbench: flax scan vs the fused formulation.
-
-    Compiles ``value_and_grad`` of a scalar loss over the full dynamic scan for
-    both paths (``kernels=off`` -> flax reference; ``kernels=reference`` -> the
-    fused step with its hand-written ``custom_vjp``) at the same shapes, via
-    ``guarded_jit`` + ``aot_compile`` so both programs land in the compiled-
-    program ledger and carry cost_analysis numbers. The headline is the fused
-    path's ``bytes accessed`` per scan step — the custom_vjp keeps only the
-    scan's own carries/xs as residuals and recomputes every intermediate in the
-    backward pass, so its memory traffic must sit >= 25% below the flax scan,
-    whose autodiff stacks per-step intermediates across T (ISSUE 16 acceptance
-    gate; CPU-measurable, the cost model is backend-portable). Defaults are the
-    Atari-100K training recipe scan shape (batch 16 x seq 64): parameter reads
-    amortize across the scan there, so the residual-traffic reduction is the
-    signal — short scans dilute it under per-step weight re-reads.
-
-    v5e design target: at the walker_walk XL shape (R=4096, 32x32 stochastic)
-    the same traffic reduction is what pushes the DV3 train step toward MFU
-    0.45 on v5e-8 — the wall-clock column here is CPU-only context, not the
-    accelerator number.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from sheeprl_tpu.algos.dreamer_v3.agent import MLPWithHead, RecurrentModel, RSSM
-    from sheeprl_tpu.core import compile as jax_compile
-
-    sd = stochastic * discrete
-    rm = RecurrentModel(
-        input_size=action + sd,
-        recurrent_state_size=recurrent,
-        dense_units=dense_units,
-        layer_norm=True,
-        layer_norm_eps=1e-3,
-    )
-    rep = MLPWithHead(
-        input_dim=embed + recurrent,
-        hidden_sizes=[hidden],
-        output_dim=sd,
-        activation="silu",
-        layer_norm=True,
-        layer_norm_eps=1e-3,
-    )
-    trans = MLPWithHead(
-        input_dim=recurrent,
-        hidden_sizes=[hidden],
-        output_dim=sd,
-        activation="silu",
-        layer_norm=True,
-        layer_norm_eps=1e-3,
-    )
-
-    key = jax.random.PRNGKey(0)
-    k1, k2, k3, k4, k5, k6, k7 = jax.random.split(key, 7)
-    wm_params = {
-        "recurrent_model": rm.init(k1, jnp.zeros((batch, action + sd)), jnp.zeros((batch, recurrent))),
-        "representation_model": rep.init(k2, jnp.zeros((batch, embed + recurrent))),
-        "transition_model": trans.init(k3, jnp.zeros((batch, recurrent))),
-        "initial_recurrent_state": 0.1 * jax.random.normal(k4, (recurrent,)),
-    }
-    emb = jax.random.normal(k5, (seq_len, batch, embed))
-    act = jax.random.normal(k6, (seq_len, batch, action))
-    isf = jnp.zeros((seq_len, batch, 1)).at[0].set(1.0)
-
-    def _loss_for(kernels: str):
-        rssm = RSSM(
-            rm, rep, trans, stochastic_size=stochastic, discrete_size=discrete,
-            unimix=0.01, kernels=kernels,
-        )
-
-        def loss(params, embedded, actions, is_first, rng):
-            h, post, prior_l, post_l = rssm.dynamic_scan(params, embedded, actions, is_first, rng)
-            return (
-                jnp.mean(jnp.square(h))
-                + jnp.mean(jnp.square(post))
-                + jnp.mean(jnp.square(prior_l))
-                + jnp.mean(jnp.square(post_l))
-            )
-
-        return jax.value_and_grad(loss)
-
-    result = {
-        "rssm_shape": f"B{batch}xT{seq_len} S{stochastic}xD{discrete} R{recurrent} DU{dense_units}",
-        "rssm_backend": jax.default_backend(),
-        "rssm_bytes_reduction_target_pct": 25.0,
-        "rssm_v5e_mfu_target": 0.45,
-    }
-    specs = tuple(
-        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)
-        for a in (wm_params, emb, act, isf, k7)
-    )
-    for label, kernels in (("flax", "off"), ("fused", "reference")):
-        gfn = jax_compile.guarded_jit(_loss_for(kernels), name=f"bench.rssm_{label}")
-        t0 = time.perf_counter()
-        gfn.aot_compile(*specs)
-        result[f"rssm_{label}_compile_s"] = round(time.perf_counter() - t0, 3)
-        if gfn.last_step_bytes is not None:
-            result[f"rssm_{label}_bytes_per_step"] = round(gfn.last_step_bytes / seq_len, 1)
-        if gfn.last_step_flops is not None:
-            result[f"rssm_{label}_flops_per_step"] = round(gfn.last_step_flops / seq_len, 1)
-        # warm pass, then the timed median-free mean (CPU context number only)
-        jax.block_until_ready(gfn(wm_params, emb, act, isf, k7))
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            jax.block_until_ready(gfn(wm_params, emb, act, isf, k7))
-        dt = (time.perf_counter() - t0) / iters
-        result[f"rssm_{label}_scan_ms"] = round(dt * 1e3, 3)
-        result[f"rssm_{label}_steps_per_sec"] = round(seq_len / dt, 1)
-    flax_b = result.get("rssm_flax_bytes_per_step")
-    fused_b = result.get("rssm_fused_bytes_per_step")
-    if flax_b and fused_b:
-        result["rssm_bytes_reduction_pct"] = round((1.0 - fused_b / flax_b) * 100.0, 2)
-        result["rssm_bytes_gate_pass"] = bool(
-            result["rssm_bytes_reduction_pct"] >= result["rssm_bytes_reduction_target_pct"]
-        )
-    return result
-
-
 def _fsdp_child_main(iters: int = 5) -> dict:
     """The in-process body of ``bench.py --target fsdp`` (see :func:`bench_fsdp`).
 
@@ -1939,7 +1810,6 @@ def _target_metric(target: str) -> str:
         "ingraph": "ingraph_env_steps_per_sec",
         "ingraph_train": "ingraph_fused_train_env_steps_per_sec",
         "telemetry": "telemetry_tracer_overhead_pct",
-        "rssm": "rssm_fused_bytes_per_step",
         "fsdp": "fsdp_handoff_bytes_per_iter",
         "checkpoint": "checkpoint_blocked_save_ms",
         "population": "population_agg_env_steps_per_sec",
@@ -1969,9 +1839,6 @@ _SENTINEL_CLASSES = (
     ("_p50_ms", "lower", 0.25),
     ("hbm_peak", "lower", 0.05),
     ("overhead_pct", "lower", 0.50),
-    # cost-model bytes are deterministic per (shape, compiler) — any growth is
-    # a real fusion/residual regression, so the threshold is tight
-    ("bytes_per_step", "lower", 0.02),
     # per-shard handoff bytes are pure payload-shape arithmetic — growth means
     # a leaf fell off the sharded path back onto the replicated one
     ("handoff_bytes", "lower", 0.02),
@@ -2155,7 +2022,6 @@ if __name__ == "__main__":
             "ingraph",
             "ingraph_train",
             "telemetry",
-            "rssm",
             "fsdp",
             "checkpoint",
             "population",
@@ -2319,17 +2185,6 @@ if __name__ == "__main__":
                 result.setdefault("value", tel.get("telemetry_tracer_overhead_pct"))
                 result.setdefault("unit", "%")
                 result.setdefault("vs_baseline", None)
-            if cli_args.target == "rssm":
-                # opt-in only: fused-RSSM step-kernel microbench — flax scan vs
-                # the fused custom_vjp formulation at the same shapes, headline
-                # is cost_analysis bytes-accessed per scan step (the ISSUE 16
-                # >=25%-reduction gate; deterministic on any backend)
-                rs = bench_rssm()
-                result.update(rs)
-                result.setdefault("metric", headline_metric)
-                result.setdefault("value", rs.get("rssm_fused_bytes_per_step"))
-                result.setdefault("unit", "bytes/step")
-                result.setdefault("vs_baseline", rs.get("rssm_bytes_reduction_pct"))
             if cli_args.target == "fsdp":
                 # opt-in only: DDP-vs-FSDP-vs-overlap step time + per-shard
                 # handoff bytes on the 8-device virtual mesh (subprocess child;

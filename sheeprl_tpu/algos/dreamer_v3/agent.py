@@ -617,7 +617,6 @@ class RSSM:
         learnable_initial_recurrent_state: bool = True,
         decoupled: bool = False,
         dynamic_scan_unroll: int = 1,
-        kernels: str = "off",
     ):
         self.recurrent_model = recurrent_model
         self.representation_model = representation_model
@@ -632,42 +631,6 @@ class RSSM:
         # ([5120, 12288] for XL's GRU), not by the MXU; unrolling lets XLA overlap
         # consecutive steps' HBM reads and MXU work
         self.dynamic_scan_unroll = int(dynamic_scan_unroll)
-        # world_model.kernels knob: off/auto/pallas/interpret/reference. Anything
-        # but "off" routes the dynamic/imagination steps through the fused Pallas
-        # subsystem (ops/pallas/rssm_step.py); "off" is the bitwise flax reference.
-        self.kernels = str(kernels).lower()
-
-    def _fused_spec(self, embed_size: int, action_size: int):
-        """Build the static step spec, or raise KernelUnsupported when this RSSM
-        falls outside the fused-step contract (dispatch then stays on flax)."""
-        from sheeprl_tpu.ops.pallas import rssm_step as _fk
-
-        if self.decoupled:
-            raise _fk.KernelUnsupported("decoupled RSSM has no sequential posterior step")
-        if not (self.recurrent_model.layer_norm and self.representation_model.layer_norm
-                and self.transition_model.layer_norm):
-            raise _fk.KernelUnsupported("fused step requires layer_norm on all RSSM trunks")
-        for m in (self.representation_model, self.transition_model):
-            if str(m.activation) != "silu":
-                raise _fk.KernelUnsupported(f"fused step expects silu trunks, got {m.activation!r}")
-            if len(m.hidden_sizes) != 1:
-                raise _fk.KernelUnsupported("fused step expects single-hidden-layer trunks")
-        return _fk.RSSMStepSpec(
-            action_size=int(action_size),
-            embed_size=int(embed_size),
-            dense_units=int(self.recurrent_model.dense_units),
-            recurrent_size=int(self.recurrent_model.recurrent_state_size),
-            trans_hidden=int(self.transition_model.hidden_sizes[0]),
-            repr_hidden=int(self.representation_model.hidden_sizes[0]),
-            stochastic=self.stochastic_size,
-            discrete=self.discrete_size,
-            unimix=float(self.unimix),
-            eps_in=float(self.recurrent_model.layer_norm_eps),
-            eps_gru=float(self.recurrent_model.layer_norm_eps),
-            eps_trans=float(self.transition_model.layer_norm_eps),
-            eps_repr=float(self.representation_model.layer_norm_eps),
-            dtype=jnp.dtype(self.recurrent_model.dtype).name,
-        )
 
     @property
     def stoch_state_size(self) -> int:
@@ -772,18 +735,7 @@ class RSSM:
         learned initial state is computed once, before the scan, for the same
         reason: inside the step it would apply the transition model a second
         time with the same tap.
-
-        With ``kernels != off`` the non-decoupled path dispatches to the fused
-        step (ops/pallas/rssm_step.py): same return contract, logits in f32,
-        sampling distribution-equivalent (not bitwise) to this path. An active
-        ``train.kernel_dispatch`` failpoint degrades back to the flax scan
-        below; so does a structural mismatch under ``kernels=auto`` (logged
-        once), while a NAMED implementation raises it.
         """
-        if self.kernels != "off" and not self.decoupled:
-            fused = self._fused_dynamic_scan(wm_params, embedded_obs, actions, is_first, key)
-            if fused is not None:
-                return fused
         T, B = embedded_obs.shape[0], embedded_obs.shape[1]
         keys = jax.random.split(key, T)
         init_rec = jnp.zeros((B, self.recurrent_model.recurrent_state_size), dtype=embedded_obs.dtype)
@@ -844,57 +796,8 @@ class RSSM:
         posteriors_logits = posteriors_logits.reshape(T, B, self.stochastic_size, self.discrete_size)
         return recurrent_states, posteriors, priors_logits, posteriors_logits
 
-    def _fused_step_params(self, wm_params, embed_size: int, action_size: int, batch: int):
-        """``(params, spec-with-impl)`` for the fused step, or None for the flax
-        path. A structure the fused contract does not cover is the flax path
-        only under ``kernels=auto``, with one warning; a named implementation
-        raises :class:`KernelUnsupported`."""
-        from sheeprl_tpu.ops.pallas import rssm_step as _fk
-
-        try:
-            spec = self._fused_spec(embed_size, action_size)
-            impl = _fk.select_impl(self.kernels, spec, batch)
-            if impl is None:
-                return None
-            return _fk.extract_step_params(wm_params, self.stoch_state_size), spec.with_impl(impl)
-        except _fk.KernelUnsupported as e:
-            if str(self.kernels).lower() != "auto":
-                raise
-            _fk.log_choice_once("auto", "flax scan", f"fused-step contract not met: {e}")
-            return None
-
-    def _fused_dynamic_scan(self, wm_params, embedded_obs, actions, is_first, key):
-        """Fused-path dispatch; None means fall back to the flax scan."""
-        from sheeprl_tpu.ops.pallas import rssm_step as _fk
-
-        fused = self._fused_step_params(
-            wm_params, embedded_obs.shape[-1], actions.shape[-1], embedded_obs.shape[1]
-        )
-        if fused is None:
-            return None
-        p, spec = fused
-        return _fk.fused_dynamic_scan(
-            p,
-            spec,
-            wm_params["initial_recurrent_state"],
-            embedded_obs,
-            actions,
-            is_first,
-            key,
-            learnable_init=self.learnable_initial_recurrent_state,
-            unroll=self.dynamic_scan_unroll,
-        )
-
     def imagination_step(self, wm_params, prior_flat: jax.Array, recurrent_state: jax.Array, actions: jax.Array, key):
-        """One-step latent imagination (reference agent.py:482-498); dispatches
-        to the fused step under the same ``kernels`` knob as dynamic_scan."""
-        if self.kernels != "off" and not self.decoupled:
-            from sheeprl_tpu.ops.pallas import rssm_step as _fk
-
-            fused = self._fused_step_params(wm_params, 0, actions.shape[-1], recurrent_state.shape[0])
-            if fused is not None:
-                p, spec = fused
-                return _fk.fused_imagination_step(p, spec, prior_flat, recurrent_state, actions, key)
+        """One-step latent imagination (reference agent.py:482-498)."""
         recurrent_state = self._recurrent(wm_params, prior_flat, actions, recurrent_state)
         _, imagined_prior = self._transition(wm_params, recurrent_state, key)
         return imagined_prior.reshape(*prior_flat.shape), recurrent_state
@@ -1057,6 +960,13 @@ def build_agent(
     world_model_cfg = cfg.algo.world_model
     actor_cfg = cfg.algo.actor
     critic_cfg = cfg.algo.critic
+    # a saved run's config still carries `kernels: off`, which yaml reads as False (eval, serve, resume)
+    kernels = world_model_cfg.get("kernels")
+    if str(kernels).lower() not in ("none", "false", "off"):
+        raise ValueError(
+            "algo.world_model.kernels is gone: the fused RSSM step was removed and the "
+            f"flax scan is the one implementation; drop the key (got algo.world_model.kernels={kernels!r})"
+        )
 
     recurrent_state_size = int(world_model_cfg.recurrent_model.recurrent_state_size)
     stochastic_size = int(world_model_cfg.stochastic_size) * int(world_model_cfg.discrete_size)
@@ -1149,7 +1059,6 @@ def build_agent(
         learnable_initial_recurrent_state=bool(world_model_cfg.get("learnable_initial_recurrent_state", True)),
         decoupled=decoupled,
         dynamic_scan_unroll=int(world_model_cfg.get("dynamic_scan_unroll", 1)),
-        kernels=str(world_model_cfg.get("kernels", "off")),
     )
 
     cnn_keys_dec = list(cfg.algo.cnn_keys.decoder)

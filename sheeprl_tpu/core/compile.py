@@ -347,8 +347,7 @@ class GuardedFn:
         # hand-derived). Keyed like _aot; last_step_flops is the newest.
         self._aot_flops: Dict[Tuple, float] = {}
         self.last_step_flops: Optional[float] = None
-        # bytes accessed per call, same provenance — the bench.py rssm target
-        # reads these to compare flax-vs-fused memory traffic per scan step
+        # bytes accessed per call, same provenance (stats() and the program ledger)
         self.last_step_bytes: Optional[float] = None
         self.flops_dispatched = 0.0
         # warmup jobs queued for this fn but not yet compiled (threading.Events,

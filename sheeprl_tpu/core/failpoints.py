@@ -123,7 +123,6 @@ KNOWN_FAILPOINTS: Dict[str, Dict[str, str]] = {
     "env.autoreset": {"plane": "env", "doc": "autoreset path misbehaves after episode end"},
     "preempt.iteration": {"plane": "train", "doc": "preemption signal at a training-iteration boundary"},
     "train.fused_update": {"plane": "train", "doc": "fused in-graph update step fails"},
-    "train.kernel_dispatch": {"plane": "train", "doc": "Pallas RSSM kernel dispatch fails; scan degrades to the flax path"},
     "handoff.shard_put": {"plane": "train", "doc": "per-shard rollout handoff put fails mid-iteration (parallel/handoff.py)"},
     "train.grad_sync": {"plane": "train", "doc": "microbatched gradient-sync train dispatch fails at an iteration boundary"},
     "telemetry.program_record": {"plane": "telemetry", "doc": "compiled-program ledger capture fails"},

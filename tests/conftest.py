@@ -63,13 +63,6 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "kernels: fused Pallas RSSM step kernels (sheeprl_tpu/ops/pallas/) — interpret "
-        "bit-parity vs the reference formulation, custom_vjp gradient parity, dispatch/"
-        "VMEM-gate units, and the flax-fallback drill; select with `-m kernels` before "
-        "touching ops/pallas or the RSSM dispatch seams",
-    )
-    config.addinivalue_line(
-        "markers",
         "analysis: the JAX-invariant static analyzer (sheeprl_tpu/analysis/) — rule "
         "fixtures, call-graph reachability, baseline round-trips, and the tree-wide "
         "self-lint; select with `-m analysis` (or run scripts/lint.sh) before "
