@@ -52,10 +52,15 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # the parts the benchmark's flops_lfm2.py counts, by the scope they run under
 SCOPES = ("lm.embed", "lm.conv", "lm.attn", "lm.dense_ffn", "lm.moe.route", "lm.moe.experts", "lm.head")
 HI = jax.lax.Precision.HIGHEST
+# What a block keeps for its backward pass beside its input (`_layer`): its matmul products, marked with this
+# name where they are made. Outside a `jax.checkpoint` the mark is the identity.
+PRODUCT = "lm.product"
+KEEP_PRODUCTS = jax.checkpoint_policies.save_only_these_names(PRODUCT)
 
 
 @dataclass(frozen=True)
@@ -224,7 +229,7 @@ def conv_op(p: Dict[str, jax.Array], n: jax.Array, tail: Optional[jax.Array] = N
     gated input of the positions before (zeros at a sequence's start). Returns
     the output and the new tail."""
     with jax.named_scope("lm.conv"):
-        b, c, u = jnp.split(n @ p["in_proj"], 3, axis=-1)
+        b, c, u = jnp.split(checkpoint_name(n @ p["in_proj"], PRODUCT), 3, axis=-1)
         z = b * u
         taps = p["filter"].shape[0]
         t = z.shape[1]
@@ -232,15 +237,15 @@ def conv_op(p: Dict[str, jax.Array], n: jax.Array, tail: Optional[jax.Array] = N
             tail = jnp.zeros((z.shape[0], taps - 1, z.shape[2]), z.dtype)
         padded = jnp.concatenate([tail.astype(z.dtype), z], axis=1)
         conv = sum(p["filter"][j].astype(z.dtype) * padded[:, taps - 1 - j : taps - 1 - j + t] for j in range(taps))
-        return (c * conv) @ p["out_proj"], padded[:, t:]
+        return checkpoint_name((c * conv) @ p["out_proj"], PRODUCT), padded[:, t:]
 
 
 def _qkv(p, n, cfg: LMConfig, positions):
     bsz, t, _ = n.shape
     nq, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head
-    q = (n @ p["q"]).reshape(bsz, t, nq, hd)
-    k = (n @ p["k"]).reshape(bsz, t, nkv, hd)
-    v = (n @ p["v"]).reshape(bsz, t, nkv, hd)
+    q = checkpoint_name(n @ p["q"], PRODUCT).reshape(bsz, t, nq, hd)
+    k = checkpoint_name(n @ p["k"], PRODUCT).reshape(bsz, t, nkv, hd)
+    v = checkpoint_name(n @ p["v"], PRODUCT).reshape(bsz, t, nkv, hd)
     cos, sin = _rope_tables(positions, hd, cfg.rope_theta)
     q = _rope(rms_norm(q, p["q_norm"], cfg.norm_eps), cos, sin)
     k = _rope(rms_norm(k, p["k_norm"], cfg.norm_eps), cos, sin)
@@ -317,7 +322,7 @@ def attn_op(p: Dict[str, jax.Array], n: jax.Array, cfg: LMConfig):
         nq, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head
         q, k, v = _qkv(p, n, cfg, jnp.arange(t)[None, :])
         if on_tpu():
-            return flash_core(q, k, v, cfg) @ p["o"], k, v
+            return checkpoint_name(flash_core(q, k, v, cfg) @ p["o"], PRODUCT), k, v
         q = q.reshape(bsz, t, nkv, nq // nkv, hd)
         block = min(cfg.query_block, t)
 
@@ -331,7 +336,7 @@ def attn_op(p: Dict[str, jax.Array], n: jax.Array, cfg: LMConfig):
             for start in range(0, t, block)
         ]
         out = jnp.concatenate(outs, axis=1).reshape(bsz, t, nq * hd)
-        return out @ p["o"], k, v
+        return checkpoint_name(out @ p["o"], PRODUCT), k, v
 
 
 def attn_decode(p, n, cfg: LMConfig, cache_k, cache_v, pos):
@@ -351,7 +356,8 @@ def attn_decode(p, n, cfg: LMConfig, cache_k, cache_v, pos):
 
 def gated_mlp(p: Dict[str, jax.Array], n: jax.Array) -> jax.Array:
     with jax.named_scope("lm.dense_ffn"):
-        return (jax.nn.silu(n @ p["w1"]) * (n @ p["w3"])) @ p["w2"]
+        gate, up = checkpoint_name(n @ p["w1"], PRODUCT), checkpoint_name(n @ p["w3"], PRODUCT)
+        return (jax.nn.silu(gate) * up) @ p["w2"]  # no mark: nothing going backwards needs this product
 
 
 def route(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig):
@@ -603,11 +609,17 @@ def _ffn_half(p, h, cfg: LMConfig, ffn: str):
 
 
 def _layer(p, x, cfg: LMConfig, mixer: str, ffn: str):
-    """One block over whole sequences; returns the block's output and its aux. The
-    block keeps its input only and is computed again going backwards
-    (``jax.checkpoint``); around the flash kernel it is two such halves, so that
-    the kernel runs once forwards (it keeps what its own backward pass needs: q,
-    k, v, the output and the row statistics, 0.2 GB at 2 x 8,192 positions)."""
+    """One block over whole sequences; returns the block's output and its aux. For its
+    backward pass the block keeps its input and its matmul products (the arrays marked
+    :data:`PRODUCT`: 4 x the input's bytes in a conv block, 7 x in the dense FFN at the
+    published widths, 2.5 x in the attention block) and makes everything else again going
+    backwards (``jax.checkpoint``): norms, gates, taps, activations and rotary are vector
+    work over arrays the products define, and keeping them would cost gigabytes. The
+    expert layer keeps its inputs only (:func:`_held_experts`). Around the flash kernel
+    the block is two such halves, so that the kernel runs once forwards (it keeps what
+    its own backward pass needs: q, k, v, the output and the row statistics, 0.2 GB at
+    2 x 8,192 positions)."""
+    block = functools.partial(jax.checkpoint, policy=KEEP_PRODUCTS)
     if mixer == "attn" and on_tpu():
 
         def qkv(p, x):
@@ -616,20 +628,20 @@ def _layer(p, x, cfg: LMConfig, mixer: str, ffn: str):
 
         def rest(p, x, attended):
             with jax.named_scope("lm.attn"):
-                h = x + attended @ p["attn"]["o"]
+                h = x + checkpoint_name(attended @ p["attn"]["o"], PRODUCT)
             return _ffn_half(p, h, cfg, ffn)
 
-        q, k, v = jax.checkpoint(qkv)(p, x)
+        q, k, v = block(qkv)(p, x)
         with jax.named_scope("lm.attn"):
             attended = flash_core(q, k, v, cfg)
-        return jax.checkpoint(rest)(p, x, attended)
+        return block(rest)(p, x, attended)
 
     def whole(p, x):
         normed = rms_norm(x, p["op_norm"], cfg.norm_eps)
         op = conv_op(p["conv"], normed)[0] if mixer == "conv" else attn_op(p["attn"], normed, cfg)[0]
         return _ffn_half(p, x + op, cfg, ffn)
 
-    return jax.checkpoint(whole)(p, x)
+    return block(whole)(p, x)
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LMConfig, dtype=jnp.float32):

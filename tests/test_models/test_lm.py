@@ -6,6 +6,7 @@ expert layer, and acting through the carried state."""
 import functools
 import importlib
 import os
+import re
 import sys
 
 import jax
@@ -376,3 +377,53 @@ def test_the_token_policy_s_metrics_carry_the_compact_share(experts_held):
     *_, metrics = policy.evaluate(params, {"actions": ids}, {"tokens": ids})
     assert float(metrics["Moe/compact_share"]) == 1.0  # four expert layers, each within its buffer
     assert float(metrics["Moe/pairs_here"]) <= float(metrics["Moe/pairs_total"]) == 4 * B * T * 2
+
+
+# published index of a layer of each kind of block (two leading dense layers, attention at 2 and 6)
+BLOCKS = {"conv_dense": 0, "conv_experts": 3, "attention_experts": 2}
+_REMADE_PRODUCT = re.compile(r"rematted_computation/lm\.(?:conv|dense_ffn|attn)/dot_general")
+
+
+@pytest.mark.parametrize("kind", list(BLOCKS))
+def test_a_block_keeps_its_products_for_its_backward_pass(kind, monkeypatch, capsys):
+    """A block's matmul products are kept for its backward pass and no number moves: the loss and the
+    gradient of ``lm.evaluate`` with respect to every parameter equal, to the last bit, those of the
+    parent's formulation (a plain ``jax.checkpoint`` with no policy, which keeps the block's input only);
+    the lowered gradient makes no product of ``lm.conv``, ``lm.dense_ffn`` or ``lm.attn`` a second time
+    where the parent's does; and what the block keeps beside its arguments are those products. (The
+    attention here is the CPU's: its scores, ``einsum``s under a ``jax.checkpoint`` of their own, are
+    still made again. The router's product is too.)"""
+    cfg = config(layers=(BLOCKS[kind],))
+    (mixer, ffn), d = cfg.kinds[0], cfg.hidden_size
+    params = lm.init_params(cfg, jax.random.PRNGKey(12))
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (B, T), 0, 64)
+    actions = jax.random.randint(jax.random.PRNGKey(3), (B, T), 0, 64)
+
+    def loss_and_gradient():  # traced anew for each formulation
+        def program(p):
+            logp, entropy, values, _ = lm.evaluate(p, tokens, actions, cfg)
+            return jnp.sum(logp) + 0.3 * jnp.sum(entropy) + 0.7 * jnp.sum(values)
+
+        fn = jax.jit(jax.value_and_grad(program))
+        return fn(params), fn.lower(params).as_text(debug_info=True)
+
+    got, text = loss_and_gradient()
+    assert not _REMADE_PRODUCT.search(text)
+    assert ("rematted_computation/lm.moe.route/dot_general" in text) == (ffn == "moe")  # the expert layer is as it was
+
+    block = lambda p, x: lm._layer(p, x, cfg, mixer, ffn)[0]  # noqa: E731
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(block, params["layers"]["layer_0"], jnp.zeros((B, T, d)))
+    made_here = (line for line in capsys.readouterr().out.splitlines() if " output of " in line)  # the others are arguments
+    kept = sorted(re.match(r"f32\[([\d,]+)\]", line).group(1) for line in made_here)
+    heads = cfg.head * cfg.num_attention_heads, cfg.head * cfg.num_key_value_heads
+    widths = [3 * d, d] if mixer == "conv" else [heads[0], heads[1], heads[1], d]  # in_proj, out_proj | q, k, v, o
+    widths += [cfg.intermediate_size] * 2 if ffn == "dense" else []  # w1, w3; an expert layer keeps its inputs only
+    assert kept == sorted(f"{B},{T},{w}" for w in widths)
+
+    monkeypatch.setattr(lm, "KEEP_PRODUCTS", None)  # the parent's: `jax.checkpoint(whole)`
+    want, parent_text = loss_and_gradient()
+    assert _REMADE_PRODUCT.search(parent_text)
+    assert float(jnp.abs(want[0])) > 1.0
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want)):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), jax.tree_util.keystr(path)

@@ -2,8 +2,8 @@
 published widths and the benchmark cell's shapes: the attention layer through the stock
 Pallas flash-attention kernel (Mosaic has to take it: heads of 64, blocks of 1,024), and an
 expert layer through the stock Pallas grouped matmul at both of its widths (the compact row buffer, and a
-row for every (token, slot) pair as the fallback). No chip is attached: nothing runs, and nothing here is a
-device number.
+row for every (token, slot) pair as the fallback); and a block's gradient makes none of the block's matmul
+products a second time. No chip is attached: nothing runs, and nothing here is a device number.
 
 The topology is described inside a fixture, never while a module is imported (only one
 process may load the TPU's library, and every xdist worker imports every test file), and
@@ -62,13 +62,9 @@ def _compile(fn, *specs):
 
 def test_attention_layer_through_the_flash_kernel_compiles_for_v5e(one_chip, cut, monkeypatch):
     monkeypatch.setattr(lm, "on_tpu", lambda: True)  # no chip is attached here: the program would take its CPU branch
-    bf = jnp.bfloat16
-    p = {
-        "q": _spec((D, 2048), bf, one_chip), "k": _spec((D, 512), bf, one_chip), "v": _spec((D, 512), bf, one_chip),
-        "o": _spec((2048, D), bf, one_chip), "q_norm": _spec((64,), jnp.float32, one_chip), "k_norm": _spec((64,), jnp.float32, one_chip),
-    }
+    p = _block(one_chip, "attn", "moe")["attn"]
     grad = jax.grad(lambda p, n: lm.attn_op(p, n, cut)[0].astype(jnp.float32).sum(), argnums=(0, 1))
-    text = _compile(grad, p, _spec((B, T, D), bf, one_chip)).as_text()
+    text = _compile(grad, p, _spec((B, T, D), jnp.bfloat16, one_chip)).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') >= 3  # the forward, dq and dkv kernels are in the program
 
 
@@ -124,3 +120,67 @@ def test_a_decode_step_s_expert_layer_compiles_for_v5e(one_chip, cut, monkeypatc
     text = _compile(lambda p, x: lm.moe_ffn(p, x, cut)[0], p, _spec((2, D), jnp.bfloat16, one_chip)).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert " conditional(" not in text  # eight pairs round up to all of them: one width, the parent's program
+
+
+def _block(one_chip, mixer, ffn):
+    """The shapes of one block's working copy: matmul operands in bfloat16, norm scales in float32."""
+    bf, f32 = jnp.bfloat16, jnp.float32
+    p = {"op_norm": _spec((D,), f32, one_chip), "ffn_norm": _spec((D,), f32, one_chip)}
+    if mixer == "conv":
+        p["conv"] = {
+            "in_proj": _spec((D, 3 * D), bf, one_chip), "filter": _spec((3, D), bf, one_chip), "out_proj": _spec((D, D), bf, one_chip),
+        }
+    else:
+        p["attn"] = {
+            "q": _spec((D, 2048), bf, one_chip), "k": _spec((D, 512), bf, one_chip), "v": _spec((D, 512), bf, one_chip),
+            "o": _spec((2048, D), bf, one_chip), "q_norm": _spec((64,), f32, one_chip), "k_norm": _spec((64,), f32, one_chip),
+        }
+    if ffn == "dense":
+        p["ffn"] = {"w1": _spec((D, 7168), bf, one_chip), "w3": _spec((D, 7168), bf, one_chip), "w2": _spec((7168, D), bf, one_chip)}
+    else:
+        p["moe"] = _expert_layer(one_chip)
+    return p
+
+
+def test_a_conv_block_with_its_dense_ffn_makes_no_product_twice(one_chip, cut, monkeypatch):
+    """Layer 0 of the cut, forwards and backwards at the cell's shapes: five products forwards and two transposes
+    for each going backwards, 15 ``convolution``s, none of them under ``rematted_computation``. The parent's
+    formulation (a plain ``jax.checkpoint`` with no policy) makes ``in_proj``, ``out_proj``, ``w1`` and ``w3`` again: 19."""
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)
+    p, x = _block(one_chip, "conv", "dense"), _spec((B, T, D), jnp.bfloat16, one_chip)
+    remade = ("rematted_computation/lm.conv/dot_general", "rematted_computation/lm.dense_ffn/dot_general")
+
+    def compiled_block():  # traced anew for each formulation
+        fn = jax.value_and_grad(lambda p, x: lm._layer(p, x, cut, "conv", "dense")[0].astype(jnp.float32).sum(), argnums=(0, 1))
+        compiled = _compile(fn, p, x)
+        return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+    text, temp = compiled_block()
+    assert len(re.findall(r" convolution\(", text)) == 15
+    assert not any(name in text for name in remade)
+    # The kept products are 0.74 GB of bfloat16 (201 + 67 + 2 x 235 MB), and the plan is smaller for them: 1,108,875,264 B
+    # here against the parent's 1,311,196,672 (my compiles, PR 32), which holds the second copies while the transposes run.
+    assert temp < 1.2e9
+    monkeypatch.setattr(lm, "KEEP_PRODUCTS", None)  # the parent's: `jax.checkpoint(whole)`
+    parent_text, _ = compiled_block()
+    assert len(re.findall(r" convolution\(", parent_text)) == 19
+    assert all(name in parent_text for name in remade)
+
+
+def test_the_attention_block_around_the_flash_kernel_makes_no_projection_twice(one_chip, cut, monkeypatch):
+    """The branch a TPU takes through the attention block (two halves around the flash kernel), lowered and not
+    compiled: going backwards neither half makes ``q``, ``k``, ``v`` or ``o``'s product again; the router's small
+    float32 product is still made again (the expert layer keeps its inputs only)."""
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)
+    p, x = _block(one_chip, "attn", "moe"), _spec((B, T, D), jnp.bfloat16, one_chip)
+
+    def lowered_block():
+        fn = jax.grad(lambda p, x: lm._layer(p, x, cut, "attn", "moe")[0].astype(jnp.float32).sum(), argnums=(0, 1), allow_int=True)
+        with jax.default_matmul_precision("high"):
+            return jax.jit(fn).lower(p, x).as_text(debug_info=True)
+
+    text = lowered_block()
+    assert "rematted_computation/lm.attn/dot_general" not in text
+    assert "rematted_computation/lm.moe.route/dot_general" in text
+    monkeypatch.setattr(lm, "KEEP_PRODUCTS", None)
+    assert "rematted_computation/lm.attn/dot_general" in lowered_block()
