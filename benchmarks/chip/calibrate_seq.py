@@ -3,6 +3,7 @@ and the faults judged by them (PERF.md, the calibration table of ``check_seq``).
 
     python3 benchmarks/chip/calibrate_seq.py --workload <cell> --seeds 4 --controls 2 --out <file.json>
     python3 benchmarks/chip/calibrate_seq.py --workload <cell> --seeds 2 --fault three_experts --out <file.json>
+    python3 benchmarks/chip/calibrate_seq.py --workload <cell> --seed-list 7,11 --controls 0 --witness --out <file.json>
 
 One process on the chip, at the cell's own sizes; what `calibrate.py` does for the
 replay-fed cells. For every seed: the program's compared steps through the driver's own
@@ -12,7 +13,12 @@ place). With ``--fault`` every seed reads that fault of faults_seq.py planted in
 instead: one fault a process, because two train programs of this size do not load beside
 the state on one chip (my chip run, PR 29: RESOURCE_EXHAUSTED at the second). Every set of
 numbers also goes through `check_seq.judge` with the configuration's limits: the sound
-program has to come out correct, the control and each fault not. A benchmark run never runs this.
+program has to come out correct, the control and each fault not. ``--witness`` reads, for
+every seed, the reference once more with both operands of every matmul rounded to bfloat16
+(the precision the configuration states, made by other code than the program's) against the
+float32 reference: where the program reads far off on a seed and this witness does too, the
+seed's later steps amplify the rounding and the program is sound (PERF.md section 5, PR 33).
+A benchmark run never runs this.
 """
 
 from __future__ import annotations
@@ -31,7 +37,9 @@ def main(argv) -> int:
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seeds", type=int, default=4)
     parser.add_argument("--first-seed", type=int, default=1000)
+    parser.add_argument("--seed-list", help="these seeds, separated by commas, instead of --seeds from --first-seed on")
     parser.add_argument("--controls", type=int, default=2)
+    parser.add_argument("--witness", action="store_true", help="also the reference with bfloat16 operands, every seed")
     parser.add_argument("--fault", help="a name of faults_seq.FAULTS: read that fault on every seed, and nothing else")
     parser.add_argument("--out", required=True)
     parser.add_argument("--rehearse-cpu", action="store_true")
@@ -77,9 +85,17 @@ def main(argv) -> int:
         failed = [k for k, v in verdict["compared"].items() if v["limit"] is not None and not v["value"] <= v["limit"]]
         return {"numbers": numbers, "correct": verdict["correct"], "failed": failed}
 
+    def bf16_operands(x):
+        """The witness's hook: an operand as bfloat16 holds it; the products are summed in float32.
+        `reduce_precision` and not a cast there and back, which XLA:TPU takes out (it allows excess
+        precision: my chip run, PR 33, read the witness equal to the reference to the last digit)."""
+        return x + jax.lax.stop_gradient(jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7) - x)
+
+    seeds = [args.first_seed + 7919 * n for n in range(args.seeds)]
+    if args.seed_list:
+        seeds = [int(x) for x in args.seed_list.split(",")]
     rows = []
-    for n in range(args.seeds):
-        seed = args.first_seed + 7919 * n
+    for n, seed in enumerate(seeds):
         row = {"seed": seed, "readings": {}}
         out = row["readings"]
         # each subject is judged against the reference over the rollouts that it was fed itself
@@ -93,6 +109,11 @@ def main(argv) -> int:
             control = probe.reference_readings(quant=probe.reference.fake_fp8)
             out["control_fp8"] = reading(tokens_wrong, control, ref)
             del control
+        if not args.fault and args.witness:
+            witness = probe.reference_readings(quant=bf16_operands)
+            out["witness_bf16"] = reading(tokens_wrong, witness, ref)
+            out["witness_bf16"]["losses"] = witness["losses"]
+            del witness
         del probe, prog, ref
         rows.append(row)
         print(json.dumps(row), flush=True)

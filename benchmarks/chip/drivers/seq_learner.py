@@ -313,15 +313,17 @@ def run(cell, seed, seconds, trace, rehearse, devices, t_start, out_dir) -> Dict
     }
     if trace:
         reduce = load_module("", "reduce", cell["here"])
-        scopes_lm = load_module("", "scopes_lm", cell["here"])
+        scopes = load_module("", "scopes", cell["here"])
         # the device events carry no scope: the compiled program's text does (scopes.py). main() calls the
         # train function through plain jit, so its text is made again here from the call's own specs; the
         # executable comes out of the persistent cache
         with open(os.path.join(trace_dir, "train.hlo.txt"), "w") as f:
-            f.write(scopes_lm.compiled_text(built["train_fn"], built["state"]["specs"]))
+            f.write(scopes.compiled_text(built["train_fn"], built["state"]["specs"]))
         out["trace"] = reduce.reduce_dir(trace_dir)
-        out["scopes"] = scopes_lm.reduce_dir(trace_dir)  # device self time by lm.* / ppo.* scope
-        out["trace"]["breakdown"]["device_ms_a_step_by_scope"] = scopes_lm.ms_a_step(out["scopes"])
+        # device self time by the scopes that the configuration's count file names
+        names = load_module("", "flops", cell["here"]).scopes_of(cell["config_file"])
+        out["scopes"] = scopes.reduce_dir(trace_dir, names)
+        out["trace"]["breakdown"]["device_ms_a_step_by_scope"] = scopes.ms_a_step(out["scopes"])
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     # ---- the comparison, once the window has closed, the peak is read and the program's state is freed

@@ -1,10 +1,29 @@
 """The yardstick's operation counts: model FLOPs of one gradient step, from shapes.
 
+A configuration file names its count as ``flops``: ``<file>:<function>`` is that
+function of ``<file>.py`` beside this file; a bare name (the two accepted
+configuration files have one each) is a function of this file or, where this file
+has none of that name, of ``flops_<the name's first word>.py``. Nothing is
+registered and no count file needs loading before another: `count_of` finds the
+file when it is asked. A count file offers
+
+- the count itself, ``f(sizes)`` or ``f(sizes, pairs_here)`` (the (token, slot)
+  pairs an expert layer computed on this chip, the program's ``Moe/pairs_here``):
+  FLOPs of ONE gradient step by the scope the program runs the part under, with
+  their ``total``;
+- ``UNCOUNTED``: the program's scopes that hold no counted work (`scopes.py` looks
+  for the counted parts and for these);
+- ``LAYERS``, where several configurations share a per-layer metric: for a layer
+  of ``BENCHMARK.json`` the scopes whose device time is that layer's;
+- ``kernels(sizes, pairs_here)``, where the program runs Pallas kernels: for each
+  family (``gmm``, ``attention``) the scope its kernels run under and the least
+  ``flops`` and ``bytes`` a step.
+
+This file is also the count file of the DreamerV3 configurations:
 ``dv3_step_flops`` is a copy of ``benchmarks/analytic_flops.py`` (the original
 stays for ``bench.py``); it reads the sizes from the configuration file's
 ``sizes`` instead of the program's config tree, so no later PR of the program
-can move it. A new model adds its count to ``COUNTS`` under the name its
-configuration file gives as ``flops``.
+can move it.
 
 Counting rules: a matmul [m,k]@[k,n] is 2*m*k*n; a convolution is
 2 * out_spatial * C_out * C_in * k*k per sample; a path that receives parameter
@@ -14,7 +33,17 @@ operations, LayerNorms, activations and softmaxes are not counted.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Sequence
+import os
+import sys
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+from common import load_module  # noqa: E402
+
+# dv3.train's scopes that hold no counted work (the optimizers, the return moments, the target's average, the player's copy)
+UNCOUNTED = ("world_opt", "actor_opt", "critic_opt", "moments", "target_ema", "player_ravel")
+
 
 def _mm(m: float, k: float, n: float) -> float:
     return 2.0 * m * k * n
@@ -113,12 +142,44 @@ def dv3_step_flops(sizes: Dict[str, Any]) -> Dict[str, float]:
     return parts
 
 
-COUNTS = {"dv3_step_flops": dv3_step_flops}
+def count_of(config: Dict[str, Any]) -> Tuple[Any, Callable[..., Dict[str, float]]]:
+    """(count file, count) that ``config["flops"]`` names, among the files beside this one."""
+    file, _, function = str(config["flops"]).rpartition(":")
+    if not file:
+        file = "flops" if function in globals() else "flops_" + function.split("_")[0]
+    module = load_module("", file, HERE)
+    count = getattr(module, function, None)
+    if not callable(count):
+        raise KeyError(f"configuration {config.get('name')!r} names the FLOP count {config['flops']!r}: {file}.py has no such function")
+    return module, count
 
 
-def step_flops(config: Dict[str, Any]) -> float:
+def step_parts(config: Dict[str, Any], pairs_here: Optional[float] = None) -> Dict[str, float]:
+    """Model FLOPs of one gradient step of ``config`` by scope, with their ``total``; ``pairs_here``
+    (where the run has the program's count of them) goes to a count that takes it."""
+    count = count_of(config)[1]
+    return count(config["sizes"]) if pairs_here is None else count(config["sizes"], pairs_here)
+
+
+def step_flops(config: Dict[str, Any], pairs_here: Optional[float] = None) -> float:
     """Model FLOPs of one gradient step of ``config`` (its ``flops`` names the count)."""
-    name = config["flops"]
-    if name not in COUNTS:
-        raise KeyError(f"configuration {config['name']!r} names the FLOP count {name!r}; flops.py has {sorted(COUNTS)}")
-    return float(COUNTS[name](config["sizes"])["total"])
+    return float(step_parts(config, pairs_here)["total"])
+
+
+def scopes_of(config: Dict[str, Any]) -> Tuple[str, ...]:
+    """The scopes to look for in a capture of ``config``'s train program: the parts its count file
+    counts, then the ones it lists as uncounted."""
+    module, count = count_of(config)
+    return tuple(k for k in count(config["sizes"]) if k != "total") + tuple(getattr(module, "UNCOUNTED", ()))
+
+
+def layer_scopes(config: Dict[str, Any], layer: str) -> Tuple[str, ...]:
+    """The scopes whose device time is ``layer``'s in ``config`` (its count file's ``LAYERS``); () where it has none."""
+    return tuple(getattr(count_of(config)[0], "LAYERS", {}).get(layer, ()))
+
+
+def kernel_least(config: Dict[str, Any], family: str, pairs_here: Optional[float] = None) -> Optional[Dict[str, Any]]:
+    """``scope``, ``flops`` and ``bytes``: the least a step that the Pallas kernels of ``family`` must do
+    in ``config``, and the scope they run under; None where its count file names no such kernels."""
+    kernels = getattr(count_of(config)[0], "kernels", None)
+    return kernels(config["sizes"], pairs_here).get(family) if kernels else None

@@ -1,6 +1,7 @@
 """Operation counts of one token-level PPO gradient step on the LFM2 cut, by part,
 under the names of the scopes the program runs its parts in (``models/lm.py``'s
-``SCOPES``; ``ppo.loss`` and ``ppo.opt`` are not counted: no matmul).
+``SCOPES``; ``ppo.loss`` and ``ppo.opt`` are not counted: no matmul). A count file
+as ``flops.py`` describes one: `lfm2_step_flops`, ``UNCOUNTED``, ``LAYERS``, `kernels`.
 
 Counting rules as ``flops.py``: a matmul [m,k]@[k,n] is 2*m*k*n; a trained path
 costs 3x its forward; causal attention counts the lower triangle (half of
@@ -17,7 +18,13 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from common import HERE, load_module
+UNCOUNTED = ("ppo.loss", "ppo.opt")
+# the scopes whose device time a shared per-layer metric adds up, by the metric's layer in BENCHMARK.json
+LAYERS = {
+    "expert layer": ("lm.moe.route", "lm.moe.experts"),
+    "token mixers": ("lm.conv", "lm.attn"),
+    "head and loss": ("lm.head", "ppo.loss"),
+}
 
 
 def _kinds(s: Dict[str, Any]):
@@ -90,13 +97,10 @@ def flash_attention_least(s: Dict[str, Any]) -> Dict[str, float]:
     }
 
 
-COUNTS = {"lfm2_step_flops": lfm2_step_flops}
-# flops.py: "a new model adds its count to COUNTS under the name its configuration file gives as flops".
-# This file may not edit that one, so the count is added when this module is loaded (the cell's
-# metric readers load it); with the whole-step signature flops.py's table has, the expectation of the pairs.
-load_module("", "flops", HERE).COUNTS.setdefault("lfm2_step_flops", lfm2_step_flops)
-
-
-def step_flops(config: Dict[str, Any], pairs_here: Optional[float] = None) -> float:
-    """Model FLOPs of one gradient step of ``config`` (its ``flops`` names the count)."""
-    return float(COUNTS[config["flops"]](config["sizes"], pairs_here)["total"])
+def kernels(s: Dict[str, Any], pairs_here: Optional[float] = None) -> Dict[str, Dict[str, Any]]:
+    """The program's Pallas kernels by family: the scope they run under and the least they must do a step
+    (megablox ``gmm`` / ``tgmm`` for the experts' grouped products, flash attention forward, dq and dkv)."""
+    return {
+        "gmm": {"scope": "lm.moe.experts", "flops": lfm2_step_flops(s, pairs_here)["lm.moe.experts"], "bytes": lfm2_gmm_bytes(s, pairs_here)},
+        "attention": {"scope": "lm.attn", **flash_attention_least(s)},
+    }

@@ -1,6 +1,7 @@
-"""Device self time a step under the scopes ``lm.head`` and ``ppo.loss`` (the logits over the held
-vocabulary in chunks, log-softmax, entropy, the critic and the PPO losses; forwards, recomputed and
-backwards), from the driver's reduction of the capture by scope (scopes_lm.py).
+"""Device self time a step under the scopes of the head and the loss (the configuration's count file
+lists them under the layer ``head and loss``: the logits over the held vocabulary in chunks, log-softmax,
+entropy, the critic and the PPO losses; forwards, recomputed and backwards), from the driver's reduction
+of the capture by scope (scopes.py).
 
 Read in the ``--trace 1`` run, whose window is the traffic mix's ``trace_seconds``, whatever ``--seconds`` asks for.
 """
@@ -8,4 +9,4 @@ from common import load_module
 
 
 def read(run):
-    return load_module("", "scopes_lm", run["cell"]["here"]).scope_ms(run, "lm.head", "ppo.loss")
+    return load_module("", "scopes", run["cell"]["here"]).layer_ms(run, "head and loss")

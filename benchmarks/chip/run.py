@@ -103,6 +103,7 @@ def main(argv: List[str]) -> int:
         device["busy_s"] = run["trace"]["busy_s"]
         device["window_s"] = run["trace"]["window_s"]
         result["breakdown"] = run["trace"]["breakdown"]
+    result["counters"] = run.get("counters", {})  # the last train call's scalars, where the driver keeps them
     result["steps"] = run["steps"]
     result["compared"] = run["check"]["compared"]  # each number beside its limit, last in the line
     sys.stdout.flush()

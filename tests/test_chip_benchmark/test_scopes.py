@@ -1,7 +1,8 @@
-"""PR 27's part of the on-chip benchmark, on the CPU: the scope names are the yardstick's
-part names, ``scopes.py``'s arithmetic on small synthetic lists, the seven readers that
-read the program's own spans and counters, and ``dv3.train``'s named scopes (metadata
-only: the same bits come out, and the lowered program carries every name).
+"""``scopes.py`` on the CPU: the scopes it looks for are each configuration's count file's
+(and the program's own names), its arithmetic on small synthetic lists for one-word and for
+dotted scopes, the seven readers of PR 27 that read the program's own spans and counters, and
+``dv3.train``'s named scopes (metadata only: the same bits come out, and the lowered program
+carries every name).
 """
 
 from __future__ import annotations
@@ -18,20 +19,34 @@ sys.path.insert(0, CHIP)
 import common  # noqa: E402
 
 scopes = common.load_module("", "scopes")
+flops = common.load_module("", "flops")
 NEW_METRICS = (
     "prefetch_wait_ms", "prefetch_sample_ms", "prefetch_h2d_ms", "h2d_mib_per_step",
     "train_route_ms", "train_execute_ms", "setup_lower_s",
 )
 
 
-def test_every_counted_part_is_a_scope_of_the_program():
-    from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import TRAIN_SCOPES
+def _program_scopes(name):
+    if name == "dv3_xl_crafter":
+        from sheeprl_tpu.algos.dreamer_v3.dreamer_v3 import TRAIN_SCOPES
 
-    config = common.load_json(CHIP, "configs", "dv3_xl_crafter.json")
-    parts = set(common.load_module("", "flops").dv3_step_flops(config["sizes"])) - {"total"}
-    assert parts and parts <= set(TRAIN_SCOPES)
-    assert tuple(scopes.SCOPES) == tuple(TRAIN_SCOPES)
-    assert parts == set(scopes.SCOPES) - set(scopes.UNCOUNTED)
+        return tuple(TRAIN_SCOPES)
+    from sheeprl_tpu.models import lm
+
+    return tuple(lm.SCOPES) + ("ppo.loss", "ppo.opt")  # ppo_recurrent.train's own two, around the model's
+
+
+@pytest.mark.parametrize("name", ["dv3_xl_crafter", "lfm2_8b_a1b_ep4"])
+def test_every_counted_part_is_a_scope_of_the_program(name):
+    """What the reduction looks for is data: the parts the configuration's count file counts, then the
+    ones it lists as uncounted; for both accepted configurations that is the program's own tuple."""
+    config = common.load_json(CHIP, "configs", f"{name}.json")
+    module, count = flops.count_of(config)
+    parts = tuple(k for k in count(config["sizes"]) if k != "total")
+    assert parts and flops.scopes_of(config) == parts + tuple(module.UNCOUNTED) == _program_scopes(name)
+    assert set(flops.scopes_of(config)) <= set(scopes.known_scopes())  # and what is looked for where no configuration is named
+    for layer, names in getattr(module, "LAYERS", {}).items():
+        assert set(names) <= set(flops.scopes_of(config)), layer
 
 
 def test_self_time_gives_a_while_only_what_its_body_leaves():
@@ -92,7 +107,8 @@ def test_summarize_sums_to_busy_and_labels_gaps_by_the_innermost_span():
         {"encoder": 2.0, "dynamic_scan": 4.5, "unscoped": 0.5, "other_programs": 0.5}
     )  # the second run is cut at the window's end, before fusion.5
     assert sum(out["scopes"].values()) == pytest.approx(out["busy_s"])
-    assert dict(map(tuple, out["unscoped_ops"])) == pytest.approx({"copy.6": 0.5})
+    assert out["unscoped_ops"] == [["copy.6", pytest.approx(0.5), ""]] and out["kernels"] == {}  # no path, no kernel
+    assert dict(map(tuple, out["top_ops"]["dynamic_scan"])) == pytest.approx({"convolution.4": 4.5})
     assert out["steps"] == pytest.approx(1.75)
     # gaps 0-1, 5-5.5, 6-7, labelled thread by thread and by the innermost span: execute covers 0.6 of
     # the first, player.push most of the others; never the train.call around them
@@ -101,6 +117,46 @@ def test_summarize_sums_to_busy_and_labels_gaps_by_the_innermost_span():
     assert out["idle_gaps_over_1ms"] == out["idle_gaps"]
     with pytest.raises(ValueError):
         scopes.summarize(ops, modules, {}, {"jit_train": table})
+    # a driver's reduction: the same sums by a configuration's own scopes, the idle gaps by thread left out
+    names = flops.scopes_of(common.load_json(CHIP, "configs", "dv3_xl_crafter.json"))
+    brief = scopes.summarize(ops, modules, host, {"jit_train": table}, names, idle_by_thread=False)
+    assert brief["scopes"] == out["scopes"] and brief["busy_s"] == out["busy_s"] and "idle_gaps" not in brief
+    assert scopes.summarize(ops, modules, host, {"jit_train": table}, ("encoder",))["scopes"] == pytest.approx(
+        {"encoder": 2.0, "unscoped": 5.0, "other_programs": 0.5}
+    )  # a scope that is not looked for is not found
+
+
+def test_scope_of_reads_dotted_scopes_innermost_last():
+    path = "jit(train)/jit(main)/while/body/ppo.loss/transpose(jvp(lm.moe.experts))/ragged_dot"
+    assert scopes.scope_of(path) == "lm.moe.experts"
+    assert scopes.scope_of("jit(train)/while/body/ppo.loss/checkpoint/lm.attn/dot_general") == "lm.attn"
+    assert scopes.scope_of("jit(train)/while/body/ppo.opt/mul") == "ppo.opt"
+    assert scopes.scope_of("jit(train)/while/body/ppo.loss/jvp(lm.moe.route)/top_k") == "lm.moe.route"
+    assert scopes.scope_of("jit(train)/convert_element_type") == "unscoped"
+    # a dotted name reads whole: neither its words nor a longer name stand for it
+    assert scopes.scope_of("jit(train)/lm.attn.cache/mul") == "unscoped" and scopes.scope_of("jit(train)/lm/attn/mul") == "unscoped"
+    assert scopes.scope_of("jit(train)/lm.attn/mul", ("lm.conv",)) == "unscoped" and scopes.scope_of("jit(train)/lm.extra/mul", ("lm.extra",)) == "lm.extra"
+    text = (
+        'HloModule jit_train\n'
+        '  %fusion.3 = bf16[8,8]{1,0} fusion(%p), kind=kLoop, metadata={op_name="jit(train)/lm.conv/mul"}\n'
+        '  %custom-call.7 = bf16[8,8]{1,0} custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(train)/lm.attn/pallas_call"}\n'
+    )
+    assert scopes.kernel_instructions(text) == {"custom-call.7"}
+    # a step of two ops: the kernel's time is counted under its scope, and apart
+    ops = [("fusion.3", 0.0, 0.3), ("custom-call.7", 0.4, 1.0)]
+    table = scopes.op_names(text)
+    summary = scopes.summarize(ops, [("jit_train", 0.0, 1.0)], {"t": [("train", 0.0, 1.0)]}, {table[0]: table[1]}, kernels={table[0]: {"custom-call.7"}})
+    assert summary["scopes"] == pytest.approx({"lm.conv": 0.3, "lm.attn": 0.6}) and summary["kernels"] == pytest.approx({"lm.attn": 0.6})
+    run = {"scopes": summary, "config": common.load_json(CHIP, "configs", "lfm2_8b_a1b_ep4.json")}
+    assert scopes.scope_ms(run, "lm.conv", "lm.attn") == pytest.approx(900.0) and scopes.kernel_ms(run, "lm.attn") == pytest.approx(600.0)
+    assert scopes.scope_ms({}, "lm.conv") is None and scopes.kernel_ms(run, "lm.conv") is None
+    assert scopes.ms_a_step(summary) == [["lm.attn", pytest.approx(600.0)], ["lm.conv", pytest.approx(300.0)]] and scopes.ms_a_step(None) == []
+    # by layer and by kernel family, both from the configuration's count file; nothing where it lists nothing
+    assert scopes.layer_ms(run, "token mixers") == pytest.approx(900.0) and scopes.layer_ms(run, "expert layer") is None
+    assert scopes.layer_ms(run, "no such layer") is None and scopes.roofline_pct(run, "attention") is None  # no peak
+    run["peak"] = common.peak_for("TPU v5 lite")
+    assert scopes.roofline_pct(run, "attention") == pytest.approx(100 * (1.649e12 / 197e12) / 0.6, rel=1e-3)
+    assert scopes.roofline_pct(run, "gmm") is None and scopes.roofline_pct(run, "no such family") is None  # no kernel ran under its scope
 
 
 def _run(attempted=4):
@@ -160,15 +216,6 @@ def test_new_readers_on_a_hand_made_run_and_ring():
         for fn in snapshot["functions"].values():
             del fn["route_seconds"], fn["execute_seconds"]
     assert [_read(n, run) for n in NEW_METRICS[4:]] == [None, None, None]
-
-
-def test_benchmark_json_lists_the_new_metrics_last_and_every_cell_reports_them():
-    bench = common.load_json(ROOT, "BENCHMARK.json")
-    assert tuple(m["name"] for m in bench["per_layer"][-7:]) == NEW_METRICS
-    assert all("workloads" not in m and m["better"] == "lower" for m in bench["per_layer"][-7:])
-    for w in bench["workloads"]:
-        reported = [m["name"] for m in common.resolve_cell(w["name"])["per_layer"]]
-        assert set(NEW_METRICS) <= set(reported)
 
 
 # ---------------------------------------------------------------- the named scopes of dv3.train

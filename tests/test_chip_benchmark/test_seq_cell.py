@@ -20,14 +20,6 @@ sys.path.insert(0, CHIP)
 import common  # noqa: E402
 
 CELL = "lfm2_ep4.ppo_update_8k"
-PR27_METRICS = (
-    "prefetch_wait_ms", "prefetch_sample_ms", "prefetch_h2d_ms", "h2d_mib_per_step",
-    "train_route_ms", "train_execute_ms", "setup_lower_s",
-)
-NEW_METRICS = (
-    "lm_train_mfu_pct", "moe_device_ms", "mixer_device_ms", "head_loss_device_ms", "moe_experts_roofline_pct",
-    "moe_load_max_over_mean", "rollout_feed_ms", "flash_attention_roofline_pct", "gmm_roofline_pct",
-)
 TEST_LIMIT = 1e-4  # float32 on both sides at tiny sizes: program and reference differ by rounding order alone
 
 
@@ -91,25 +83,11 @@ def test_the_program_runs_the_configuration_file_s_model():
     assert cfg.fabric.precision == config["precision"] and cfg.fabric.player_on_host is False
 
 
-def test_accepted_metrics_keep_their_entries_and_their_cell():
-    """What is left of the accepted test that this PR's conftest marks as superseded."""
-    bench = common.load_json(ROOT, "BENCHMARK.json")
-    names = [m["name"] for m in bench["per_layer"]]
-    assert tuple(names[10:17]) == PR27_METRICS and tuple(names[17:]) == NEW_METRICS
-    for m in bench["per_layer"][:17]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
-        assert m.get("workloads", ["dv3_xl.chip_player"]) == ["dv3_xl.chip_player"]
-    reported = [m["name"] for m in common.resolve_cell("dv3_xl.chip_player")["per_layer"]]
-    assert reported == names[:17]  # all seventeen, as before
-    here = [m["name"] for m in common.resolve_cell(CELL)["per_layer"]]
-    absent = {"train_mfu_pct", "prefetch_wait_ms", "prefetch_sample_ms", "prefetch_h2d_ms", "h2d_mib_per_step"}
-    assert set(here) == (set(names[:17]) - absent) | set(NEW_METRICS)
-    assert all(m["workloads"] == [CELL] and m["moves"] == "gsteps_per_s" for m in bench["per_layer"][17:])
-
-
 def test_flop_count_is_the_issue_s_arithmetic():
-    flops = common.load_module("", "flops_lfm2")
-    sizes = common.load_json(CHIP, "configs", "lfm2_8b_a1b_ep4.json")["sizes"]
+    config = common.load_json(CHIP, "configs", "lfm2_8b_a1b_ep4.json")
+    shared, sizes = common.load_module("", "flops"), config["sizes"]
+    flops, count = shared.count_of(config)  # the configuration's count file, found by the name it gives
+    assert count is flops.lfm2_step_flops and shared.step_parts(config) == count(sizes)
     parts = flops.lfm2_step_flops(sizes)
     tokens = 2 * 8192
     assert parts["total"] == pytest.approx(21.26e12, rel=2e-3) and parts["total"] / tokens == pytest.approx(1.30e9, rel=5e-3)
@@ -119,10 +97,20 @@ def test_flop_count_is_the_issue_s_arithmetic():
     assert macs["lm.head"] == pytest.approx(33.6e6, rel=2e-3)
     assert flops.expected_pairs(sizes) == 4 * tokens  # one pair a token a layer, four expert layers
     assert flops.lfm2_step_flops(sizes, pairs_here=0.0)["lm.moe.experts"] == 0.0
-    assert flops.step_flops({"flops": "lfm2_step_flops", "sizes": sizes}, pairs_here=2 * 4 * tokens) > parts["total"]
-    assert "lfm2_step_flops" in common.load_module("", "flops").COUNTS  # added on load, as flops.py asks
+    assert shared.step_flops(config, pairs_here=2 * 4 * tokens) > shared.step_flops(config) == parts["total"]
+    assert shared.step_flops({"flops": "flops_lfm2:lfm2_step_flops", "sizes": sizes}) == parts["total"]  # the form a new configuration gives
+    with pytest.raises(KeyError):
+        shared.step_flops({"name": "x", "flops": "flops_lfm2:no_such_count", "sizes": sizes})
+    with pytest.raises(FileNotFoundError):
+        shared.step_flops({"name": "x", "flops": "nowhere_step_flops", "sizes": sizes})
     least = flops.flash_attention_least(sizes)
     assert least["flops"] == pytest.approx(1.649e12, rel=1e-3) and least["bytes"] == pytest.approx(0.537e9, rel=1e-2)
+    # what the shared readers ask the count file: its kernels by family, its scopes by layer
+    assert shared.kernel_least(config, "attention") == {"scope": "lm.attn", **least}
+    gmm = shared.kernel_least(config, "gmm", 65536.0)
+    assert gmm == {"scope": "lm.moe.experts", "flops": flops.lfm2_step_flops(sizes, 65536.0)["lm.moe.experts"], "bytes": flops.lfm2_gmm_bytes(sizes, 65536.0)}
+    assert shared.kernel_least(config, "no such family") is None and shared.kernel_least(common.load_json(CHIP, "configs", "dv3_xl_crafter.json"), "gmm") is None
+    assert shared.layer_scopes(config, "expert layer") == ("lm.moe.route", "lm.moe.experts") and shared.layer_scopes(config, "device") == ()
 
 
 def test_rollouts_come_from_the_seed_and_are_zipf_with_one_terminal_reward():
@@ -140,30 +128,6 @@ def test_rollouts_come_from_the_seed_and_are_zipf_with_one_terminal_reward():
     assert not r["rewards"][:-1].any() and set(np.unique(r["rewards"][-1])) <= {0.0, 1.0} and r["dones"][-1].all()
     ids = np.concatenate([x["tokens"].reshape(-1) for x in a])
     assert np.mean(ids == 0) > 5 * np.mean(ids == 9)  # Zipf: the first id far ahead of the tenth
-
-
-def test_scope_of_reads_dotted_scopes_innermost_last():
-    scopes_lm = common.load_module("", "scopes_lm")
-    path = "jit(train)/jit(main)/while/body/ppo.loss/transpose(jvp(lm.moe.experts))/ragged_dot"
-    assert scopes_lm.scope_of(path) == "lm.moe.experts"
-    assert scopes_lm.scope_of("jit(train)/while/body/ppo.loss/checkpoint/lm.attn/dot_general") == "lm.attn"
-    assert scopes_lm.scope_of("jit(train)/while/body/ppo.opt/mul") == "ppo.opt"
-    assert scopes_lm.scope_of("jit(train)/while/body/ppo.loss/jvp(lm.moe.route)/top_k") == "lm.moe.route"
-    assert scopes_lm.scope_of("jit(train)/convert_element_type") == "unscoped"
-    text = (
-        'HloModule jit_train\n'
-        '  %fusion.3 = bf16[8,8]{1,0} fusion(%p), kind=kLoop, metadata={op_name="jit(train)/lm.conv/mul"}\n'
-        '  %custom-call.7 = bf16[8,8]{1,0} custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(train)/lm.attn/pallas_call"}\n'
-    )
-    assert scopes_lm.kernel_instructions(text) == {"custom-call.7"}
-    # a step of two ops: the kernel's time is counted under its scope, and apart
-    ops = [("fusion.3", 0.0, 0.3), ("custom-call.7", 0.4, 1.0)]
-    table = common.load_module("", "scopes").op_names(text)
-    summary = scopes_lm.summarize(ops, [("jit_train", 0.0, 1.0)], {"t": [("train", 0.0, 1.0)]}, {table[0]: table[1]}, {table[0]: {"custom-call.7"}})
-    assert summary["scopes"] == pytest.approx({"lm.conv": 0.3, "lm.attn": 0.6}) and summary["kernels"] == pytest.approx({"lm.attn": 0.6})
-    run = {"scopes": summary}
-    assert scopes_lm.scope_ms(run, "lm.conv", "lm.attn") == pytest.approx(900.0) and scopes_lm.kernel_ms(run, "lm.attn") == pytest.approx(600.0)
-    assert scopes_lm.scope_ms({}, "lm.conv") is None and scopes_lm.kernel_ms(run, "lm.conv") is None
 
 
 def test_reference_follows_a_given_routing_and_reports_its_own():
@@ -334,6 +298,7 @@ def test_traced_path_with_the_device_plane_stubbed_reads_every_metric_it_can(mon
     values = {m["name"]: common.load_module("metrics", m["name"]).read(run) for m in run["cell"]["per_layer"]}
     assert values["rollout_feed_ms"] > 0 and values["rollout_feed_ms"] <= values["sample_wait_ms"]  # the span inside the benchmark's
     assert values["moe_load_max_over_mean"] >= 1.0 and values["window_compiles"] == 0
+    assert values["moe_compact_share"] == run["counters"]["Moe/compact_share"] and 0.0 <= values["moe_compact_share"] <= 1.0
     assert values["train_route_ms"] + values["train_execute_ms"] <= values["dispatch_ms"]
     for name in ("lm_train_mfu_pct", "moe_device_ms", "mixer_device_ms", "head_loss_device_ms", "moe_experts_roofline_pct", "flash_attention_roofline_pct", "gmm_roofline_pct"):
         assert values[name] is None  # device numbers: nothing on a CPU
