@@ -39,7 +39,8 @@ set-up does), not a wider buffer. A shared expert is computed whole on every
 chip. On a TPU the grouped products
 and the attention over whole sequences are the stock Pallas kernels (megablox
 ``gmm`` / ``tgmm``; splash attention, which reads the key-value heads as they are
-and skips the key blocks outside the mask); elsewhere ``jax.lax.ragged_dot`` and a
+and skips the key blocks outside the mask, with the block's products made in its
+layout [B, H, T, hd] so that nothing is transposed or cast around it); elsewhere ``jax.lax.ragged_dot`` and a
 blocked plain-JAX attention compute the same (:func:`on_tpu`). ``vocab_held`` is the slice of
 the vocabulary held here, ``layers`` the published layers that are run.
 
@@ -263,10 +264,14 @@ def working_copy(params: Dict[str, Any], dtype) -> Dict[str, Any]:
 
 
 # ----------------------------------------------------------------------------- parts
+def _row(x32: jax.Array, eps: float) -> jax.Array:
+    """The RMS norm's factor of each row of the last axis."""
+    return jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+
+
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps) * scale
-    return y.astype(x.dtype)
+    return (x32 * _row(x32, eps) * scale).astype(x.dtype)
 
 
 def _rope_tables(positions: jax.Array, hd: int, theta: float):
@@ -276,12 +281,77 @@ def _rope_tables(positions: jax.Array, hd: int, theta: float):
     return jnp.concatenate([jnp.cos(ang)] * 2, -1), jnp.concatenate([jnp.sin(ang)] * 2, -1)
 
 
-def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """``x`` [..., H, hd] rotated; ``cos`` / ``sin`` broadcast over the heads."""
+def _swap(a: jax.Array) -> jax.Array:
+    """The two halves of the last axis exchanged; for the small tables (:func:`_rot` for an array of ``q``'s size)."""
+    half = a.shape[-1] // 2
+    return jnp.concatenate([a[..., half:], a[..., :half]], -1)
+
+
+def _rot(x: jax.Array) -> jax.Array:
+    """Rotary's ``[-x2, x1]`` of the halves ``[x1, x2]`` of the last axis, in float32, as a product with the signed
+    permutation [hd, hd]: exact (one term a column), 17 GFLOP for a ``q`` of 2 x 8,192 x 32 x 128, and XLA:TPU takes
+    the vector work around it in as the product's epilogue. Written as slices and a concatenation along the minor
+    axis, the halves are two arrays of their own in HBM, forwards and backwards (my compiles for a described v5e,
+    PR 35). Its transpose is ``-_rot``."""
     hd = x.shape[-1]
+    turn = jnp.eye(hd, k=hd // 2, dtype=x.dtype) - jnp.eye(hd, k=-(hd // 2), dtype=x.dtype)
+    return jnp.einsum("...k,kj->...j", x, turn, precision=HI, preferred_element_type=jnp.float32)
+
+
+def _turned(x: jax.Array, a: jax.Array, b: Optional[jax.Array], rot_x: Optional[jax.Array] = None) -> jax.Array:
+    """``x a + rot(x) b`` in float32 (``b`` None: the first term alone); ``rot_x`` where the caller has ``rot(x)`` already."""
     x32 = x.astype(jnp.float32)
-    rotated = jnp.concatenate([-x32[..., hd // 2 :], x32[..., : hd // 2]], -1)
-    return (x32 * cos[..., None, :] + rotated * sin[..., None, :]).astype(x.dtype)
+    return x32 * a if b is None else x32 * a + (_rot(x) if rot_x is None else rot_x) * b
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _normed(x: jax.Array, a: jax.Array, b: Optional[jax.Array], eps: float) -> jax.Array:
+    """``(x a + rot(x) b) / rms(x)`` over the last axis of ``x`` [..., hd], in float32, rounded once: the float32
+    tables ``a`` and ``b`` (None: no second term) broadcast to ``x``. Its backward rule is written out so that it,
+    too, turns its two inputs only: it reads ``x`` and the cotangent, each in its own type, and writes ``x``'s
+    cotangent rounded once, with nothing of ``x``'s size in float32 between two passes (left to the transpose rules
+    the row factor's cotangent is a float32 array of ``x``'s size that one pass writes and the next reads)."""
+    x32 = x.astype(jnp.float32)
+    return (_turned(x, a, b) * _row(x32, eps)).astype(x.dtype)
+
+
+def _normed_fwd(x, a, b, eps):
+    return _normed(x, a, b, eps), (x, a, b)
+
+
+def _normed_bwd(eps, res, g):
+    x, a, b = res
+    x32, g32 = x.astype(jnp.float32), g.astype(jnp.float32)
+    row = _row(x32, eps)
+    rot_x = None if b is None else _rot(x)  # once, for the row factor's and for ``b``'s cotangent
+    by_row = jnp.sum(g32 * _turned(x, a, b, rot_x), axis=-1, keepdims=True)  # the row factor's cotangent
+    d_x = row * _turned(g, a, None if b is None else -_swap(b)) - (row**3 / x.shape[-1]) * by_row * x32
+
+    def to_table(z, table):  # summed over the axes a table is broadcast over
+        z = jnp.sum(z, axis=tuple(range(z.ndim - table.ndim)))
+        return jnp.sum(z, axis=tuple(i for i, size in enumerate(table.shape) if size == 1 and z.shape[i] != 1), keepdims=True)
+
+    scaled = g32 * row
+    return d_x.astype(x.dtype), to_table(scaled * x32, a), None if b is None else to_table(scaled * rot_x, b)
+
+
+_normed.defvjp(_normed_fwd, _normed_bwd)
+
+
+def _head_norm(x: jax.Array, scale: jax.Array, eps: float, tables=None, factor: float = 1.0) -> jax.Array:
+    """What lies between an attention product and its kernel, for ``q`` and for ``k``: the per-head RMSNorm of
+    ``x`` [..., hd], times ``factor`` (``1 / sqrt(hd)`` for ``q``: no kernel here has a scale of its own), rotated
+    by ``tables`` (cos, sin of :func:`_rope_tables`, broadcastable to ``x``; None where the layer has no rotary
+    embedding). One float32 expression, rounded once at its end, whatever the layout of the axes before the
+    last. Rotary is linear, so the norm's row factor is taken out of it, and the norm's scale, ``factor`` and
+    the tables are folded into two small tables: ``rope(x r s) = r (x (s cos) + rot(x) (swap(s) sin))``. The
+    halves are turned on the product itself (:func:`_rot`) and never on an intermediate, so nothing of ``x``'s
+    size exists in float32 outside one pass. ``q`` was rounded three times before (norm, rotary, scale), ``k`` twice."""
+    scale = scale.astype(jnp.float32) * factor
+    if tables is None:
+        return _normed(x, scale, None, eps)
+    cos, sin = tables
+    return _normed(x, scale * cos, _swap(scale) * sin, eps)
 
 
 def conv_op(p: Dict[str, jax.Array], n: jax.Array, tail: Optional[jax.Array] = None):
@@ -300,29 +370,45 @@ def conv_op(p: Dict[str, jax.Array], n: jax.Array, tail: Optional[jax.Array] = N
         return checkpoint_name((c * conv) @ p["out_proj"], PRODUCT), padded[:, t:]
 
 
-def _qkv(p, n, cfg: LMConfig, positions, rotary: bool):
-    bsz, t, _ = n.shape
+def _project(n, w, heads: int, heads_first: bool):
+    """``n`` [B, T, D] times ``w`` [D, heads * hd], kept for the backward pass: as [B, T, heads, hd], or
+    ``heads_first`` as [B, heads, T, hd] straight out of the matmul, which writes either for the same work."""
+    if heads_first:
+        return checkpoint_name(jnp.einsum("btd,dhk->bhtk", n, w.reshape(w.shape[0], heads, -1)), PRODUCT)
+    return checkpoint_name(n @ w, PRODUCT).reshape(n.shape[:2] + (heads, -1))
+
+
+def _qkv(p, n, cfg: LMConfig, positions, rotary: bool, heads_first: bool = False):
+    """``q`` (normed, rotated, times ``1 / sqrt(hd)``), ``k`` (normed, rotated) and ``v`` of the normed rows ``n``
+    [B, T, D] at ``positions`` [B or 1, T]: [B, T, H, hd], or ``heads_first`` [B, H, T, hd], the attention
+    kernel's layout. The whole-sequence passes and the decode step differ by the layout alone: the same
+    numbers to the bit, so a cache holds the keys the learner's pass makes."""
     nq, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head
-    q = checkpoint_name(n @ p["q"], PRODUCT).reshape(bsz, t, nq, hd)
-    k = checkpoint_name(n @ p["k"], PRODUCT).reshape(bsz, t, nkv, hd)
-    v = checkpoint_name(n @ p["v"], PRODUCT).reshape(bsz, t, nkv, hd)
-    q, k = rms_norm(q, p["q_norm"], cfg.norm_eps), rms_norm(k, p["k_norm"], cfg.norm_eps)
+    q, k, v = (_project(n, p[name], heads, heads_first) for name, heads in (("q", nq), ("k", nkv), ("v", nkv)))
+    tables = None
     if rotary:
-        cos, sin = _rope_tables(positions, hd, cfg.rope_theta)
-        q, k = _rope(q, cos, sin), _rope(k, cos, sin)
-    return q, k, v
+        over_heads = (lambda a: a[:, None]) if heads_first else (lambda a: a[:, :, None])
+        tables = tuple(over_heads(a) for a in _rope_tables(positions, hd, cfg.rope_theta))
+    q = _head_norm(q, p["q_norm"], cfg.norm_eps, tables, 1.0 / math.sqrt(hd))
+    return q, _head_norm(k, p["k_norm"], cfg.norm_eps, tables), v
 
 
-def _gated(p, n, attended, cfg: LMConfig):
-    """``attended`` [B, T, H * hd] times the output gate ``sigmoid(n Wg)``, in a model that has one."""
-    if not cfg.attn_output_gate:
-        return attended
-    return attended * jax.nn.sigmoid(checkpoint_name(n @ p["gate"], PRODUCT))
+def _out(p, n, attended, cfg: LMConfig, heads_first: bool = False):
+    """The attention block after its kernel: ``attended`` ([B, T, H * hd], or ``heads_first`` [B, H, T, hd]) times
+    the output gate ``sigmoid(n Wg)`` in a model that has one, the gate's product made in ``attended``'s layout,
+    then the output projection, which contracts heads and head size from either layout."""
+    if cfg.attn_output_gate:
+        gate = _project(n, p["gate"], cfg.num_attention_heads, heads_first)
+        attended = attended * jax.nn.sigmoid(gate if heads_first else gate.reshape(attended.shape))
+    if heads_first:
+        return checkpoint_name(jnp.einsum("bhtk,hkd->btd", attended, p["o"].reshape(attended.shape[1], -1, p["o"].shape[1])), PRODUCT)
+    return checkpoint_name(attended @ p["o"], PRODUCT)
 
 
 def _attend(q, k, v, allowed):
-    """``q`` [B, Q, G, R, hd], ``k`` / ``v`` [B, K, G, hd], ``allowed`` broadcastable to [B, 1, 1, Q, K]."""
-    scores = jnp.einsum("bqgrh,bkgh->bgrqk", q, k, preferred_element_type=jnp.float32) / math.sqrt(q.shape[-1])
+    """``q`` [B, Q, G, R, hd] (already times ``1 / sqrt(hd)``), ``k`` / ``v`` [B, K, G, hd], ``allowed``
+    broadcastable to [B, 1, 1, Q, K]."""
+    scores = jnp.einsum("bqgrh,bkgh->bgrqk", q, k, preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(jnp.where(allowed, scores, -jnp.inf), axis=-1)
     return jnp.einsum("bgrqk,bkgh->bqgrh", probs.astype(v.dtype), v)
 
@@ -400,35 +486,38 @@ def on_tpu() -> bool:
 
 def kernel_core(q: jax.Array, k: jax.Array, v: jax.Array, cfg: LMConfig, window: Optional[int] = None) -> jax.Array:
     """Causal attention by the stock Pallas TPU splash-attention kernel (its own ``custom_vjp``), over every
-    earlier position or over the last ``window``: ``q`` [B, T, H, hd], ``k`` / ``v`` [B, T, G, hd] ->
-    [B, T, H * hd]. Both families go through it: with 32 query heads on 8 key-value heads of 64 it takes
-    37.7 ms forwards and backwards at 2 x 8,192 positions where the flash kernel, which has no grouped-query
-    form and was given the keys and values repeated fourfold, took 47.2 (my chip runs, PR 34). Blocks of
-    1,024: of 512, 36.9 -> 44.8 ms for full attention at heads of 128 and the same 21.5 for a window of
-    2,048; of 2,048 the kernels do not fit the core's fast memory; the library's default of 128 is several
-    times slower (PR 29)."""
-    bsz, t, nq, hd = q.shape
-    heads_first = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
-    scaled = (q.astype(jnp.float32) / math.sqrt(hd)).astype(q.dtype)  # the kernel has no scale of its own
-    call = functools.partial(_splash_call, block=min(2 * cfg.query_block, t), window=window)
-    out = _kernel_vjp(call)(heads_first(scaled), heads_first(k), heads_first(v))
-    return heads_first(out).reshape(bsz, t, nq * hd)
+    earlier position or over the last ``window``, in the kernel's own layout: ``q`` [B, H, T, hd] (already
+    times ``1 / sqrt(hd)``: the kernel has no scale of its own), ``k`` / ``v`` [B, G, T, hd] -> [B, H, T, hd].
+    Nothing is transposed or cast around it: the products before it are made heads-first and the one after
+    it contracts from heads-first (:func:`_qkv`, :func:`_out`). Both families go through it: with 32 query
+    heads on 8 key-value heads of 64 it takes 37.7 ms forwards and backwards at 2 x 8,192 positions where the
+    flash kernel, which has no grouped-query form and was given the keys and values repeated fourfold, took
+    47.2 (my chip runs, PR 34). Blocks of 1,024: of 512, 36.9 -> 44.8 ms for full attention at heads of 128
+    and the same 21.5 for a window of 2,048; of 2,048 the kernels do not fit the core's fast memory; the
+    library's default of 128 is several times slower (PR 29)."""
+    call = functools.partial(_splash_call, block=min(2 * cfg.query_block, q.shape[2]), window=window)
+    return _kernel_vjp(call)(q, k, v)
 
 
 def attn_op(p: Dict[str, jax.Array], n: jax.Array, cfg: LMConfig, mixer: str = "attn"):
     """Causal grouped-query attention over whole sequences ``n`` [B, T, D] that
     start at position 0; as ``mixer`` "swa" a query sees the last ``sliding_window``
-    positions, its own among them. The [T, T] scores never exist: on a TPU a Pallas
-    kernel computes it, elsewhere plain JAX takes a block of queries at a time
+    positions, its own among them. The [T, T] scores never exist: on a TPU the three
+    parts of the block :func:`_layer` runs (:func:`_qkv` heads-first, :func:`kernel_core`,
+    :func:`_out`) compute it, elsewhere plain JAX takes a block of queries at a time
     against the keys from the first that the block's first query sees up to the
-    block's end. Returns the output, keys, values."""
+    block's end, in the layout [B, T, H, hd]. ``q`` comes out of :func:`_qkv` normed,
+    rotated and scaled in one float32 expression that is rounded once, ``k`` alike.
+    Returns the output, keys, values ([B, T, G, hd])."""
     with jax.named_scope(SCOPE_OF[mixer]):
         bsz, t, _ = n.shape
         nq, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head
         window = cfg.window(mixer)
-        q, k, v = _qkv(p, n, cfg, jnp.arange(t)[None, :], cfg.rotary(mixer))
-        if on_tpu():
-            return checkpoint_name(_gated(p, n, kernel_core(q, k, v, cfg, window), cfg) @ p["o"], PRODUCT), k, v
+        tpu = on_tpu()
+        q, k, v = _qkv(p, n, cfg, jnp.arange(t)[None, :], cfg.rotary(mixer), heads_first=tpu)
+        if tpu:
+            out = _out(p, n, kernel_core(q, k, v, cfg, window), cfg, heads_first=True)
+            return out, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
         q = q.reshape(bsz, t, nkv, nq // nkv, hd)
         block = min(cfg.query_block, t)
 
@@ -444,8 +533,7 @@ def attn_op(p: Dict[str, jax.Array], n: jax.Array, cfg: LMConfig, mixer: str = "
             first = 0 if window is None else max(0, start - window + 1)
             seen = slice(first, start + block)
             outs.append(one_block(q[:, start : start + block], k[:, seen], v[:, seen], start, first))
-        out = jnp.concatenate(outs, axis=1).reshape(bsz, t, nq * hd)
-        return checkpoint_name(_gated(p, n, out, cfg) @ p["o"], PRODUCT), k, v
+        return _out(p, n, jnp.concatenate(outs, axis=1).reshape(bsz, t, nq * hd), cfg), k, v
 
 
 def attn_decode(p, n, cfg: LMConfig, cache_k, cache_v, pos, mixer: str = "attn"):
@@ -464,7 +552,7 @@ def attn_decode(p, n, cfg: LMConfig, cache_k, cache_v, pos, mixer: str = "attn")
         age = (pos[:, None] - jnp.arange(size)[None, :]) % size
         allowed = (age <= pos[:, None])[:, None, None, None, :]
         out = _attend(q, cache_k.astype(q.dtype), cache_v.astype(q.dtype), allowed)
-        return _gated(p, n, out.reshape(bsz, 1, nq * hd), cfg) @ p["o"], cache_k, cache_v
+        return _out(p, n, out.reshape(bsz, 1, nq * hd), cfg), cache_k, cache_v
 
 
 def gated_mlp(p: Dict[str, jax.Array], n: jax.Array, scope: str = "lm.dense_ffn") -> jax.Array:
@@ -743,7 +831,15 @@ def _layer(p, x, cfg: LMConfig, mixer: str, ffn: str):
     The expert layer keeps its inputs only (:func:`_held_experts`). Around the attention
     kernel the block is two such halves, so that the kernel runs once forwards (it keeps
     what its own backward pass needs: q, k, v, the output and the row statistics, 0.2 GB
-    at 2 x 8,192 positions with heads of 64, 0.3 GB with heads of 128 on 4 key-value heads)."""
+    at 2 x 8,192 positions with heads of 64, 0.3 GB with heads of 128 on 4 key-value heads).
+    Everything between those products and the kernel is in the kernel's layout [B, H, T, hd]:
+    q, k, v and the gate come out of their products heads-first (the same products, the same
+    bytes kept), q and k are normed, rotated and scaled in one float32 expression rounded once
+    (:func:`_head_norm`), the gate multiplies the kernel's output where it lies and ``o``'s
+    product contracts heads and head size from there: an array of q's size is written once
+    in each direction, in bfloat16, with no transposing copy and no cast between passes
+    (at heads of 128; at heads of 64 XLA transposes the q product once). The compiled block
+    is held to that in ``tests/test_models/test_lm_tpu_compile.py``."""
     block = functools.partial(jax.checkpoint, policy=KEEP_PRODUCTS)
     if mixer in SCOPE_OF and on_tpu():
         scope = SCOPE_OF[mixer]
@@ -751,13 +847,12 @@ def _layer(p, x, cfg: LMConfig, mixer: str, ffn: str):
         def qkv(p, x):
             with jax.named_scope(scope):
                 normed = rms_norm(x, p["op_norm"], cfg.norm_eps)
-                return _qkv(p["attn"], normed, cfg, jnp.arange(x.shape[1])[None, :], cfg.rotary(mixer))
+                return _qkv(p["attn"], normed, cfg, jnp.arange(x.shape[1])[None, :], cfg.rotary(mixer), heads_first=True)
 
         def rest(p, x, attended):
-            with jax.named_scope(scope):
-                if cfg.attn_output_gate:  # the gate reads the normed input, which is made again here
-                    attended = _gated(p["attn"], rms_norm(x, p["op_norm"], cfg.norm_eps), attended, cfg)
-                h = _added(x, checkpoint_name(attended @ p["attn"]["o"], PRODUCT), p, "op_post_norm", cfg)
+            with jax.named_scope(scope):  # the gate reads the normed input, which is made again here
+                normed = rms_norm(x, p["op_norm"], cfg.norm_eps) if cfg.attn_output_gate else None
+                h = _added(x, _out(p["attn"], normed, attended, cfg, heads_first=True), p, "op_post_norm", cfg)
             return _ffn_half(p, h, cfg, ffn)
 
         q, k, v = block(qkv)(p, x)

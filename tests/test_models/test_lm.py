@@ -560,13 +560,26 @@ def test_a_sequence_of_four_windows_fails_where_a_part_of_the_second_family_is_l
     assert float(jnp.max(jnp.abs(faulty - want))) > 100 * 1e-4, fault
 
 
+def _the_tpu_branch_interpreted_equals_the_plain_form(monkeypatch, fn, args, ct):
+    """``fn(*args)`` and its gradients for the cotangent ``ct``, first through the plain form and then through the
+    branch a TPU takes with the splash kernels run by the Pallas interpreter: equal, and no gradient leaf is zero."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as kernel
+
+    want, want_vjp = jax.vjp(fn, *args)
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)
+    monkeypatch.setattr(kernel, "make_splash_mha", functools.partial(kernel.make_splash_mha, interpret=True))
+    got, got_vjp = jax.vjp(fn, *args)
+    _close(got, want, 2e-4)
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got_vjp(ct))[0], jax.tree_util.tree_leaves(want_vjp(ct))):
+        assert float(jnp.max(jnp.abs(w))) > 0, jax.tree_util.keystr(path)
+        assert float(jnp.max(jnp.abs(g - w))) <= 2e-4 * (float(jnp.max(jnp.abs(w))) + 1.0), jax.tree_util.keystr(path)
+
+
 @pytest.mark.parametrize("mixer", ["swa", "attn"])
 def test_the_tpu_branch_of_the_attention_call_in_interpret_mode(monkeypatch, mixer):
     """The branch a TPU takes through a model with a window (stock splash attention: 8 query heads on 1 key-value
     head in place, blocks of 128, a local mask of 256 over 512 positions or the causal one) run by the Pallas
     interpreter on the CPU gives what the blocked plain form gives, forwards and backwards."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as kernel
-
     cfg = config2(num_attention_heads=8, head_dim=128, hidden_size=64, sliding_window=256, max_positions=512, query_block=64)
     ks = jax.random.split(jax.random.PRNGKey(0), 9)
     shapes = {"q": (64, 1024), "k": (64, 128), "v": (64, 128), "gate": (64, 1024), "o": (1024, 64)}
@@ -575,16 +588,59 @@ def test_the_tpu_branch_of_the_attention_call_in_interpret_mode(monkeypatch, mix
     n = jax.random.normal(ks[7], (2, 512, 64))
     ct = jax.random.normal(ks[8], (2, 512, 64))
 
-    def out_and_grads():
-        return jax.vjp(lambda p, n: lm.attn_op(p, n, cfg, mixer)[0], p, n)
+    _the_tpu_branch_interpreted_equals_the_plain_form(monkeypatch, lambda p, n: lm.attn_op(p, n, cfg, mixer)[0], (p, n), ct)
 
-    want, want_vjp = out_and_grads()
-    monkeypatch.setattr(lm, "on_tpu", lambda: True)
-    monkeypatch.setattr(kernel, "make_splash_mha", functools.partial(kernel.make_splash_mha, interpret=True))
-    got, got_vjp = out_and_grads()
-    _close(got, want, 2e-4)
-    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got_vjp(ct))[0], jax.tree_util.tree_leaves(want_vjp(ct))):
-        assert float(jnp.max(jnp.abs(g - w))) <= 2e-4 * (float(jnp.max(jnp.abs(w))) + 1.0), jax.tree_util.keystr(path)
+
+# an attention block of each kind the benchmark's cells run, at sizes the splash kernels take: (config maker, mixer, its own sizes)
+BLOCKS_AROUND_THE_KERNEL = {
+    "first_family_attn": (config, "attn", dict(layer_types=("full_attention",) * 8, num_key_value_heads=2, head_dim=64)),
+    "second_family_swa": (config2, "swa", dict(num_key_value_heads=1, head_dim=128, sliding_window=256)),
+    "second_family_attn": (config2, "attn", dict(num_key_value_heads=1, head_dim=128, sliding_window=256)),
+}
+
+
+@pytest.mark.parametrize("case", list(BLOCKS_AROUND_THE_KERNEL))
+def test_the_tpu_branch_of_an_attention_block_in_interpret_mode(monkeypatch, case):
+    """``_layer`` of an attention block with a dense FFN through the branch a TPU takes (its two halves around stock
+    splash attention: the products made heads-first, norm, rotary and scale in one rounding, the gate and the
+    output projection from the kernel's layout; the kernels run by the Pallas interpreter on the CPU) gives what
+    the plain form gives in ``[B, T, H, hd]``: the block's output and the gradient of every leaf and of the input."""
+    make, mixer, sizes = BLOCKS_AROUND_THE_KERNEL[case]
+    cfg = make(layers=(0,), hidden_size=64, num_attention_heads=8, max_positions=512, query_block=64, **sizes)
+    assert cfg.kinds[0][1] == "dense"  # layer 0's weights; the second family's full block runs on a sliding layer's, alike in shape
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    p = lm.init_params(cfg, keys[0])["layers"]["layer_0"]
+    scales = {"q_norm", "k_norm", "op_norm", "ffn_norm", "op_post_norm", "ffn_post_norm"}  # they start at 1: moved, so that their gradients are told apart
+    p = jax.tree_util.tree_map_with_path(lambda path, w: w * jnp.linspace(0.5, 1.5, w.shape[-1]) if path[-1].key in scales else w, p)
+    x = jax.random.normal(keys[1], (2, 512, 64))
+    ct = jax.random.normal(keys[2], (2, 512, 64))
+
+    _the_tpu_branch_interpreted_equals_the_plain_form(monkeypatch, lambda p, x: lm._layer(p, x, cfg, mixer, "dense")[0], (p, x), ct)
+
+
+def test_the_cache_holds_the_keys_of_the_whole_pass_to_the_bit_in_bfloat16(family):
+    """In ``bf16-mixed`` the rollout's keys are the learner's: a decode step writes into its cache, position by
+    position, the very bits that the whole-sequence pass makes of the same normed rows (one function norms, rotates
+    and rounds once, whatever the layout: a step at a time, ``[B, T, G, hd]``, or the kernel's ``[B, G, T, hd]``).
+    The second family's sliding layer goes round its ring of 8 rows four times; the last round is compared."""
+    _, config, (_, params) = family
+    cfg = config()
+    n, mixer = next((i, m) for i, (m, _) in enumerate(cfg.kinds) if m != "conv")
+    p = lm.working_copy(params, jnp.bfloat16)["layers"][f"layer_{n}"]["attn"]
+    normed = jax.random.normal(jax.random.PRNGKey(9), (B, T, 32)).astype(jnp.bfloat16)
+    _, keys, values = lm.attn_op(p, normed, cfg, mixer)
+    rotary, positions = cfg.rotary(mixer), jnp.arange(T)[None, :]
+    for plain, first in zip(lm._qkv(p, normed, cfg, positions, rotary), lm._qkv(p, normed, cfg, positions, rotary, heads_first=True)):
+        assert plain.dtype == jnp.bfloat16 and np.array_equal(np.asarray(plain, np.float32), np.asarray(jnp.swapaxes(first, 1, 2), np.float32))
+    state = lm.init_state(cfg, B, jnp.bfloat16)["layers"][f"layer_{n}"]
+    cache_k, cache_v = state["k"], state["v"]
+    rows = cache_k.shape[1]
+    for t in range(T):
+        _, cache_k, cache_v = lm.attn_decode(p, normed[:, t : t + 1], cfg, cache_k, cache_v, jnp.full((B,), t), mixer)
+    kept = np.arange(T - rows, T)
+    assert rows == (WINDOW if mixer == "swa" else T) and float(jnp.max(jnp.abs(keys.astype(jnp.float32)))) > 0.1
+    assert np.array_equal(np.asarray(cache_k, np.float32)[:, kept % rows], np.asarray(keys, np.float32)[:, kept])
+    assert np.array_equal(np.asarray(cache_v, np.float32)[:, kept % rows], np.asarray(values, np.float32)[:, kept])
 
 
 def test_the_second_family_s_working_copy_and_config_group():
