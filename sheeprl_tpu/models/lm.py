@@ -1,8 +1,9 @@
 """A language-model policy: sparse-expert decoder blocks as pure functions over a
-parameter dict. Two published families run through it, each told apart by its
+parameter dict. Three published families run through it, each told apart by its
 own config keys and by nothing else: ``lfm2_moe``
-(``https://huggingface.co/LiquidAI/LFM2-8B-A1B``) and ``afmoe``
-(``https://huggingface.co/arcee-ai/Trinity-Mini``).
+(``https://huggingface.co/LiquidAI/LFM2-8B-A1B``), ``afmoe``
+(``https://huggingface.co/arcee-ai/Trinity-Mini``) and SmallThinker
+(``https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct``).
 
 Block: ``h = x + Op(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; with
 ``post_norms`` the mixer's and the FFN's outputs are normed once more before they
@@ -14,8 +15,13 @@ earlier position (``full_attention``) or over the last ``sliding_window`` of the
 ``sigmoid(n Wg)`` before the output projection. ``FFN`` is a gated MLP in the
 leading ``num_dense_layers`` published layers and a sigmoid-routed expert layer
 in the others, beside ``num_shared_experts`` experts that every token goes
-through. The embedding's rows are multiplied by ``sqrt(hidden_size)`` under
-``mup_enabled``. After the last block an RMSNorm; the logits are over the held
+through. In the third family the per-head norms are absent (``qk_norm`` off: ``q`` and
+``k`` are scaled and rotated in the same one pass, or go on as their products are), the
+experts are gated by ReLU (``hidden_act``), and the router stands before attention
+(``early_router``): it reads the block's input as it is, un-normed, while the experts read
+the normed state after the mixer; the chosen experts' weights are the softmax of their
+logits and there is no selection bias (``router_apply_softmax``). The embedding's rows are
+multiplied by ``sqrt(hidden_size)`` under ``mup_enabled``. After the last block an RMSNorm; the logits are over the held
 rows of the embedding or, with ``tie_embedding`` off, of a head of its own; the
 PPO critic is one linear map on the final normed state.
 
@@ -28,7 +34,8 @@ the others, and are multiplied as ragged groups (:func:`grouped_dot`). Rows are
 moved only for the pairs held here: the buffer between the routing and the
 products has :func:`compact_rows` rows, :data:`SLACK` times the held experts' even
 share of all pairs in whole row tiles of the grouped matmul, a width fixed by the
-shapes (no option sets it). How many pairs are held is the data's, so a layer
+shapes (no option sets it; the kernels' other two tiles follow from the products' shapes too,
+:func:`gmm_tiles`). How many pairs are held is the data's, so a layer
 whose held pairs pass that width takes the width of all pairs instead, under a
 ``lax.cond`` (:func:`_held_experts`): the same result at the older speed, which is
 what keeps the layer dropless. ``Moe/compact_share`` (:func:`moe_metrics`) is the
@@ -53,7 +60,9 @@ rows; a sliding layer's is a ring of its window's: position ``p`` is written at
 ``p mod sliding_window`` and a row is masked by its age.
 
 Every part runs under a ``jax.named_scope``: the first family's are :data:`SCOPES`, the
-second adds ``lm.swa`` (attention over a window) and ``lm.moe.shared`` (the shared expert);
+second adds ``lm.swa`` (attention over a window) and ``lm.moe.shared`` (the shared expert),
+the third runs under ``lm.swa``, ``lm.attn``, ``lm.moe.route`` (wherever in the block the
+router's product, top-k and sort are issued) and ``lm.moe.experts``;
 the benchmark's FLOP counts use the same names.
 """
 
@@ -115,6 +124,11 @@ class LMConfig:
     tie_embedding: bool = True  # the logits are over the embedding's rows; off, over a head leaf's
     mup_enabled: bool = False  # the embedding's rows times sqrt(hidden_size)
     route_eps: float = 1e-6  # added to the sum of the chosen scores before the division
+    # the third family's own keys, each with the value that leaves the first two families' programs as they are
+    qk_norm: bool = True  # an RMSNorm with a learned scale over each head of q and of k
+    hidden_act: str = "silu"  # what gates a gated MLP, dense, shared or routed: "silu" | "relu"
+    router_apply_softmax: bool = False  # the chosen experts' weights are a softmax of the router's logits: no sigmoid, no bias leaf
+    early_router: bool = False  # the router reads the block's input as it is, before the input norm and the mixer
 
     @property
     def head(self) -> int:
@@ -175,6 +189,10 @@ class LMConfig:
             tie_embedding=bool(get("tie_embedding", True)),
             mup_enabled=bool(get("mup_enabled", False)),
             route_eps=float(get("route_eps", 1e-6)),
+            qk_norm=bool(get("qk_norm", True)),
+            hidden_act=str(get("hidden_act", "silu") or "silu"),
+            router_apply_softmax=bool(get("router_apply_softmax", False)),
+            early_router=bool(get("early_router", False)),
         )
 
 
@@ -200,8 +218,9 @@ def param_shapes(cfg: LMConfig) -> Dict[Tuple[str, ...], Tuple[Tuple[int, ...], 
             spec[p + ("attn", "k")] = ((d, nkv * hd), "normal")
             spec[p + ("attn", "v")] = ((d, nkv * hd), "normal")
             spec[p + ("attn", "o")] = ((nq * hd, d), "normal")
-            spec[p + ("attn", "q_norm")] = ((hd,), "ones")
-            spec[p + ("attn", "k_norm")] = ((hd,), "ones")
+            if cfg.qk_norm:
+                spec[p + ("attn", "q_norm")] = ((hd,), "ones")
+                spec[p + ("attn", "k_norm")] = ((hd,), "ones")
             if cfg.attn_output_gate:
                 spec[p + ("attn", "gate")] = ((d, nq * hd), "normal")
         if ffn == "dense":
@@ -212,7 +231,8 @@ def param_shapes(cfg: LMConfig) -> Dict[Tuple[str, ...], Tuple[Tuple[int, ...], 
         else:
             f, e = cfg.moe_intermediate_size, cfg.experts_held
             spec[p + ("moe", "router")] = ((d, cfg.num_experts), "normal")
-            spec[p + ("moe", "bias")] = ((cfg.num_experts,), "bias")
+            if not cfg.router_apply_softmax:
+                spec[p + ("moe", "bias")] = ((cfg.num_experts,), "bias")
             spec[p + ("moe", "w1")] = ((e, d, f), "normal")
             spec[p + ("moe", "w3")] = ((e, d, f), "normal")
             spec[p + ("moe", "w2")] = ((e, f, d), "normal")
@@ -305,14 +325,16 @@ def _turned(x: jax.Array, a: jax.Array, b: Optional[jax.Array], rot_x: Optional[
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _normed(x: jax.Array, a: jax.Array, b: Optional[jax.Array], eps: float) -> jax.Array:
+def _normed(x: jax.Array, a: jax.Array, b: Optional[jax.Array], eps: Optional[float]) -> jax.Array:
     """``(x a + rot(x) b) / rms(x)`` over the last axis of ``x`` [..., hd], in float32, rounded once: the float32
-    tables ``a`` and ``b`` (None: no second term) broadcast to ``x``. Its backward rule is written out so that it,
+    tables ``a`` and ``b`` (None: no second term) broadcast to ``x``; ``eps`` None: no division (a model without
+    per-head norms). Its backward rule is written out so that it,
     too, turns its two inputs only: it reads ``x`` and the cotangent, each in its own type, and writes ``x``'s
     cotangent rounded once, with nothing of ``x``'s size in float32 between two passes (left to the transpose rules
     the row factor's cotangent is a float32 array of ``x``'s size that one pass writes and the next reads)."""
     x32 = x.astype(jnp.float32)
-    return (_turned(x, a, b) * _row(x32, eps)).astype(x.dtype)
+    turned = _turned(x, a, b)
+    return (turned if eps is None else turned * _row(x32, eps)).astype(x.dtype)
 
 
 def _normed_fwd(x, a, b, eps):
@@ -322,10 +344,13 @@ def _normed_fwd(x, a, b, eps):
 def _normed_bwd(eps, res, g):
     x, a, b = res
     x32, g32 = x.astype(jnp.float32), g.astype(jnp.float32)
-    row = _row(x32, eps)
+    row = 1.0 if eps is None else _row(x32, eps)
     rot_x = None if b is None else _rot(x)  # once, for the row factor's and for ``b``'s cotangent
-    by_row = jnp.sum(g32 * _turned(x, a, b, rot_x), axis=-1, keepdims=True)  # the row factor's cotangent
-    d_x = row * _turned(g, a, None if b is None else -_swap(b)) - (row**3 / x.shape[-1]) * by_row * x32
+    if eps is None:
+        d_x = _turned(g, a, None if b is None else -_swap(b))
+    else:
+        by_row = jnp.sum(g32 * _turned(x, a, b, rot_x), axis=-1, keepdims=True)  # the row factor's cotangent
+        d_x = row * _turned(g, a, None if b is None else -_swap(b)) - (row**3 / x.shape[-1]) * by_row * x32
 
     def to_table(z, table):  # summed over the axes a table is broadcast over
         z = jnp.sum(z, axis=tuple(range(z.ndim - table.ndim)))
@@ -338,15 +363,20 @@ def _normed_bwd(eps, res, g):
 _normed.defvjp(_normed_fwd, _normed_bwd)
 
 
-def _head_norm(x: jax.Array, scale: jax.Array, eps: float, tables=None, factor: float = 1.0) -> jax.Array:
+def _head_norm(x: jax.Array, scale: Optional[jax.Array], eps: float, tables=None, factor: float = 1.0) -> jax.Array:
     """What lies between an attention product and its kernel, for ``q`` and for ``k``: the per-head RMSNorm of
-    ``x`` [..., hd], times ``factor`` (``1 / sqrt(hd)`` for ``q``: no kernel here has a scale of its own), rotated
+    ``x`` [..., hd] (``scale`` None: a model without per-head norms, and the same expression without the row
+    factor; where nothing is left of it, ``k`` of a layer without rotary, the product goes on as it is), times ``factor`` (``1 / sqrt(hd)`` for ``q``: no kernel here has a scale of its own), rotated
     by ``tables`` (cos, sin of :func:`_rope_tables`, broadcastable to ``x``; None where the layer has no rotary
     embedding). One float32 expression, rounded once at its end, whatever the layout of the axes before the
     last. Rotary is linear, so the norm's row factor is taken out of it, and the norm's scale, ``factor`` and
     the tables are folded into two small tables: ``rope(x r s) = r (x (s cos) + rot(x) (swap(s) sin))``. The
     halves are turned on the product itself (:func:`_rot`) and never on an intermediate, so nothing of ``x``'s
     size exists in float32 outside one pass. ``q`` was rounded three times before (norm, rotary, scale), ``k`` twice."""
+    if scale is None:
+        if tables is None:
+            return x if factor == 1.0 else _normed(x, jnp.float32(factor), None, None)
+        return _normed(x, tables[0] * factor, tables[1] * factor, None)
     scale = scale.astype(jnp.float32) * factor
     if tables is None:
         return _normed(x, scale, None, eps)
@@ -379,7 +409,7 @@ def _project(n, w, heads: int, heads_first: bool):
 
 
 def _qkv(p, n, cfg: LMConfig, positions, rotary: bool, heads_first: bool = False):
-    """``q`` (normed, rotated, times ``1 / sqrt(hd)``), ``k`` (normed, rotated) and ``v`` of the normed rows ``n``
+    """``q`` (normed in a model with per-head norms, rotated, times ``1 / sqrt(hd)``), ``k`` (normed, rotated) and ``v`` of the normed rows ``n``
     [B, T, D] at ``positions`` [B or 1, T]: [B, T, H, hd], or ``heads_first`` [B, H, T, hd], the attention
     kernel's layout. The whole-sequence passes and the decode step differ by the layout alone: the same
     numbers to the bit, so a cache holds the keys the learner's pass makes."""
@@ -389,8 +419,9 @@ def _qkv(p, n, cfg: LMConfig, positions, rotary: bool, heads_first: bool = False
     if rotary:
         over_heads = (lambda a: a[:, None]) if heads_first else (lambda a: a[:, :, None])
         tables = tuple(over_heads(a) for a in _rope_tables(positions, hd, cfg.rope_theta))
-    q = _head_norm(q, p["q_norm"], cfg.norm_eps, tables, 1.0 / math.sqrt(hd))
-    return q, _head_norm(k, p["k_norm"], cfg.norm_eps, tables), v
+    q_scale, k_scale = (p["q_norm"], p["k_norm"]) if cfg.qk_norm else (None, None)
+    q = _head_norm(q, q_scale, cfg.norm_eps, tables, 1.0 / math.sqrt(hd))
+    return q, _head_norm(k, k_scale, cfg.norm_eps, tables), v
 
 
 def _out(p, n, attended, cfg: LMConfig, heads_first: bool = False):
@@ -555,17 +586,31 @@ def attn_decode(p, n, cfg: LMConfig, cache_k, cache_v, pos, mixer: str = "attn")
         return _out(p, n, out.reshape(bsz, 1, nq * hd), cfg), cache_k, cache_v
 
 
-def gated_mlp(p: Dict[str, jax.Array], n: jax.Array, scope: str = "lm.dense_ffn") -> jax.Array:
+ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}  # a model's ``hidden_act``: what gates its gated MLPs
+
+
+def gated_mlp(p: Dict[str, jax.Array], n: jax.Array, scope: str = "lm.dense_ffn", act: str = "silu") -> jax.Array:
     with jax.named_scope(scope):
         gate, up = checkpoint_name(n @ p["w1"], PRODUCT), checkpoint_name(n @ p["w3"], PRODUCT)
-        return (jax.nn.silu(gate) * up) @ p["w2"]  # no mark: nothing going backwards needs this product
+        return (ACTS[act](gate) * up) @ p["w2"]  # no mark: nothing going backwards needs this product
 
 
 def route(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig):
     """(chosen experts [N, k], their weights [N, k] float32) of the rows ``x`` [N, D].
-    The matmul and the sigmoid are float32; the bias enters the selection only."""
+    The matmul and the scores are float32. Sigmoid scores: the bias enters the selection only.
+    ``router_apply_softmax``: the largest logits are chosen, there is no bias, and the weights are the softmax
+    over the chosen logits (``norm_topk_prob``; the softmax over all experts renormalised over the chosen is the
+    same numbers) or, without it, the chosen experts' part of the softmax over all."""
     with jax.named_scope("lm.moe.route"):
-        scores = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32), p["router"].astype(jnp.float32), precision=HI))
+        logits = jnp.matmul(x.astype(jnp.float32), p["router"].astype(jnp.float32), precision=HI)
+        if cfg.router_apply_softmax:
+            _, chosen = jax.lax.top_k(logits, cfg.num_experts_per_tok)
+            if cfg.norm_topk_prob:
+                w = jax.nn.softmax(jnp.take_along_axis(logits, chosen, axis=-1), axis=-1)
+            else:
+                w = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1), chosen, axis=-1)
+            return chosen, w * cfg.routed_scaling_factor
+        scores = jax.nn.sigmoid(logits)
         _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(p["bias"].astype(jnp.float32)), cfg.num_experts_per_tok)
         w = jnp.take_along_axis(scores, chosen, axis=-1)
         if cfg.norm_topk_prob:
@@ -638,7 +683,29 @@ def _pairs_to_rows_bwd(res, g):
 _pairs_to_rows.defvjp(_pairs_to_rows_fwd, _pairs_to_rows_bwd)
 
 
-GMM_TILES = (512, 1024, 1024)  # rows, contraction, columns of the Pallas grouped matmul; its default 128s are 8x slower (my chip runs, PR 29)
+ROW_TILE, TILE = 512, 1024  # rows; contraction and columns of the Pallas grouped matmul at their widest (its default 128s are 8x slower: my chip runs, PR 29)
+
+
+def _tile(width: int) -> int:
+    """The tile of the grouped matmul along a contraction or a column axis of ``width``: ``TILE`` where whole
+    tiles of it leave at most an eighth of their elements beyond ``width`` (the kernels multiply a tile whole,
+    padding included: 1,792 in two tiles of 1,024 is an eighth), else the largest multiple of 128 under it that
+    divides ``width`` (2,560 and 768, a sixth and a quarter of padding at 1,024: 640 and 768)."""
+    padding = -width % TILE
+    if 8 * padding <= width + padding:
+        return TILE
+    whole = [t for t in range(128, TILE, 128) if width % t == 0]
+    return max(whole) if whole else TILE
+
+
+def gmm_tiles(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """(rows, contraction, columns) tiles of a grouped product [m, k] x [k, n] or of its two transposes, from the
+    product's shapes: what the kernels ask a ``tiling`` function. (512, 1024, 1024) at the first two families'
+    widths (2,048, 1,792, 1,024)."""
+    del m
+    return ROW_TILE, _tile(k), _tile(n)
+
+
 # The room of the compact row buffer over an even share of the pairs (`compact_rows`). As drawn, a chip's share of
 # a layer's pairs had a standard deviation of 3.3% around its even quarter; placed by load it starts within 0.6%
 # of it and falls 15% over a window as the routers train (PERF.md, PR 29): half as much again is out of reach of
@@ -650,8 +717,7 @@ def compact_rows(n_pairs: int, held_n: int, num_experts: int) -> int:
     """Rows of the buffer between the routing and the grouped products: ``SLACK`` times the
     even share of ``n_pairs`` that ``held_n`` of ``num_experts`` experts get, in whole row tiles
     of the grouped matmul, and never more than all pairs (every expert held; a decode step)."""
-    tile = GMM_TILES[0]
-    return min(n_pairs, -(-math.ceil(SLACK * n_pairs * held_n / num_experts) // tile) * tile)
+    return min(n_pairs, -(-math.ceil(SLACK * n_pairs * held_n / num_experts) // ROW_TILE) * ROW_TILE)
 
 
 def _group_products(xs, w, group_sizes, cotangent=None):
@@ -670,13 +736,13 @@ def _group_products(xs, w, group_sizes, cotangent=None):
         megablox = importlib.import_module("jax.experimental.pallas.ops.tpu.megablox.gmm")
         # the kernels take whole tiles of rows: zero rows are added after the last group (a decode step has 8 rows)
         rows = xs.shape[0]
-        whole = lambda a: jnp.pad(a, ((0, -rows % GMM_TILES[0]), (0, 0)))  # noqa: E731
+        whole = lambda a: jnp.pad(a, ((0, -rows % ROW_TILE), (0, 0)))  # noqa: E731
         with jax.default_matmul_precision("default"):
             if cotangent is None:
-                return megablox.gmm(whole(xs), w, group_sizes, xs.dtype, GMM_TILES)[:rows]
-            d_xs = megablox.gmm(whole(cotangent), w, group_sizes, xs.dtype, GMM_TILES, transpose_rhs=True)[:rows]
+                return megablox.gmm(whole(xs), w, group_sizes, xs.dtype, gmm_tiles)[:rows]
+            d_xs = megablox.gmm(whole(cotangent), w, group_sizes, xs.dtype, gmm_tiles, transpose_rhs=True)[:rows]
             d_w = megablox.tgmm(
-                whole(xs).swapaxes(0, 1), whole(cotangent), group_sizes, w.dtype, GMM_TILES, num_actual_groups=w.shape[0]
+                whole(xs).swapaxes(0, 1), whole(cotangent), group_sizes, w.dtype, gmm_tiles, num_actual_groups=w.shape[0]
             )
             return d_xs, d_w
     if cotangent is None:
@@ -708,8 +774,8 @@ def _grouped_dot_bwd(res, g):
 grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
-@functools.partial(jax.jit, static_argnames="rows")
-def _experts_at_width(x, weight, kernels, routing, rows: int):
+@functools.partial(jax.jit, static_argnames=("rows", "act"))
+def _experts_at_width(x, weight, kernels, routing, rows: int, act: str):
     """The held experts' partial sum [N, D] through a buffer of the first ``rows`` sorted pairs,
     which has to hold every held pair. Under ``jit`` (as :func:`_experts_at_width_vjp`) so that the
     expert layers of a model, alike in their shapes, are traced once between them: a program traces
@@ -717,15 +783,15 @@ def _experts_at_width(x, weight, kernels, routing, rows: int):
     w1, w3, w2 = kernels
     order, inverse, held, group_sizes = routing
     xs = _rows_to_pairs(x, order, inverse, rows)  # the groups cover the held pairs, in front
-    hidden = jax.nn.silu(grouped_dot(xs, w1, group_sizes)) * grouped_dot(xs, w3, group_sizes)
+    hidden = ACTS[act](grouped_dot(xs, w1, group_sizes)) * grouped_dot(xs, w3, group_sizes)
     return _pairs_to_rows(grouped_dot(hidden, w2, group_sizes), weight, order, inverse, held)
 
 
-@functools.partial(jax.jit, static_argnames="rows")
-def _experts_at_width_vjp(x, weight, kernels, routing, g, rows: int):
+@functools.partial(jax.jit, static_argnames=("rows", "act"))
+def _experts_at_width_vjp(x, weight, kernels, routing, g, rows: int, act: str):
     """The cotangents of ``x``, ``weight`` and ``kernels`` for the cotangent ``g`` of :func:`_experts_at_width`,
     which is computed again for it."""
-    return jax.vjp(lambda *inputs: _experts_at_width(*inputs, routing, rows=rows), x, weight, kernels)[1](g)
+    return jax.vjp(lambda *inputs: _experts_at_width(*inputs, routing, rows=rows, act=act), x, weight, kernels)[1](g)
 
 
 def _at_either_width(rows: int, routing, at_width):
@@ -737,31 +803,32 @@ def _at_either_width(rows: int, routing, at_width):
     return jax.lax.cond(jnp.sum(group_sizes) <= rows, lambda: at_width(rows), lambda: at_width(order.shape[0]))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _held_experts(x, weight, kernels, routing, rows: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _held_experts(x, weight, kernels, routing, rows: int, act: str):
     """:func:`_experts_at_width` at ``rows`` where the held pairs fit, else at the width of all
     pairs: dropless at any load. One ``custom_vjp`` whose residuals are its inputs and whose two
     rules each choose the width for themselves, so that nothing is differentiated through the
     ``cond`` (which would have the compact branch write the full width's residuals as zeros, over
     1 GB a layer at the benchmark's sizes) and a layer computed again going backwards
     (``jax.checkpoint``) does not run the products a third time."""
-    return _at_either_width(rows, routing, lambda r: _experts_at_width(x, weight, kernels, routing, rows=r))
+    return _at_either_width(rows, routing, lambda r: _experts_at_width(x, weight, kernels, routing, rows=r, act=act))
 
 
-def _held_experts_fwd(x, weight, kernels, routing, rows):
-    return _held_experts(x, weight, kernels, routing, rows), (x, weight, kernels, routing)
+def _held_experts_fwd(x, weight, kernels, routing, rows, act):
+    return _held_experts(x, weight, kernels, routing, rows, act), (x, weight, kernels, routing)
 
 
-def _held_experts_bwd(rows, res, g):
+def _held_experts_bwd(rows, act, res, g):
     x, weight, kernels, routing = res
-    return (*_at_either_width(rows, routing, lambda r: _experts_at_width_vjp(x, weight, kernels, routing, g, rows=r)), None)
+    return (*_at_either_width(rows, routing, lambda r: _experts_at_width_vjp(x, weight, kernels, routing, g, rows=r, act=act)), None)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def moe_ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig):
-    """The held experts' part of the expert layer over the rows ``x`` [N, D].
+def moe_ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig, routed: Optional[jax.Array] = None):
+    """The held experts' part of the expert layer over the rows ``x`` [N, D]; the router reads ``x`` too or,
+    in a model whose router stands before attention, the rows ``routed`` [N, D] of the block's input.
 
     Routing is over all ``num_experts``; the (token, slot) pairs whose expert is
     held here are sorted by expert, in front of the others, and multiplied as
@@ -776,7 +843,7 @@ def moe_ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig):
     lo = cfg.expert_lo
     held_n = p["w1"].shape[0]
     n_rows, k = x.shape[0], cfg.num_experts_per_tok
-    chosen, w = route(p, x, cfg)
+    chosen, w = route(p, x if routed is None else routed, cfg)
     with jax.named_scope("lm.moe.route"):
         local = chosen - lo
         held = (local >= 0) & (local < held_n)
@@ -788,7 +855,7 @@ def moe_ffn(p: Dict[str, jax.Array], x: jax.Array, cfg: LMConfig):
     with jax.named_scope("lm.moe.experts"):
         weight = jnp.where(held, w, 0.0).astype(x.dtype)
         kernels, routing = (p["w1"], p["w3"], p["w2"]), (order, inverse, held, group_sizes)
-        out = _held_experts(x, weight, kernels, routing, rows_wide)
+        out = _held_experts(x, weight, kernels, routing, rows_wide, cfg.hidden_act)
     rows = group_sizes.astype(jnp.float32)
     counters = {
         "pairs_here": jnp.sum(rows),
@@ -806,15 +873,18 @@ def _added(x, out, p, post_norm: str, cfg: LMConfig):
     return x + (rms_norm(out, p[post_norm], cfg.norm_eps) if cfg.post_norms else out)
 
 
-def _ffn_half(p, h, cfg: LMConfig, ffn: str):
+def _ffn_half(p, h, cfg: LMConfig, ffn: str, x=None):
+    """The block's second half over the state ``h`` after the mixer; ``x`` is the block's input, which an
+    ``early_router`` reads (un-normed, so that the routing waits for nothing in the block)."""
     normed = rms_norm(h, p["ffn_norm"], cfg.norm_eps)
     if ffn == "dense":
-        out, aux = gated_mlp(p["ffn"], normed), None
+        out, aux = gated_mlp(p["ffn"], normed, act=cfg.hidden_act), None
     else:
-        flat, chosen, counters = moe_ffn(p["moe"], normed.reshape(-1, normed.shape[-1]), cfg)
+        routed = x.reshape(-1, x.shape[-1]) if cfg.early_router else None
+        flat, chosen, counters = moe_ffn(p["moe"], normed.reshape(-1, normed.shape[-1]), cfg, routed)
         out, aux = flat.reshape(h.shape), (chosen, counters)
         if cfg.num_shared_experts:  # whole on every chip: the chips' partial sums of the routed experts are what is exchanged
-            out = out + gated_mlp(p["moe"]["shared"], normed, "lm.moe.shared")
+            out = out + gated_mlp(p["moe"]["shared"], normed, "lm.moe.shared", cfg.hidden_act)
     if cfg.post_norms:  # the norm's backward pass reads its input: kept, or the FFN (the held experts too) would run once more for it
         out = checkpoint_name(out, PRODUCT)
     return _added(h, out, p, "ffn_post_norm", cfg), aux
@@ -853,7 +923,7 @@ def _layer(p, x, cfg: LMConfig, mixer: str, ffn: str):
             with jax.named_scope(scope):  # the gate reads the normed input, which is made again here
                 normed = rms_norm(x, p["op_norm"], cfg.norm_eps) if cfg.attn_output_gate else None
                 h = _added(x, _out(p["attn"], normed, attended, cfg, heads_first=True), p, "op_post_norm", cfg)
-            return _ffn_half(p, h, cfg, ffn)
+            return _ffn_half(p, h, cfg, ffn, x)
 
         q, k, v = block(qkv)(p, x)
         with jax.named_scope(scope):
@@ -863,7 +933,7 @@ def _layer(p, x, cfg: LMConfig, mixer: str, ffn: str):
     def whole(p, x):
         normed = rms_norm(x, p["op_norm"], cfg.norm_eps)
         op = conv_op(p["conv"], normed)[0] if mixer == "conv" else attn_op(p["attn"], normed, cfg, mixer)[0]
-        return _ffn_half(p, _added(x, op, p, "op_post_norm", cfg), cfg, ffn)
+        return _ffn_half(p, _added(x, op, p, "op_post_norm", cfg), cfg, ffn, x)
 
     return block(whole)(p, x)
 
@@ -1002,7 +1072,7 @@ def decode_step(params: Dict[str, Any], tokens: jax.Array, state: Dict[str, Any]
         else:
             op, k, v = attn_decode(p["attn"], normed, cfg, st["k"], st["v"], pos, mixer)
             new_layers[f"layer_{n}"] = {"k": k, "v": v}
-        x, _ = _ffn_half(p, _added(x, op, p, "op_post_norm", cfg), cfg, ffn)
+        x, _ = _ffn_half(p, _added(x, op, p, "op_post_norm", cfg), cfg, ffn, x)
     final = rms_norm(x, p16["final_norm"], cfg.norm_eps)[:, 0]
     with jax.named_scope("lm.head"):
         logits = jnp.matmul(final, _head_rows(p16, cfg).T, preferred_element_type=jnp.float32)

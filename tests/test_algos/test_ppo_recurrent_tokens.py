@@ -78,6 +78,39 @@ def test_the_second_family_s_experiment_runs_through_the_cli_with_no_retrace(tmp
     assert gae.retraces == gae_before
 
 
+# the third family's experiment: the cut's four layers (full, sliding, sliding, sliding) at width 32, seven query heads on one
+# key-value head, a window of 4, and the experiment's own one sequence a step (a microbatch of one whole episode)
+TINY_SMALLTHINKER = [
+    "exp=ppo_recurrent_smallthinker_tokens",
+    "algo.lm.hidden_size=32", "algo.lm.moe_intermediate_size=24",
+    "algo.lm.num_attention_heads=7", "algo.lm.num_key_value_heads=1", "algo.lm.head_dim=8",
+    "algo.lm.num_experts=16", "algo.lm.num_experts_per_tok=3", "algo.lm.experts_held=4", "algo.lm.vocab_held=64",
+    "algo.lm.sliding_window=4", "algo.lm.query_block=8", "algo.lm.head_chunk=8",
+    "env.wrapper.prompt_tokens=4", "env.wrapper.sampled_tokens=12", "algo.rollout_steps=16",
+    "fabric.precision=32-true", "fabric.devices=1",
+    "metric.log_level=0", "checkpoint.save_last=False", "algo.run_test=False",
+]
+
+
+@pytest.mark.parametrize("player_on_host", [True, False])
+def test_the_third_family_s_experiment_runs_through_the_cli_with_no_retrace(tmp_path, monkeypatch, player_on_host):
+    """Three updates of ``exp=ppo_recurrent_smallthinker_tokens`` (one env, 16-step episodes: four times round the
+    sliding layers' ring of 4; the router reads each block's input in the decode step as in the whole pass): acting,
+    the value and the train call on one sequence a step, each program traced once."""
+    monkeypatch.chdir(tmp_path)
+    run(overrides=TINY_SMALLTHINKER + ["algo.total_steps=48", f"fabric.player_on_host={player_on_host}"])
+    train, act = jax_compile.find("ppo_recurrent.train"), jax_compile.find("ppo_recurrent.act_packed")
+    values = jax_compile.find("ppo_recurrent.values")
+    assert train.calls == 3 and train.traces == 1 and train.retraces == 0
+    assert act.calls == 48 and act.traces == 1 and act.retraces == 0
+    assert values.calls == 3 and values.traces == 1 and values.retraces == 0
+
+
+def test_the_third_family_in_bf16_mixed_and_its_greedy_test_episode_run(tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    run(overrides=TINY_SMALLTHINKER + ["dry_run=True", "fabric.precision=bf16-mixed", "fabric.player_on_host=True", "algo.run_test=True"])
+
+
 def test_the_second_family_in_bf16_mixed_and_its_greedy_test_episode_run(tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     run(overrides=TINY_TRINITY + ["dry_run=True", "fabric.precision=bf16-mixed", "fabric.player_on_host=True", "algo.run_test=True"])

@@ -1,8 +1,8 @@
 """The language-model policy's blocks (``sheeprl_tpu/models/lm.py``) against the plain
-references (``benchmarks/chip/reference/lfm2_ppo.py`` and ``trinity_ppo.py``, float32, nothing
-imported from the program), at small sizes on the CPU: each part, the whole pass, the chip's
-share of an expert layer, and acting through the carried state. Where a test is the same for
-the two families it is one test with a case a family."""
+references (``benchmarks/chip/reference/lfm2_ppo.py``, ``trinity_ppo.py`` and ``smallthinker_ppo.py``,
+float32, nothing imported from the program), at small sizes on the CPU: each part, the whole pass,
+the chip's share of an expert layer, and acting through the carried state. Where a test is the same
+for the families it is one test with a case a family."""
 
 import dataclasses
 import functools
@@ -88,7 +88,39 @@ def uncut2():
     return s, ref2.make_params(ref2.param_spec(s), 11)
 
 
-FAMILIES = {"lfm2": (ref, config, "uncut"), "trinity": (ref2, config2, "uncut2")}
+# ------------------------------------------------------------------ the third family, at small sizes
+# SmallThinker's pattern: a full layer without rotary, then three sliding ones with it, every layer with experts;
+# published layers 0-3 are run, as in the benchmark's cut. Seven query heads on one key-value head (the published
+# 28 on 4: a group of 7), 64 experts at 6 a token, a router that reads the block's input, ReLU-gated experts.
+ref3 = common.load_module("reference", "smallthinker_ppo")
+LAYER_TYPES3 = (["full_attention"] + ["sliding_attention"] * 3) * 2
+SIZES3 = dict(
+    hidden_size=32, layers=[0, 1, 2, 3], layer_types=LAYER_TYPES3, num_dense_layers=0, moe_intermediate_size=24,
+    num_experts=64, num_experts_per_tok=6, experts_held=64, expert_lo=0, vocab=64, num_attention_heads=7,
+    num_key_value_heads=1, head_dim=8, rope_theta=1.5e6, rope_layer_types=["sliding_attention"], sliding_window=WINDOW,
+    norm_eps=1e-6, norm_topk_prob=True, query_block=8,
+)
+
+
+def config3(**kw):
+    base = dict(
+        hidden_size=32, layers=(0, 1, 2, 3), layer_types=tuple(LAYER_TYPES3), num_dense_layers=0, intermediate_size=0,
+        moe_intermediate_size=24, num_experts=64, num_experts_per_tok=6, experts_held=64, vocab_held=64,
+        num_attention_heads=7, num_key_value_heads=1, head_dim=8, max_positions=T, query_block=8, head_chunk=8,
+        rope_theta=1.5e6, norm_eps=1e-6, sliding_window=WINDOW, rope_layer_types=("sliding_attention",), tie_embedding=False,
+        qk_norm=False, hidden_act="relu", router_apply_softmax=True, early_router=True,
+    )
+    base.update(kw)
+    return lm.LMConfig(**base)
+
+
+@pytest.fixture(scope="module")
+def uncut3():
+    s = ref3.sizes_from(SIZES3)
+    return s, ref3.make_params(ref3.param_spec(s), 11)
+
+
+FAMILIES = {"lfm2": (ref, config, "uncut"), "trinity": (ref2, config2, "uncut2"), "smallthinker": (ref3, config3, "uncut3")}
 
 
 @pytest.fixture(params=list(FAMILIES))
@@ -146,7 +178,7 @@ def test_whole_pass_and_its_gradient_equal_the_reference(family):
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want)):
         scale = float(jnp.max(jnp.abs(w))) + 1e-12
         assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * scale + 1e-7, jax.tree_util.keystr(path)
-    assert not np.any(np.asarray(got["layers"]["layer_2"]["moe"]["bias"]))  # the bias selects, and learns nothing
+    assert not np.any(np.asarray(got["layers"]["layer_2"]["moe"].get("bias", 0.0)))  # the bias selects, and learns nothing
     assert all(float(jnp.max(jnp.abs(g))) > 0 for g in jax.tree_util.tree_leaves({**got, "layers": None}))  # no top leaf is left out
 
 
@@ -544,22 +576,6 @@ PLANTED = {
 }
 
 
-@pytest.mark.parametrize("fault", list(PLANTED))
-def test_a_sequence_of_four_windows_fails_where_a_part_of_the_second_family_is_left_out(uncut2, fault):
-    """Each of the second family's own rules planted wrong through its config key (the weights stay; a leaf the
-    faulty model does not read is ignored): the logits over four windows' length leave the reference's by far
-    more than the tolerance the sound program keeps."""
-    s, params = uncut2
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (B, T), 0, 64)
-    with jax.default_matmul_precision("highest"):
-        final, _ = ref2.forward(params, tokens, s)
-        want = jnp.matmul(final, params["head"].T)
-        sound, _ = lm.logits_and_values(params, tokens, config2())
-        faulty, _ = lm.logits_and_values(params, tokens, config2(**PLANTED[fault]))
-    _close(sound, want, 1e-4)
-    assert float(jnp.max(jnp.abs(faulty - want))) > 100 * 1e-4, fault
-
-
 def _the_tpu_branch_interpreted_equals_the_plain_form(monkeypatch, fn, args, ct):
     """``fn(*args)`` and its gradients for the cotangent ``ct``, first through the plain form and then through the
     branch a TPU takes with the splash kernels run by the Pallas interpreter: equal, and no gradient leaf is zero."""
@@ -596,6 +612,9 @@ BLOCKS_AROUND_THE_KERNEL = {
     "first_family_attn": (config, "attn", dict(layer_types=("full_attention",) * 8, num_key_value_heads=2, head_dim=64)),
     "second_family_swa": (config2, "swa", dict(num_key_value_heads=1, head_dim=128, sliding_window=256)),
     "second_family_attn": (config2, "attn", dict(num_key_value_heads=1, head_dim=128, sliding_window=256)),
+    # no per-head norm: scale and rotary, or the scale alone, in the same one pass; seven query heads on one key-value head
+    "third_family_swa": (config3, "swa", dict(num_attention_heads=7, num_dense_layers=1, intermediate_size=48, head_dim=128, sliding_window=256)),
+    "third_family_attn": (config3, "attn", dict(num_attention_heads=7, num_dense_layers=1, intermediate_size=48, head_dim=128, sliding_window=256)),
 }
 
 
@@ -606,7 +625,7 @@ def test_the_tpu_branch_of_an_attention_block_in_interpret_mode(monkeypatch, cas
     output projection from the kernel's layout; the kernels run by the Pallas interpreter on the CPU) gives what
     the plain form gives in ``[B, T, H, hd]``: the block's output and the gradient of every leaf and of the input."""
     make, mixer, sizes = BLOCKS_AROUND_THE_KERNEL[case]
-    cfg = make(layers=(0,), hidden_size=64, num_attention_heads=8, max_positions=512, query_block=64, **sizes)
+    cfg = make(**{**dict(layers=(0,), hidden_size=64, num_attention_heads=8, max_positions=512, query_block=64), **sizes})
     assert cfg.kinds[0][1] == "dense"  # layer 0's weights; the second family's full block runs on a sliding layer's, alike in shape
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     p = lm.init_params(cfg, keys[0])["layers"]["layer_0"]
@@ -671,3 +690,161 @@ def test_the_second_family_s_working_copy_and_config_group():
     assert [m for m, _ in uncut.kinds].count("attn") == 8 and [f for _, f in uncut.kinds].count("dense") == 2
     first = lm.LMConfig.from_cfg(compose(config_name="config", overrides=["exp=ppo_recurrent_lfm2_tokens"]).algo.lm)
     assert first.sliding_window is None and first.rotary("attn") and first.tie_embedding and first.embed_scale == 1.0
+
+
+# ----------------------------------------------------------------------- the third family's own parts
+
+
+@pytest.mark.parametrize("part", ["swa", "attn", "moe"])
+def test_each_part_of_the_third_family_equals_the_reference(uncut3, part):
+    """A sliding layer's attention (window, rotary, no per-head norm, seven query heads on one key-value head), a
+    full layer's (neither rotary nor norm: ``k`` is its product as it is), and an expert layer whose router reads
+    other rows than its experts, with softmax weights over the six chosen logits and ReLU-gated experts."""
+    s, params = uncut3
+    cfg = config3()
+    n = jax.random.normal(jax.random.PRNGKey(1), (B, T, 32))
+    layers = params["layers"]
+    with jax.default_matmul_precision("highest"):
+        if part == "swa":
+            _close(lm.attn_op(layers["layer_1"]["attn"], n, cfg, "swa")[0], ref3.attn_op(layers["layer_1"]["attn"], n, s, "swa", None))
+        elif part == "attn":
+            _close(lm.attn_op(layers["layer_0"]["attn"], n, cfg, "attn")[0], ref3.attn_op(layers["layer_0"]["attn"], n, s, "attn", None))
+        else:
+            x = jax.random.normal(jax.random.PRNGKey(2), (B * T, 32))  # the block's input, which the router reads
+            flat = n.reshape(-1, 32)
+            out, chosen, counters = lm.moe_ffn(layers["layer_2"]["moe"], flat, cfg, x)
+            want, want_chosen = ref3.moe_ffn(layers["layer_2"]["moe"], flat, x, s, None)
+            _close(out, want)
+            assert np.array_equal(np.asarray(chosen), np.asarray(want_chosen))
+            assert float(counters["pairs_here"]) == float(counters["pairs_total"]) == B * T * 6
+            _, w = lm.route(layers["layer_2"]["moe"], x, cfg)
+            _close(jnp.sum(w, axis=-1), jnp.ones(B * T))  # a softmax over the chosen six
+            all_experts = jax.nn.softmax(jnp.matmul(x, layers["layer_2"]["moe"]["router"], precision=lm.HI), axis=-1)
+            renormalised = jnp.take_along_axis(all_experts, chosen, axis=-1)
+            _close(w, renormalised / jnp.sum(renormalised, axis=-1, keepdims=True))  # = the softmax over all, renormalised
+
+
+def test_the_eight_shares_of_eight_experts_each_add_up_to_the_uncut_layer(uncut3):
+    """Experts 0-7, 8-15, ..., 56-63 of 64 (the router whole, six experts a token): what the eight chips of the
+    deployment compute, each its own experts' part, sums to the uncut reference's layer; there is no shared expert
+    to count once."""
+    s, params = uncut3
+    p = params["layers"]["layer_2"]["moe"]
+    m = jax.random.normal(jax.random.PRNGKey(4), (B * T, 32))
+    x = jax.random.normal(jax.random.PRNGKey(5), (B * T, 32))
+    with jax.default_matmul_precision("highest"):
+        whole, chosen = ref3.moe_ffn(p, m, x, s, None)
+        parts, pairs = [], 0.0
+        for lo in range(0, 64, 8):
+            share = {**p, **{k: p[k][lo : lo + 8] for k in ("w1", "w3", "w2")}}
+            out, share_chosen, counters = lm.moe_ffn(share, m, config3(experts_held=8, expert_lo=lo), x)
+            assert np.array_equal(np.asarray(share_chosen), np.asarray(chosen))  # every chip routes alike
+            parts.append(out)
+            pairs += float(counters["pairs_here"])
+            _close(out, ref3.moe_ffn(share, m, x, {**s, "experts_held": 8, "expert_lo": lo}, None)[0])
+    assert pairs == B * T * 6  # every pair is computed on exactly one chip
+    _close(sum(parts), whole)
+    assert float(jnp.max(jnp.abs(parts[0] - whole))) > 1e-3  # a share alone is not the layer
+    assert lm.compact_rows(16384 * 6, 8, 64) == 18432  # the benchmark's cell: 98,304 pairs, 12,288 expected here
+
+
+def test_the_routing_s_gradient_reaches_the_block_s_input_and_not_the_state_after_attention(uncut3):
+    """The second half of a block of the third family over the state ``h`` after attention and the block's input
+    ``x``: the choices follow ``x`` alone, the router's gradient is the reference's, and the same weights under a
+    router that stands after attention (the other families' place) route by ``h`` and leave ``x`` without a gradient."""
+    s, params = uncut3
+    cfg, layer = config3(), params["layers"]["layer_2"]
+    h = jax.random.normal(jax.random.PRNGKey(6), (B, T, 32))
+    x = jax.random.normal(jax.random.PRNGKey(7), (B, T, 32))
+    ct = jax.random.normal(jax.random.PRNGKey(8), (B, T, 32))
+
+    def program(layer, h, x, cfg=cfg):
+        return jnp.sum(lm._ffn_half(layer, h, cfg, "moe", x)[0] * ct)
+
+    def reference(layer, h, x):
+        m = ref3.rms_norm(h, layer["ffn_norm"], 1e-6).reshape(-1, 32)
+        return jnp.sum((h + ref3.moe_ffn(layer["moe"], m, x.reshape(-1, 32), s, None)[0].reshape(h.shape)) * ct)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = jax.grad(program, argnums=(0, 1, 2))(layer, h, x), jax.grad(reference, argnums=(0, 1, 2))(layer, h, x)
+        for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+            _close(g, w, 1e-4)
+        assert float(jnp.max(jnp.abs(got[2]))) > 1e-3  # the block's input gets the routing weights' gradient
+        chosen = lm._ffn_half(layer, h, cfg, "moe", x)[1][0]
+        assert np.array_equal(np.asarray(lm._ffn_half(layer, h + 1.0, cfg, "moe", x)[1][0]), np.asarray(chosen))
+        assert not np.array_equal(np.asarray(lm._ffn_half(layer, h, cfg, "moe", x + 1.0)[1][0]), np.asarray(chosen))
+        late = config3(early_router=False)
+        assert not np.any(np.asarray(jax.grad(program, argnums=2)(layer, h, x, late)))
+        assert not np.array_equal(np.asarray(lm._ffn_half(layer, h, late, "moe", x)[1][0]), np.asarray(chosen))
+
+
+def test_the_grouped_matmul_s_tiles_follow_the_products_shapes():
+    """(512, 1024, 1024) at every product of the two accepted families, forwards and both transposes (hidden 2,048
+    against experts of 1,792 and of 1,024), and whole tiles at the third's 2,560 and 768."""
+    for hidden, width in ((2048, 1792), (2048, 1024)):
+        assert lm.gmm_tiles(24576, hidden, width) == lm.gmm_tiles(12288, width, hidden) == (512, 1024, 1024)
+    for rows in (18432, 98304, 512):
+        rows_tile, contraction, columns = lm.gmm_tiles(rows, 2560, 768)
+        assert rows_tile == 512 and 2560 % contraction == 0 and 768 % columns == 0
+        assert lm.gmm_tiles(rows, 768, 2560) == (512, columns, contraction)
+    assert lm.gmm_tiles(8, 32, 24) == (512, 1024, 1024)  # a test's widths: one tile, as before
+    assert lm.ROW_TILE == 512 and lm.compact_rows(3000, 2, 8) == 1536  # the buffer's rows are whole row tiles
+
+
+PLANTED3 = {
+    "router_after_attention": dict(early_router=False),
+    "experts_gated_by_silu": dict(hidden_act="silu"),
+    "softmax_over_all_experts_not_renormalised": dict(norm_topk_prob=False),
+    "window_ignored": dict(sliding_window=T),
+    "rotary_in_every_layer": dict(rope_layer_types=("sliding_attention", "full_attention")),
+    "rotary_in_the_full_layers_only": dict(rope_layer_types=("full_attention",)),
+    "head_tied": dict(tie_embedding=True),
+    "five_experts_a_token": dict(num_experts_per_tok=5),
+}
+
+
+@pytest.mark.parametrize("family,fault", [("trinity", f) for f in PLANTED] + [("smallthinker", f) for f in PLANTED3])
+def test_a_sequence_of_four_windows_fails_where_a_part_of_a_family_is_left_out(request, family, fault):
+    """Each of the second and the third family's own rules planted wrong through its config key (the weights stay; a leaf
+    the faulty model does not read is ignored): the logits over four windows' length leave the reference's by far more
+    than the tolerance the sound program keeps."""
+    reference, make, fixture = FAMILIES[family]
+    s, params = request.getfixturevalue(fixture)
+    planted = (PLANTED if family == "trinity" else PLANTED3)[fault]
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (B, T), 0, 64)
+    with jax.default_matmul_precision("highest"):
+        final, _ = reference.forward(params, tokens, s)
+        want = jnp.matmul(final, params["head"].T)
+        sound, _ = lm.logits_and_values(params, tokens, make())
+        faulty, _ = lm.logits_and_values(params, tokens, make(**planted))
+    _close(sound, want, 1e-4)
+    assert float(jnp.max(jnp.abs(faulty - want))) > 100 * 1e-4, fault
+
+
+def test_the_third_family_s_leaves_and_config_group():
+    """No per-head norm and no expert bias among the leaves (the other families keep theirs); the router stays float32
+    in the bfloat16 working copy; the shipped config group composes to the published model (52 layers: 13 full without
+    rotary, 39 sliding with it; 64 experts held; 151,936 rows) and the experiment to the benchmark's cut."""
+    from sheeprl_tpu.config import compose
+
+    names = {path[-1] for path in lm.param_shapes(config3())}
+    assert not names & {"q_norm", "k_norm", "bias", "gate", "shared", "op_post_norm"} and {"router", "head", "q", "w2"} <= names
+    assert {"q_norm", "k_norm", "bias"} <= {path[-1] for path in lm.param_shapes(config())}
+    assert {"q_norm", "k_norm", "bias"} <= {path[-1] for path in lm.param_shapes(config2())}
+    copy = lm.working_copy(lm.init_params(config3(), jax.random.PRNGKey(0)), jnp.bfloat16)
+    layer = copy["layers"]["layer_1"]
+    assert layer["moe"]["router"].dtype == layer["op_norm"].dtype == jnp.float32 and layer["moe"]["w1"].dtype == copy["head"].dtype == jnp.bfloat16
+    cut = lm.LMConfig.from_cfg(compose(config_name="config", overrides=["exp=ppo_recurrent_smallthinker_tokens"]).algo.lm)
+    assert (cut.layers, cut.experts_held, cut.vocab_held, cut.max_positions) == ((0, 1, 2, 3), 8, 18992, 16384)
+    assert cut.kinds == (("attn", "moe"),) + (("swa", "moe"),) * 3
+    assert cut.rotary("swa") and not cut.rotary("attn") and cut.window("swa") == 4096 and cut.rope_theta == 1.5e6
+    assert cut.early_router and cut.router_apply_softmax and cut.hidden_act == "relu" and not cut.qk_norm and not cut.tie_embedding
+    assert (cut.hidden_size, cut.num_attention_heads, cut.num_key_value_heads, cut.head, cut.moe_intermediate_size) == (2560, 28, 4, 128, 768)
+    assert (cut.num_experts, cut.num_experts_per_tok, cut.norm_eps, cut.embed_scale) == (64, 6, 1e-6, 1.0)
+    with open(os.path.join(ROOT, "sheeprl_tpu", "configs", "algo", "ppo_recurrent_smallthinker.yaml")) as f:
+        uncut = lm.LMConfig.from_cfg({**yaml.safe_load(f)["lm"], "max_positions": 16384})  # the config group alone: the uncut model
+    assert len(uncut.layers) == 52 and uncut.experts_held == 64 and uncut.vocab_held == 151936
+    assert [m for m, _ in uncut.kinds].count("attn") == 13 and all(f == "moe" for _, f in uncut.kinds)
+    for exp in ("ppo_recurrent_lfm2_tokens", "ppo_recurrent_trinity_tokens"):  # the accepted families keep the defaults
+        other = lm.LMConfig.from_cfg(compose(config_name="config", overrides=[f"exp={exp}"]).algo.lm)
+        assert other.qk_norm and other.hidden_act == "silu" and not other.router_apply_softmax and not other.early_router

@@ -10,6 +10,7 @@ process may load the TPU's library, and every xdist worker imports every test fi
 this is the only test file that describes one.
 """
 
+import dataclasses
 import math
 import re
 
@@ -252,6 +253,53 @@ def test_a_block_of_the_second_family_compiles_for_v5e_and_makes_no_projection_t
     assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9
 
 
+# -------------------------------------------------------------------------------- the third family
+# SmallThinker-21BA3B-Instruct at its published widths, the benchmark's cut: one sequence of 16,384 positions, 28 query
+# heads on 4 key-value heads of 128 with no per-head norm, a window of 4,096, 8 of 64 ReLU-gated experts of width 768
+# held at 6 a token, routed from the block's input.
+LAYER_TYPES3 = (("full_attention",) + ("sliding_attention",) * 3) * 13
+B3, T3, D3 = 1, 16384, 2560
+
+
+@pytest.fixture(scope="module")
+def cut3():
+    return lm.LMConfig(
+        hidden_size=D3, layers=(0, 1, 2, 3), layer_types=LAYER_TYPES3, num_dense_layers=0, intermediate_size=0,
+        moe_intermediate_size=768, num_experts=64, num_experts_per_tok=6, experts_held=8, vocab_held=18992,
+        num_attention_heads=28, num_key_value_heads=4, head_dim=128, max_positions=T3, rope_theta=1.5e6, norm_eps=1e-6,
+        sliding_window=4096, rope_layer_types=("sliding_attention",), tie_embedding=False, qk_norm=False, hidden_act="relu",
+        router_apply_softmax=True, early_router=True,
+    )
+
+
+@pytest.mark.parametrize("mixer", ["swa", "attn"])
+def test_a_block_of_the_third_family_compiles_for_v5e_and_makes_no_projection_twice(one_chip, cut3, monkeypatch, mixer):
+    """A sliding block and a full block with their expert layers, forwards and backwards at 1 x 16,384: Mosaic takes the
+    splash kernels at a group of seven query heads a key-value head (forward, dq, dkv) beside the grouped matmul's at
+    both widths (18,432 rows and 98,304) in whole tiles of 2,560 and 768; going backwards neither half around the
+    kernel makes ``q``, ``k``, ``v`` or ``o``'s product again; keys and values stay at 4 heads; the router's product,
+    top-k and sort are under ``lm.moe.route`` and read the block's input."""
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)
+    working = lambda name: jnp.float32 if name in lm._FLOAT32_LEAVES else jnp.bfloat16  # noqa: E731
+    p = _tree(cut3, one_chip, working)["layers"]["layer_1"]
+    x = _spec((B3, T3, D3), jnp.bfloat16, one_chip)
+    assert lm.compact_rows(B3 * T3 * 6, 8, 64) == 18432
+    assert 2560 % lm.gmm_tiles(18432, 2560, 768)[1] == 0 and 768 % lm.gmm_tiles(18432, 2560, 768)[2] == 0
+    fn = jax.grad(lambda p, x: lm._layer(p, x, cut3, mixer, "moe")[0].astype(jnp.float32).sum(), argnums=(0, 1), allow_int=True)
+    compiled = _compile(fn, p, x)
+    text = compiled.as_text()
+    assert not _remade_projection(lm.SCOPE_OF[mixer]).search(text)
+    assert "rematted_computation/lm.moe.route/dot_general" in text  # the expert layer keeps its inputs only, as before
+    # splash forward, dq, dkv; the held experts' nine products backwards at each of the two widths (the forward three are
+    # dead code where only the gradient is asked for, as in the first family's layer: there is no post-norm to keep them for)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3 + 2 * 9
+    assert "bf16[1,28,16384,128]" in text and "bf16[1,4,16384,128]" in text and "bf16[1,28,16384,128]{3,2,1,0} broadcast(" not in text
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert all('op_name="' in line for line in kernels) and sum(f"({lm.SCOPE_OF[mixer]})" in line for line in kernels) == 3
+    assert "[18432,768]" in text and "[98304,768]" in text  # the compact buffer and the fallback's width
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9
+
+
 # ------------------------------------------------ what lies between an attention block's products and its kernel
 def _results_of_size(text, scope, elements):
     """(dtype, kind, instruction, op_name) of every result with ``elements`` elements that an instruction of the
@@ -279,13 +327,19 @@ def _results_of_size(text, scope, elements):
 
 
 # (family's cut, mixer) -> (transposing copies, further bfloat16 results) of q's size that the block's gradient writes
-Q_SIZED = {("cut2", "swa"): (0, 0), ("cut2", "attn"): (0, 1), ("cut", "attn"): (1, 0)}
+# The third family's blocks are the second's without the norm (the same one pass, no float32 result, the same further results);
+# their two copies are the batch of one's, not the model's: for the weight gradients of ``q``'s and ``o``'s products, which
+# contract over the 16,384 positions of the one sequence, XLA:TPU lays the kernel's two cotangents out with the positions
+# on the lanes. The same block at 2 x 8,192 has none, and a block of 32 heads at 1 x 16,384 has the same two (my compiles,
+# PR 36; writing the products without the batch axis or with the heads as the product's free axis changes nothing).
+Q_SIZED = {("cut2", "swa"): (0, 0), ("cut2", "attn"): (0, 1), ("cut", "attn"): (1, 0), ("cut3", "swa"): (2, 0), ("cut3", "attn"): (2, 1)}
 
 
 @pytest.mark.parametrize("family,mixer", list(Q_SIZED))
 def test_between_an_attention_block_s_products_and_its_kernel_each_array_is_written_once(one_chip, request, monkeypatch, family, mixer):
-    """The three attention blocks the cells run (the first family's, heads of 64 with rotary; the second family's
-    sliding one, heads of 128 with rotary and gate, and its full one, no rotary), each with a dense FFN, forwards and
+    """The attention blocks the cells run (the first family's, heads of 64 with rotary; the second family's
+    sliding one, heads of 128 with rotary and gate, and its full one, no rotary; the third family's two, which have no
+    per-head norm: scale and rotary, or the scale alone), each with a dense FFN, forwards and
     backwards at the cell's shapes. Under the block's scope, of the results with q's element count (2 x 8,192 x 32 x hd):
     none is float32 but the stock splash kernel's own row statistics (written 128 lanes wide and copied once, by the
     kernel's wrapper); none is a ``copy`` or ``transpose`` in the second family (q, k, v and the gate come out of
@@ -300,14 +354,17 @@ def test_between_an_attention_block_s_products_and_its_kernel_each_array_is_writ
     (my compiles, PR 35), so each of the three assertions fails there."""
     monkeypatch.setattr(lm, "on_tpu", lambda: True)
     cfg = request.getfixturevalue(family)
+    if family == "cut3":  # every layer of the third family has experts: a dense FFN of two experts' width is put around its mixers here
+        cfg = dataclasses.replace(cfg, num_dense_layers=1, intermediate_size=1536)
     working = lambda name: jnp.float32 if name in lm._FLOAT32_LEAVES else jnp.bfloat16  # noqa: E731
     # a dense FFN around the mixer: the second family's layer 0 has one (its full block runs on a sliding layer's weights, alike in shape)
     p = _block(one_chip, "attn", "dense") if family == "cut" else _tree(cfg, one_chip, working)["layers"]["layer_0"]
-    x = _spec((B, T, D), jnp.bfloat16, one_chip)
+    batch, positions = (B3, T3) if family == "cut3" else (B, T)  # the cells' shapes: 1 x 16,384 in the third family's
+    x = _spec((batch, positions, cfg.hidden_size), jnp.bfloat16, one_chip)
     fn = jax.grad(lambda p, x: lm._layer(p, x, cfg, mixer, "dense")[0].astype(jnp.float32).sum(), argnums=(0, 1))
     text = _compile(fn, p, x).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
-    rows = _results_of_size(text, lm.SCOPE_OF[mixer], B * T * cfg.num_attention_heads * cfg.head)
+    rows = _results_of_size(text, lm.SCOPE_OF[mixer], batch * positions * cfg.num_attention_heads * cfg.head)
     own = [row for row in rows if "splash_mha" not in row[3]]  # the stock kernel's wrapper is not this program's to change
     assert [row for row in own if row[0] == "f32"] == []
     copies = [row for row in own if row[1] in ("copy", "transpose")]
@@ -316,22 +373,18 @@ def test_between_an_attention_block_s_products_and_its_kernel_each_array_is_writ
     assert sum(row[1] == "kernel" for row in rows if row[0] == "bf16") == 2  # the forward kernel's output and dq
 
 
-def test_the_whole_train_step_of_the_second_family_s_cut_fits_one_chip(one_chip, cut2, monkeypatch):
-    """One PPO gradient step on the cut as `ppo_recurrent.train` makes it (the loss over `lm.evaluate` in bfloat16,
-    clipping, AdamW, the state donated) at 2 x 8,192 tokens: 504.1 M parameters, 6.05 GB of arguments, and a plan
-    under the 15.0 GB at which ISSUE 34 would have the gate's product made again (14.92 GB, my compile, PR 34;
-    14.32 GB since PR 35 took the float32 arrays and the copies out of the attention blocks; the chip has 15.75)."""
+def _train_step_compiled(cfg, one_chip, batch, positions):
+    """One PPO gradient step on ``cfg`` as `ppo_recurrent.train` makes it (the loss over `lm.evaluate` in bfloat16,
+    clipping, AdamW, the state donated) at ``batch`` x ``positions`` tokens, compiled for ``one_chip``."""
     import optax
 
-    monkeypatch.setattr(lm, "on_tpu", lambda: True)
-    params = _tree(cut2, one_chip, lambda name: jnp.float32)
-    assert sum(math.prod(shape) for shape, _ in lm.param_shapes(cut2).values()) == 504_149_760
+    params = _tree(cfg, one_chip, lambda name: jnp.float32)
     tx = optax.chain(optax.clip_by_global_norm(0.5), optax.adamw(3e-4, eps=1e-4, weight_decay=0.0))
     opt = jax.tree_util.tree_map(lambda leaf: _spec(leaf.shape, leaf.dtype, one_chip), jax.eval_shape(tx.init, params))
 
     def step(params, opt, tokens, actions, old, advantages, returns, mask):
         def loss(p):
-            logp, entropy, values, aux = lm.evaluate(p, tokens, actions, cut2, jnp.bfloat16)
+            logp, entropy, values, aux = lm.evaluate(p, tokens, actions, cfg, jnp.bfloat16)
             ratio = jnp.exp(logp - old)
             policy = -jnp.sum(jnp.minimum(advantages * ratio, advantages * jnp.clip(ratio, 0.8, 1.2)) * mask)
             return policy + 0.2 * jnp.sum(jnp.square(values - returns) * mask) - 0.001 * jnp.sum(entropy * mask), lm.moe_metrics(aux)
@@ -340,16 +393,47 @@ def test_the_whole_train_step_of_the_second_family_s_cut_fits_one_chip(one_chip,
         updates, opt = tx.update(grads, opt, params)
         return optax.apply_updates(params, updates), opt, value, metrics
 
-    ints, floats = _spec((B, T), jnp.int32, one_chip), _spec((B, T), jnp.float32, one_chip)
+    ints, floats = _spec((batch, positions), jnp.int32, one_chip), _spec((batch, positions), jnp.float32, one_chip)
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         with jax.default_matmul_precision("high"):
-            compiled = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt, ints, ints, floats, floats, floats, floats).compile()
+            return jax.jit(step, donate_argnums=(0, 1)).lower(params, opt, ints, ints, floats, floats, floats, floats).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def _plan_bytes(plan):
+    return plan.argument_size_in_bytes + plan.temp_size_in_bytes + plan.output_size_in_bytes - plan.alias_size_in_bytes
+
+
+def test_the_whole_train_step_of_the_second_family_s_cut_fits_one_chip(one_chip, cut2, monkeypatch):
+    """One PPO gradient step on the cut at 2 x 8,192 tokens: 504.1 M parameters, 6.05 GB of arguments, and a plan
+    under the 15.0 GB at which ISSUE 34 would have the gate's product made again (14.92 GB, my compile, PR 34;
+    14.32 GB since PR 35 took the float32 arrays and the copies out of the attention blocks; the chip has 15.75)."""
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)
+    assert sum(math.prod(shape) for shape, _ in lm.param_shapes(cut2).values()) == 504_149_760
+    compiled = _train_step_compiled(cut2, one_chip, B, T)
     plan = compiled.memory_analysis()
     assert plan.argument_size_in_bytes < 6.1e9 and plan.alias_size_in_bytes > 6.0e9  # the state is donated
-    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes + plan.output_size_in_bytes - plan.alias_size_in_bytes < 15.0e9
+    assert _plan_bytes(plan) < 15.0e9
     # five attention layers' three kernels, and four expert layers' twelve at each of two widths
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 5 * 3 + 4 * 24
+
+
+def test_the_whole_train_step_of_the_third_family_s_cut_fits_one_chip(one_chip, cut3, monkeypatch):
+    """The same step on SmallThinker's cut at 1 x 16,384 tokens: 370.5 M parameters (ISSUE 36's arithmetic: four layers
+    of 68,326,400, embedding and head of 48,619,520 each, the final norm and the critic), 4.45 GB of donated arguments,
+    and a plan under 15.0 GB of the chip's 15.75."""
+    monkeypatch.setattr(lm, "on_tpu", lambda: True)
+    shapes = lm.param_shapes(cut3)
+    layer = sum(math.prod(shape) for path, (shape, _) in shapes.items() if path[:2] == ("layers", "layer_0"))
+    assert layer == 2 * 2560 * 3584 + 2 * 2560 * 512 + 2560 * 64 + 2 * 2560 + 8 * 3 * 2560 * 768 == 68_326_400
+    assert sum(math.prod(shape) for shape, _ in shapes.values()) == 4 * 68_326_400 + 2 * 48_619_520 + 2560 + 2560 == 370_549_760
+    compiled = _train_step_compiled(cut3, one_chip, B3, T3)
+    plan = compiled.memory_analysis()
+    print("third family's plan:", plan.argument_size_in_bytes, plan.temp_size_in_bytes, plan.output_size_in_bytes, plan.alias_size_in_bytes)
+    assert plan.argument_size_in_bytes < 4.5e9 and plan.alias_size_in_bytes > 4.4e9  # the state is donated
+    assert _plan_bytes(plan) < 15.0e9
+    # four attention layers' three kernels, and four expert layers' twelve at each of two widths
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 4 * 3 + 4 * 24
