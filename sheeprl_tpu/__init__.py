@@ -6,7 +6,11 @@ train steps, `lax.scan` recurrences, data-parallel sharding over a `jax.sharding
 with XLA collectives over ICI, and host-side numpy replay buffers feeding HBM.
 """
 
-import os
+from time import perf_counter as _perf_counter
+
+_T_IMPORT = _perf_counter()  # the set-up phase "import" runs from here to the end of this file
+
+import os  # noqa: E402
 
 __version__ = "0.1.0"
 
@@ -28,3 +32,9 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 _min_secs = os.environ.get("SHEEPRL_TPU_COMP_CACHE_MIN_SECS")
 if _min_secs is not None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", float(_min_secs))
+
+# the set-up record and JAX's compile events live beside the compile counters;
+# from here on a compile anywhere in the process is counted
+from sheeprl_tpu.core import compile as _compile  # noqa: E402
+
+_compile.record_setup_phase("import", _T_IMPORT, _perf_counter())

@@ -938,6 +938,7 @@ def _ln_enabled(ln_cfg: Dict[str, Any]) -> Tuple[bool, float]:
     return enabled, eps
 
 
+@jax_compile.setup_phase("build_agent")
 def build_agent(
     runtime,
     actions_dim: Sequence[int],
@@ -1161,38 +1162,39 @@ def build_agent(
     )
 
     # ---- init params
-    key = jax.random.PRNGKey(cfg.seed)
-    keys = jax.random.split(key, 10)
-    dummy_obs: Dict[str, jax.Array] = {}
-    for k in cnn_keys:
-        dummy_obs[k] = jnp.zeros((1, int(np.prod(obs_space[k].shape[:-2])), *obs_space[k].shape[-2:]))
-    for k in mlp_keys:
-        dummy_obs[k] = jnp.zeros((1, int(obs_space[k].shape[0])))
-    wm_params: Dict[str, Any] = {}
-    wm_params["encoder"] = encoder.init(keys[0], dummy_obs)
-    wm_params["recurrent_model"] = recurrent_model.init(
-        keys[1], jnp.zeros((1, int(sum(actions_dim)) + stochastic_size)), jnp.zeros((1, recurrent_state_size))
-    )
-    wm_params["representation_model"] = representation_model.init(keys[2], jnp.zeros((1, repr_input)))
-    wm_params["transition_model"] = transition_model.init(keys[3], jnp.zeros((1, recurrent_state_size)))
-    wm_params["observation_model"] = observation_model.init(keys[4], jnp.zeros((1, latent_state_size)))
-    wm_params["reward_model"] = reward_model.init(keys[5], jnp.zeros((1, latent_state_size)))
-    wm_params["continue_model"] = continue_model.init(keys[6], jnp.zeros((1, latent_state_size)))
-    wm_params["initial_recurrent_state"] = jnp.zeros((recurrent_state_size,), dtype=jnp.float32)
-    actor_params = actor.init(keys[7], jnp.zeros((1, latent_state_size))) if build_actor else None
-    critic_params = critic.init(keys[8], jnp.zeros((1, latent_state_size)))
+    with jax_compile.setup_phase("build_agent.init"):
+        key = jax.random.PRNGKey(cfg.seed)
+        keys = jax.random.split(key, 10)
+        dummy_obs: Dict[str, jax.Array] = {}
+        for k in cnn_keys:
+            dummy_obs[k] = jnp.zeros((1, int(np.prod(obs_space[k].shape[:-2])), *obs_space[k].shape[-2:]))
+        for k in mlp_keys:
+            dummy_obs[k] = jnp.zeros((1, int(obs_space[k].shape[0])))
+        wm_params: Dict[str, Any] = {}
+        wm_params["encoder"] = encoder.init(keys[0], dummy_obs)
+        wm_params["recurrent_model"] = recurrent_model.init(
+            keys[1], jnp.zeros((1, int(sum(actions_dim)) + stochastic_size)), jnp.zeros((1, recurrent_state_size))
+        )
+        wm_params["representation_model"] = representation_model.init(keys[2], jnp.zeros((1, repr_input)))
+        wm_params["transition_model"] = transition_model.init(keys[3], jnp.zeros((1, recurrent_state_size)))
+        wm_params["observation_model"] = observation_model.init(keys[4], jnp.zeros((1, latent_state_size)))
+        wm_params["reward_model"] = reward_model.init(keys[5], jnp.zeros((1, latent_state_size)))
+        wm_params["continue_model"] = continue_model.init(keys[6], jnp.zeros((1, latent_state_size)))
+        wm_params["initial_recurrent_state"] = jnp.zeros((recurrent_state_size,), dtype=jnp.float32)
+        actor_params = actor.init(keys[7], jnp.zeros((1, latent_state_size))) if build_actor else None
+        critic_params = critic.init(keys[8], jnp.zeros((1, latent_state_size)))
 
-    if world_model_state:
-        wm_params = jax.tree_util.tree_map(jnp.asarray, world_model_state)
-    if actor_state and build_actor:
-        actor_params = jax.tree_util.tree_map(jnp.asarray, actor_state)
-    if critic_state:
-        critic_params = jax.tree_util.tree_map(jnp.asarray, critic_state)
-    target_critic_params = (
-        jax.tree_util.tree_map(jnp.asarray, target_critic_state)
-        if target_critic_state
-        else copy.deepcopy(critic_params)
-    )
+        if world_model_state:
+            wm_params = jax.tree_util.tree_map(jnp.asarray, world_model_state)
+        if actor_state and build_actor:
+            actor_params = jax.tree_util.tree_map(jnp.asarray, actor_state)
+        if critic_state:
+            critic_params = jax.tree_util.tree_map(jnp.asarray, critic_state)
+        target_critic_params = (
+            jax.tree_util.tree_map(jnp.asarray, target_critic_state)
+            if target_critic_state
+            else copy.deepcopy(critic_params)
+        )
 
     modules = DV3Modules(
         encoder=encoder,

@@ -110,6 +110,7 @@ class DV3OptStates(NamedTuple):
     critic: Any
 
 
+@jax_compile.setup_phase("make_train_fn")
 def make_train_fn(modules: DV3Modules, cfg, runtime, is_continuous: bool, actions_dim: Sequence[int], psync=None):
     """Build (init_opt, train) where train is a single jitted scan over G gradient steps."""
     if int(cfg.algo.get("grad_microbatches", 1) or 1) > 1:

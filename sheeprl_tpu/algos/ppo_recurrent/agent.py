@@ -276,6 +276,7 @@ class RecurrentPPOPlayer:
         return self._values(self.params, obs, prev_actions, prev_states)
 
 
+@jax_compile.setup_phase("build_agent")
 def build_agent(
     runtime,
     actions_dim: Sequence[int],
@@ -320,11 +321,14 @@ def build_agent(
     h = cfg.algo.rnn.lstm.hidden_size
     init_states = (jnp.zeros((1, h)), jnp.zeros((1, h)))
     prev_actions = jnp.zeros((1, 1, sum(actions_dim)), dtype=jnp.float32)
-    params = agent.init(jax.random.PRNGKey(cfg.seed), sample_obs, prev_actions, init_states)
-    if agent_state is not None:
-        params = jax.tree_util.tree_map(jnp.asarray, agent_state)
-    params = runtime.place_params(params)
-    # player copy lives on the player device (host CPU by default): no accelerator
-    # round-trip per env step (see sheeprl_tpu.core.runtime.Runtime.player_device)
-    player = RecurrentPPOPlayer(agent, runtime.to_player(params), actions_dim, n_envs)
+    with jax_compile.setup_phase("build_agent.init"):
+        params = agent.init(jax.random.PRNGKey(cfg.seed), sample_obs, prev_actions, init_states)
+        if agent_state is not None:
+            params = jax.tree_util.tree_map(jnp.asarray, agent_state)
+    with jax_compile.setup_phase("build_agent.place"):
+        params = runtime.place_params(params)
+        # player copy lives on the player device (host CPU by default): no accelerator
+        # round-trip per env step (see sheeprl_tpu.core.runtime.Runtime.player_device)
+        player_params = runtime.to_player(params)
+    player = RecurrentPPOPlayer(agent, player_params, actions_dim, n_envs)
     return agent, params, player

@@ -45,6 +45,7 @@ def _masked_mean(x: jax.Array, mask: jax.Array) -> jax.Array:
     return (x * mask).sum() / jnp.clip(mask.sum(), 1, None)
 
 
+@jax_compile.setup_phase("make_train_fn")
 def make_train_fn(agent, tx, cfg, runtime, obs_keys, cnn_keys, params_sync=None):
     update_epochs = int(cfg.algo.update_epochs)
     n_batches = max(int(cfg.algo.per_rank_num_batches), 1)

@@ -136,14 +136,17 @@ def build_token_agent(
         if key not in obs_space.spaces or key not in cfg.algo.mlp_keys.encoder:
             raise ValueError(f"a language-model policy needs the observation '{key}' among algo.mlp_keys.encoder")
     agent = TokenPolicy(config, runtime.compute_dtype)
-    if agent_state is not None:
-        params = jax.tree_util.tree_map(jnp.asarray, agent_state)
-    else:
-        params = jax.jit(lambda key: lm.init_params(config, key))(jax.random.PRNGKey(cfg.seed))
-    params = runtime.place_params(params)
+    with jax_compile.setup_phase("build_agent.init"):
+        if agent_state is not None:
+            params = jax.tree_util.tree_map(jnp.asarray, agent_state)
+        else:
+            params = jax.jit(lambda key: lm.init_params(config, key))(jax.random.PRNGKey(cfg.seed))
+    with jax_compile.setup_phase("build_agent.place"):
+        params = runtime.place_params(params)
+        player_params = runtime.to_player(params)
     n_envs = cfg.env.num_envs * runtime.world_size
     # the carried state lives beside the player's parameters, under their sharding (on the mesh device the
     # learner's replicated arrays: `main()` binds them), so that the act program is traced once
     placement = runtime.player_device if runtime.player_on_host else runtime.replicated
-    player = TokenPlayer(agent, runtime.to_player(params), n_envs, placement)
+    player = TokenPlayer(agent, player_params, n_envs, placement)
     return agent, params, player

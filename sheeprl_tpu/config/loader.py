@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import yaml
 
+from sheeprl_tpu.core.compile import setup_phase
 from sheeprl_tpu.utils.utils import dotdict, get_nested, set_nested
 
 MISSING = "???"
@@ -272,6 +273,7 @@ def _parse_cli_value(text: str):
         return text
 
 
+@setup_phase("compose")
 def compose(
     config_name: str = "config",
     overrides: Optional[Sequence[str]] = None,

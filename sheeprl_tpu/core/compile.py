@@ -18,11 +18,16 @@ retrace in steady state):
   ``jit(f).lower(specs).compile()`` alone does NOT populate the jit call cache
   (a later ``f(args)`` would re-trace), which is why the guard keeps the
   compiled executable and routes calls to it by abstract signature.
-- cache listeners count persistent-compilation-cache hits/misses
-  (``jax.monitoring`` events) and :func:`drain_compile_counters` folds all
-  counters into a ``MetricAggregator`` at log boundaries
+- cache listeners count persistent-compilation-cache hits/misses and sum JAX's
+  own compile durations (``jax.monitoring`` events: tracing and lowering, XLA
+  compile, loads from the persistent cache), and :func:`drain_compile_counters`
+  folds all counters into a ``MetricAggregator`` at log boundaries
   (``Compile/retraces``, ``Compile/cache_hits``, ``Compile/cache_misses``,
   ``Time/compile_seconds``).
+- :class:`setup_phase` keeps the record of a process's set-up: ``(name,
+  start, end)`` of each phase (the package's import, ``compose``, the
+  runtime, ``build_agent``, ``make_train_fn``, the wait for the AOT warmup,
+  every guarded compile), read through :func:`process_stats`.
 - :func:`pow2_bucket` / :func:`bucketed_pad` are the shared canonical-shape
   utilities (generalized from ppo_recurrent's inline episode bucketing) so
   variable-length sequences / partial final batches land in a bounded set of
@@ -35,6 +40,7 @@ recorded before this subsystem existed keep working).
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -164,6 +170,23 @@ _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "cache_hits",
     "/jax/compilation_cache/cache_misses": "cache_misses",
 }
+# JAX's own compile durations, summed into the keys process_stats() returns. They
+# count every program of the PROCESS, whoever made it (a benchmark's reference, a
+# test's helpers), not only the guarded functions.
+_TRACE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration", "/jax/core/compile/jaxpr_to_mlir_module_duration")
+# one compile request: JAX times the whole of `compile_or_get_cached` under this
+# event, a load from the persistent cache (`cache_retrieval_time_sec`) included
+_REQUEST_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_TOTALS: Dict[str, float] = {
+    "trace_seconds": 0.0, "trace_count": 0,
+    "backend_compile_seconds": 0.0, "backend_compile_count": 0,
+    "cache_retrieval_seconds": 0.0, "cache_retrieval_count": 0,
+}
+# per thread: how deep in JAX's open traces and lowerings it is, the seconds of
+# the outermost ones it closed (GuardedFn's jit path reads the difference), and
+# for each open compile request whether the persistent cache answered it
+_jax_tls = threading.local()
 
 # Aggregator keys this module feeds (register them in configs/metric/default.yaml
 # and each algo's AGGREGATOR_KEYS or the CLI prunes them).
@@ -176,7 +199,18 @@ METRIC_KEYS = (
 
 
 def install_cache_listeners() -> None:
-    """Count persistent-cache hit/miss events (idempotent; listener is global)."""
+    """Count persistent-cache hit/miss events and sum JAX's compile durations
+    (idempotent; the listeners are global, installed when this module is imported).
+
+    ``trace_seconds`` is jaxpr tracing plus lowering to MLIR; a trace opened inside
+    another (a nested ``jit``) is part of the outer one and is not added again (JAX
+    records each interval's start as a scalar event, which keeps the depth per
+    thread). JAX's ``backend_compile_duration`` times a whole compile request, a
+    load from the persistent cache included, so each request is booked once, by
+    how it was served: ``cache_retrieval_seconds`` if the cache answered it (from
+    the request to the loaded executable: the key, the read, the load), else
+    ``backend_compile_seconds``, XLA's compile of what the cache did not hold. Each
+    has a ``*_count``; the two sum to JAX's own total of the event."""
     global _LISTENER_INSTALLED
     with _LOCK:
         if _LISTENER_INSTALLED:
@@ -189,7 +223,51 @@ def install_cache_listeners() -> None:
             with _LOCK:
                 _CACHE_COUNTS[key] += 1
 
+    def _started(event: str, value: float, **kwargs) -> None:
+        if event in _TRACE_EVENTS:
+            _jax_tls.depth = getattr(_jax_tls, "depth", 0) + 1
+        elif event == _REQUEST_EVENT:
+            _open_requests().append(False)
+
+    def _ended(event: str, duration: float, **kwargs) -> None:
+        if event in _TRACE_EVENTS:
+            depth = getattr(_jax_tls, "depth", 0)
+            _jax_tls.depth = max(depth - 1, 0)
+            if depth > 1:  # inside an open trace or lowering: its seconds are the outer one's
+                return
+            _jax_tls.trace_seconds = _thread_trace_seconds() + duration
+            kind = "trace"
+        elif event == _RETRIEVAL_EVENT:
+            requests = _open_requests()
+            if requests:  # the open request was served by the cache: booked whole at its end
+                requests[-1] = True
+                return
+            kind = "cache_retrieval"
+        elif event == _REQUEST_EVENT:
+            requests = _open_requests()
+            kind = "cache_retrieval" if requests and requests.pop() else "backend_compile"
+        else:
+            return
+        with _LOCK:
+            _JAX_TOTALS[f"{kind}_seconds"] += duration
+            _JAX_TOTALS[f"{kind}_count"] += 1
+
     jax.monitoring.register_event_listener(_listener)
+    jax.monitoring.register_scalar_listener(_started)
+    jax.monitoring.register_event_duration_secs_listener(_ended)
+
+
+def _open_requests() -> List[bool]:
+    """This thread's open compile requests, innermost last: whether the cache answered each."""
+    requests = getattr(_jax_tls, "requests", None)
+    if requests is None:
+        requests = _jax_tls.requests = []
+    return requests
+
+
+def _thread_trace_seconds() -> float:
+    """Seconds of outermost traces and lowerings this thread has closed."""
+    return getattr(_jax_tls, "trace_seconds", 0.0)
 
 
 def configure(cfg: Any) -> _View:
@@ -224,6 +302,67 @@ def mark_steady() -> None:
 
 def is_steady() -> bool:
     return _STEADY
+
+
+# --------------------------------------------------------------------------- #
+# Set-up phases
+# --------------------------------------------------------------------------- #
+
+# (name, start, end) on time.perf_counter, the clock of the trace ring and of a
+# benchmark's spans, in the order the phases ended; a process has some ten, and
+# none after the steady-state watermark (a compile then is a retrace, not set-up)
+_SETUP_PHASES: List[Tuple[str, float, float]] = []
+
+
+class setup_phase:
+    """One phase of the process's set-up, as a context manager or a decorator.
+
+    Appends ``(name, start, end)`` to the record :func:`process_stats` returns
+    (``setup_phases``, and ``setup_seconds`` by name) and opens the span
+    ``setup.<name>``, so that with a tracer configured (``SHEEPRL_TPU_TRACE``)
+    set-up lies in the exported trace, and in a profiler capture as
+    ``sheeprl.setup.<name>``. Phases nest (``build_agent.init`` inside
+    ``build_agent``); the union of the intervals is the program's share of
+    set-up. Two clock reads and a list row a phase: nothing on a step's path."""
+
+    __slots__ = ("name", "_span", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> "setup_phase":
+        self._span = trace.span("setup." + self.name)
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        t1 = time.perf_counter()
+        self._span.__exit__(*exc)
+        record_setup_phase(self.name, self._t0, t1, span=False)
+        return False
+
+    def __call__(self, fun: Callable) -> Callable:
+        name = self.name
+
+        @functools.wraps(fun)
+        def phased(*args: Any, **kwargs: Any) -> Any:
+            with setup_phase(name):
+                return fun(*args, **kwargs)
+
+        return phased
+
+
+def record_setup_phase(name: str, start: float, end: float, span: bool = True) -> None:
+    """The one writer of the set-up record, for a phase its caller timed itself
+    (the package's import, which begins before this module exists; a guarded
+    compile, whose spans are its own): with ``span`` it is also the span
+    ``setup.<name>``. Nothing is kept after :func:`mark_steady`."""
+    if _STEADY:
+        return
+    _SETUP_PHASES.append((name, start, end))
+    if span:
+        trace.add_span("setup." + name, start, end, clock="perf")
 
 
 class RetraceError(RuntimeError):
@@ -355,13 +494,14 @@ class GuardedFn:
         # instead of redundantly tracing the same signature on the hot path
         self._aot_pending: List[threading.Event] = []
         self._trace_count = 0
+        self._lower_mark = 0.0  # this thread's closed-trace seconds when the last trace began
         self.calls = 0
         self.retraces = 0
         self.aot_compiles = 0
         self.aot_fallbacks = 0
         # compile_seconds: trace + lower + compile (or load from the persistent
-        # cache), AOT and jit path alike; lower_seconds: the trace-and-lower part
-        # of the AOT compiles, which no cache saves
+        # cache), AOT and jit path alike; lower_seconds: the trace-and-lower part,
+        # which no cache saves (on the jit path, what JAX's own events timed)
         self.compile_seconds = 0.0
         self.lower_seconds = 0.0
         # host time of the calls that compiled nothing, split at the point where
@@ -371,6 +511,9 @@ class GuardedFn:
         self.execute_seconds = 0.0
         # span names built once: the disabled tracer's fast path must not format strings
         self._span_names = {k: f"{self.name}.{k}" for k in ("route", "execute", "lower", "compile")}
+        # each compile is a set-up phase of its own, spanned by `lower` and `compile`
+        # (AOT) or by the `execute` of the call that traced (jit)
+        self._phase = f"compile.{self.name}"
         self.first_call_s: Optional[float] = None  # seconds since module import
         self.last_signature: Optional[Tuple] = None
         self.last_diff: Optional[str] = None
@@ -378,8 +521,11 @@ class GuardedFn:
 
         def _traced(*args, **kwargs):
             # runs ONLY while jax traces the function (retraces included);
-            # executed computations never re-enter the Python body
+            # executed computations never re-enter the Python body. The trace
+            # opened around it closes later, so this thread's total of closed
+            # traces is still the one from before the call
             self._trace_count += 1
+            self._lower_mark = _thread_trace_seconds()
             return fun(*args, **kwargs)
 
         try:
@@ -426,7 +572,9 @@ class GuardedFn:
         t_lowered = time.perf_counter()
         with trace.span(self._span_names["compile"]):
             exe = lowered.compile()
-        dt = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        dt = t1 - t0
+        record_setup_phase(self._phase, t0, t1, span=False)  # its spans are `lower` and `compile`
         flops = _cost_flops(exe)
         bytes_accessed = _cost_bytes(exe)
         _record_program(self, lowered, exe, dt)
@@ -516,20 +664,25 @@ class GuardedFn:
         t0 = time.perf_counter()
         with trace.span(self._span_names["execute"]):
             out = self._jitted(*args, **kwargs)
-        dt = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        dt = t1 - t0
         if self._trace_count != before:
             if sig is None:
                 sig = abstract_signature(args, kwargs)
-            self._on_compile(sig, dt)  # a call that traced is compile time, not execute time
+            # a call that traced is compile time, not execute time; its trace and
+            # lowering are what JAX's events timed on this thread meanwhile
+            self._on_compile(sig, dt, _thread_trace_seconds() - self._lower_mark)
+            record_setup_phase(self._phase, t0, t1, span=False)
         else:
             self.execute_seconds += dt
         if self.first_call_s is None:
             self.first_call_s = time.perf_counter() - _T0
         return out
 
-    def _on_compile(self, sig: Tuple, dt: float) -> None:
+    def _on_compile(self, sig: Tuple, dt: float, lower_dt: float) -> None:
         with _LOCK:
             self.compile_seconds += dt
+            self.lower_seconds += lower_dt
             is_retrace = self._had_any_compile
             self._had_any_compile = True
             prev = self.last_signature
@@ -661,12 +814,18 @@ def release_executables() -> None:
 
 
 def process_stats() -> Dict[str, Any]:
-    """Totals across every guarded function plus the persistent-cache counters
-    and the count of AOT warmup jobs that raised."""
+    """Totals across every guarded function plus the persistent-cache counters,
+    the count of AOT warmup jobs that raised, JAX's compile durations of the
+    whole process (``trace_seconds``, ``backend_compile_seconds``,
+    ``cache_retrieval_seconds``, each with a ``*_count``) and the set-up record:
+    ``setup_phases``, the ``(name, start, end)`` of every :class:`setup_phase`,
+    and ``setup_seconds``, their lengths summed by name."""
     with _LOCK:
         fns = list(_REGISTRY)
         cache = dict(_CACHE_COUNTS)
+        jax_totals = dict(_JAX_TOTALS)
         warmup_errors = _WARMUP_ERRORS
+    phases = list(_SETUP_PHASES)
     totals = {
         "calls": 0,
         "traces": 0,
@@ -684,8 +843,14 @@ def process_stats() -> Dict[str, Any]:
         for k in totals:
             totals[k] += s[k]
     totals.update(cache)
+    totals.update(jax_totals)
     totals["warmup_errors"] = warmup_errors
     totals["functions"] = per_fn
+    seconds: Dict[str, float] = {}
+    for name, start, end in phases:
+        seconds[name] = seconds.get(name, 0.0) + (end - start)
+    totals["setup_phases"] = phases
+    totals["setup_seconds"] = seconds
     return totals
 
 
@@ -787,8 +952,12 @@ class AOTWarmup:
         return self._done.is_set()
 
     def wait(self, timeout: Optional[float] = None) -> bool:
-        """Block until every queued warmup compile finished (cheap once done)."""
-        return self._done.wait(timeout)
+        """Block until every queued warmup compile finished (cheap once done: the
+        set-up phase ``aot_warmup`` is the first wait that found work left)."""
+        if self._done.is_set():
+            return True
+        with setup_phase("aot_warmup"):
+            return self._done.wait(timeout)
 
 
 # --------------------------------------------------------------------------- #
@@ -834,3 +1003,8 @@ def bucketed_pad(
         mask[:ln, i] = 1.0
     out["mask"] = mask
     return out
+
+
+# JAX's compile events are counted from the package's import on, a compile the
+# caller makes before any run is configured included
+install_cache_listeners()

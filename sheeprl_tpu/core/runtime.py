@@ -29,6 +29,8 @@ import numpy as np
 from jax._src import distributed as _jax_distributed
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sheeprl_tpu.core.compile import setup_phase
+
 _PRECISIONS = {
     "32-true": (jnp.float32, jnp.float32),
     "32": (jnp.float32, jnp.float32),
@@ -430,6 +432,7 @@ class Runtime:
         return seed_everything(seed)
 
 
+@setup_phase("runtime")
 def build_runtime(cfg_fabric: Dict[str, Any], extra_callbacks: Optional[Sequence[Any]] = None) -> Runtime:
     """Instantiate the Runtime from the ``fabric:`` config group."""
     callbacks = []

@@ -17,6 +17,7 @@ from typing import Any, Optional, Sequence, Tuple
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from sheeprl_tpu.core.compile import setup_phase
 from sheeprl_tpu.data.buffers import (
     EnvIndependentReplayBuffer,
     EpisodeBuffer,
@@ -122,6 +123,7 @@ def make_replay_ring(cfg, n_envs: int, leaf_specs):
     return ReplayRing(capacity, int(n_envs), leaf_specs)
 
 
+@setup_phase("replay")
 def make_sequential_replay(
     cfg,
     runtime,

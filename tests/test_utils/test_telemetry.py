@@ -62,6 +62,45 @@ def test_disabled_span_is_a_shared_singleton():
     assert trace.export() is None
 
 
+def test_disabled_setup_phase_keeps_nothing_beyond_its_row(monkeypatch):
+    """A set-up phase with tracing off: the no-op span, two clock reads and one row of the record. Of what a
+    thousand phases allocate, all that stays is made on the lines that make the row; the rest is freed
+    within its call."""
+    import inspect
+    import tracemalloc
+
+    from sheeprl_tpu.core import compile as jax_compile
+
+    def boom(*a, **k):
+        raise AssertionError("a disabled setup phase reached the recording layer")
+
+    monkeypatch.setattr(trace, "_begin", boom)
+    monkeypatch.setattr(trace, "_record_span", boom)
+    rows = []
+    monkeypatch.setattr(jax_compile, "_SETUP_PHASES", rows)
+    monkeypatch.setattr(jax_compile, "_STEADY", False)
+    row_lines = set()  # the two clock reads and the append
+    for method in (jax_compile.setup_phase.__enter__, jax_compile.setup_phase.__exit__, jax_compile.record_setup_phase):
+        lines, first = inspect.getsourcelines(method)
+        row_lines |= {first + i for i, line in enumerate(lines) if "perf_counter()" in line or ".append(" in line}
+    assert len(row_lines) == 3
+    tracemalloc.start()
+    try:
+        for _ in range(1000):
+            with jax_compile.setup_phase("compose") as phase:
+                assert phase._span is trace._NOOP
+        current, peak = tracemalloc.get_traced_memory()
+        kept = tracemalloc.take_snapshot().filter_traces(
+            [tracemalloc.Filter(True, jax_compile.__file__), tracemalloc.Filter(True, trace.__file__)]
+        )
+    finally:
+        tracemalloc.stop()
+    assert len(rows) == 1000
+    assert {stat.traceback[0].lineno for stat in kept.statistics("lineno")} <= row_lines
+    assert all(stat.traceback[0].filename == jax_compile.__file__ for stat in kept.statistics("lineno"))
+    assert peak - current <= 2048
+
+
 # --------------------------------------------------------------------------- #
 # ring semantics
 # --------------------------------------------------------------------------- #
