@@ -17,7 +17,8 @@ retrace in steady state):
   reset / first-rollout collection, so the accelerator is warm before step 0.
   ``jit(f).lower(specs).compile()`` alone does NOT populate the jit call cache
   (a later ``f(args)`` would re-trace), which is why the guard keeps the
-  compiled executable and routes calls to it by abstract signature.
+  compiled executable and routes calls to it by abstract signature (read only
+  at the leaves that tell its executables apart: :meth:`GuardedFn._reselect`).
 - cache listeners count persistent-compilation-cache hits/misses and sum JAX's
   own compile durations (``jax.monitoring`` events: tracing and lowering, XLA
   compile, loads from the persistent cache), and :func:`drain_compile_counters`
@@ -469,7 +470,11 @@ class GuardedFn:
     """A ``jax.jit``-compatible callable with trace accounting and AOT routing.
 
     Calls whose abstract signature matches a warmed AOT executable go straight
-    to it (zero tracing); everything else goes through the jitted path, where a
+    to it (zero tracing). The executable is picked by the few leaves that tell
+    the registered ones apart (none, with one registered) and checks the rest of
+    the call itself, refusing one it was not compiled for before anything runs;
+    a refused call takes the full route, the whole abstract signature and its
+    lookup. Everything else goes through the jitted path, where a
     side-effecting hook inside the wrapped function counts actual traces. Any
     trace after the first compile of this function is a *retrace*: the
     signature diff is logged, and after :func:`mark_steady` the configured
@@ -485,6 +490,8 @@ class GuardedFn:
         # compile time (telemetry: Time/mfu is computed from these, never
         # hand-derived). Keyed like _aot; last_step_flops is the newest.
         self._aot_flops: Dict[Tuple, float] = {}
+        # what a call's executable is picked by (_reselect), rebuilt whenever _aot changes
+        self._selector: Tuple[Any, Any] = (None, None)
         self.last_step_flops: Optional[float] = None
         # bytes accessed per call, same provenance (stats() and the program ledger)
         self.last_step_bytes: Optional[float] = None
@@ -505,10 +512,14 @@ class GuardedFn:
         self.compile_seconds = 0.0
         self.lower_seconds = 0.0
         # host time of the calls that compiled nothing, split at the point where
-        # the executable is known: finding it (abstract_signature over every
-        # leaf, lookup, a wait for a pending warmup) and the executable's own call
+        # the executable is known: finding it (the selector's pick; on a miss also
+        # a refused dispatch, abstract_signature over every leaf, the lookup, a
+        # wait for a pending warmup) and the executable's own call
         self.route_seconds = 0.0
         self.execute_seconds = 0.0
+        # routed calls the selector's pick served / that took the full route
+        self.route_hits = 0
+        self.route_misses = 0
         # span names built once: the disabled tracer's fast path must not format strings
         self._span_names = {k: f"{self.name}.{k}" for k in ("route", "execute", "lower", "compile")}
         # each compile is a set-up phase of its own, spanned by `lower` and `compile`
@@ -555,6 +566,8 @@ class GuardedFn:
             "lower_seconds": self.lower_seconds,
             "route_seconds": self.route_seconds,
             "execute_seconds": self.execute_seconds,
+            "route_hits": self.route_hits,
+            "route_misses": self.route_misses,
             "first_call_s": self.first_call_s,
             "flops_dispatched": self.flops_dispatched,
             "step_flops": self.last_step_flops,
@@ -583,6 +596,7 @@ class GuardedFn:
             if flops is not None:
                 self._aot_flops[_routing_key(sig)] = flops
                 self.last_step_flops = flops
+            self._reselect()
             if bytes_accessed is not None:
                 self.last_step_bytes = bytes_accessed
             self.aot_compiles += 1
@@ -612,36 +626,34 @@ class GuardedFn:
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
         self.calls += 1
         sig: Optional[Tuple] = None
-        exe = key = None
         t0 = time.perf_counter()
         if self._aot or self._aot_pending:
-            with trace.span(self._span_names["route"]):
-                sig = abstract_signature(args, kwargs)
-                key = _routing_key(sig)
-                exe = self._aot.get(key)
-                if exe is None and self._aot_pending:
-                    # a background warmup for this fn is (probably) compiling the
-                    # executable this call needs: waiting is never slower than
-                    # tracing+compiling the same signature here, and keeps the
-                    # jit-path compile from registering as a spurious retrace
-                    for ev in list(self._aot_pending):
-                        ev.wait(timeout=600.0)
-                    self._aot_pending = []
-                    exe = self._aot.get(key)
-        t_routed = time.perf_counter()
-        self.route_seconds += t_routed - t0
-        if exe is not None:
-            try:
-                with trace.span(self._span_names["execute"]):
-                    out = exe(*args, **kwargs)
-                self.execute_seconds += time.perf_counter() - t_routed
-                fl = self._aot_flops.get(key)
-                if fl is not None:
-                    self.flops_dispatched += fl
-                if self.first_call_s is None:
-                    self.first_call_s = time.perf_counter() - _T0
-                return out
-            except (TypeError, ValueError) as e:
+            with trace.span(self._span_names["route"]) as span:
+                pick = self._pick(args, kwargs)
+                span.set(hit=pick is not None)
+                if pick is None:
+                    sig, key, pick = self._route(args, kwargs)
+            error = None
+            if sig is None:  # the selector's pick
+                ran, out = self._execute(pick, args, kwargs, t0)
+                if ran:
+                    self.route_hits += 1
+                    return out
+                with trace.span(self._span_names["route"]) as span:
+                    span.set(hit=False)
+                    sig, key, found = self._route(args, kwargs)
+                if found is not None and found[0] is pick[0]:
+                    # the executable that holds the call's signature refused
+                    # it: a fault of placement or layout, not of signature
+                    pick, error = None, out
+                else:
+                    pick = found
+            if pick is not None:
+                ran, out = self._execute(pick, args, kwargs, t0)
+                if ran:
+                    return out
+                error = out
+            if error is not None:
                 # the compiled executable validates its inputs BEFORE it
                 # runs (nothing executed, nothing donated), and that is the
                 # only place a dispatch raises these types: the signature
@@ -654,12 +666,14 @@ class GuardedFn:
                 with _LOCK:
                     self._aot.pop(key, None)
                     self._aot_flops.pop(key, None)
+                    self._reselect()
                 _logger.warning(
                     "[compile] AOT executable for '%s' rejected its inputs (%s); "
                     "falling back to JIT for this signature",
                     self.name,
-                    str(e).splitlines()[0][:200],
+                    str(error).splitlines()[0][:200],
                 )
+        self.route_seconds += time.perf_counter() - t0
         before = self._trace_count
         t0 = time.perf_counter()
         with trace.span(self._span_names["execute"]):
@@ -678,6 +692,78 @@ class GuardedFn:
         if self.first_call_s is None:
             self.first_call_s = time.perf_counter() - _T0
         return out
+
+    def _reselect(self) -> None:
+        """Rebuild the selector from the registry (the caller holds ``_LOCK``):
+        ``(only, groups)``, each pick an ``(executable, flops)`` pair. With one
+        executable registered, ``only`` is its pick: nothing of the call is read,
+        the executable checks the whole signature itself. With several, ``groups``
+        maps each registered pytree structure to the flat leaf positions at which
+        its routing keys differ and the picks by ``(shape, dtype)`` there."""
+        picks = {key: (exe, self._aot_flops.get(key)) for key, exe in self._aot.items()}
+        if len(picks) <= 1:
+            self._selector = (next(iter(picks.values()), None), None)
+            return
+        by_treedef: Dict[Any, List[Tuple]] = {}
+        for key in picks:
+            by_treedef.setdefault(key[1], []).append(key)
+        groups = {}
+        for treedef, keys in by_treedef.items():
+            positions = tuple(i for i in range(len(keys[0][0])) if len({key[0][i] for key in keys}) > 1)
+            groups[treedef] = (positions, {tuple(key[0][i][:2] for i in positions): picks[key] for key in keys})
+        self._selector = (None, groups)
+
+    def _pick(self, args: Tuple, kwargs: Dict[str, Any]) -> Optional[Tuple[Any, Optional[float]]]:
+        """The selector's ``(executable, flops)`` for a call, or None where it
+        names none; only the leaves that tell the executables apart are read."""
+        only, groups = self._selector
+        if groups is None:
+            return only
+        leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
+        group = groups.get(treedef)
+        if group is None:
+            return None
+        positions, picks = group
+        return picks.get(tuple(_leaf_sig(leaves[i])[:2] for i in positions))
+
+    def _route(self, args: Tuple, kwargs: Dict[str, Any]) -> Tuple[Tuple, Tuple, Any]:
+        """The full route: ``(signature, routing key, pick or None)`` from the
+        whole abstract signature, its lookup and a wait for pending warmups."""
+        self.route_misses += 1
+        sig = abstract_signature(args, kwargs)
+        key = _routing_key(sig)
+        exe = self._aot.get(key)
+        if exe is None and self._aot_pending:
+            # a background warmup for this fn is (probably) compiling the
+            # executable this call needs: waiting is never slower than
+            # tracing+compiling the same signature here, and keeps the
+            # jit-path compile from registering as a spurious retrace
+            for ev in list(self._aot_pending):
+                ev.wait(timeout=600.0)
+            self._aot_pending = []
+            exe = self._aot.get(key)
+        return sig, key, None if exe is None else (exe, self._aot_flops.get(key))
+
+    def _execute(self, pick: Tuple[Any, Optional[float]], args: Tuple, kwargs: Dict[str, Any],
+                 t0: float) -> Tuple[bool, Any]:
+        """Call a registered executable: ``(True, outputs)``, or ``(False, error)``
+        where it refused the call (``TypeError``/``ValueError``, raised before
+        anything runs or is donated). The route's time runs from ``t0``."""
+        exe, flops = pick
+        t_routed = time.perf_counter()
+        try:
+            with trace.span(self._span_names["execute"]):
+                out = exe(*args, **kwargs)
+        except (TypeError, ValueError) as e:
+            return False, e
+        t1 = time.perf_counter()
+        self.route_seconds += t_routed - t0
+        self.execute_seconds += t1 - t_routed
+        if flops is not None:
+            self.flops_dispatched += flops
+        if self.first_call_s is None:
+            self.first_call_s = t1 - _T0
+        return True, out
 
     def _on_compile(self, sig: Tuple, dt: float, lower_dt: float) -> None:
         with _LOCK:
@@ -808,6 +894,7 @@ def release_executables() -> None:
         for gfn in _REGISTRY:
             gfn._aot.clear()
             gfn._aot_flops.clear()
+            gfn._reselect()
             gfn._had_any_compile = False
             gfn.last_signature = None
     jax.clear_caches()
@@ -832,6 +919,8 @@ def process_stats() -> Dict[str, Any]:
         "retraces": 0,
         "aot_compiles": 0,
         "aot_fallbacks": 0,
+        "route_hits": 0,
+        "route_misses": 0,
         "compile_seconds": 0.0,
         "lower_seconds": 0.0,
         "flops_dispatched": 0.0,

@@ -213,3 +213,156 @@ def test_aot_compile_records_lower_and_compile_spans():
         assert [ev[trace._EV_NAME] for ev in tracer.events()] == ["t.aotspans.lower", "t.aotspans.compile"]
     finally:
         trace.disable()
+
+
+# --------------------------------------------------------------------------- #
+# The selector: a call is routed by the leaves that tell the executables apart
+# --------------------------------------------------------------------------- #
+
+
+def _count_full_routes(monkeypatch):
+    seen = []
+    real = jax_compile.abstract_signature
+
+    def counted(args, kwargs):
+        seen.append(1)
+        return real(args, kwargs)
+
+    monkeypatch.setattr(jax_compile, "abstract_signature", counted)
+    return seen
+
+
+def test_one_executable_is_called_without_reading_the_signature(monkeypatch):
+    from sheeprl_tpu.telemetry import trace
+
+    gfn = jax_compile.guarded_jit(lambda p, x: jax.tree_util.tree_map(lambda a: a + x.sum(), p), name="t.sel.one")
+    params = {f"w{i}": jnp.full((4,), float(i)) for i in range(16)}
+    gfn.aot_compile(jax_compile.specs_of(params), jax.ShapeDtypeStruct((3,), jnp.float32))
+    full_routes = _count_full_routes(monkeypatch)
+    tracer = trace.configure(plane="train", trace_id="selector")
+    try:
+        for _ in range(5):
+            out = gfn(params, jnp.ones((3,)))
+        events = [ev for ev in tracer.events() if ev[trace._EV_NAME] == "t.sel.one.route"]
+    finally:
+        trace.disable()
+    np.testing.assert_allclose(np.asarray(out["w7"]), 10.0)
+    assert full_routes == []
+    assert gfn.traces == 0
+    s = gfn.stats()
+    assert (s["route_hits"], s["route_misses"]) == (5, 0)
+    assert [ev[trace._EV_ARGS] for ev in events] == [{"hit": True}] * 5
+    totals = jax_compile.process_stats()
+    assert totals["route_hits"] >= 5 and totals["functions"]["t.sel.one"]["route_hits"] == 5
+
+
+def test_two_executables_are_told_apart_by_the_leaf_that_differs(monkeypatch):
+    def f(tree, scale):
+        return tree["x"] @ tree["w"] * scale
+
+    gfn = jax_compile.guarded_jit(f, name="t.sel.two")
+    w = jnp.arange(12.0).reshape(3, 4)
+    for rows in (2, 5):
+        gfn.aot_compile({"x": jax.ShapeDtypeStruct((rows, 3), jnp.float32),
+                         "w": jax.ShapeDtypeStruct((3, 4), jnp.float32)},
+                        jax.ShapeDtypeStruct((), jnp.float32))
+    _only, groups = gfn._selector
+    assert [positions for positions, _ in groups.values()] == [(1,)]  # flat leaves: w, x, scale
+    full_routes = _count_full_routes(monkeypatch)
+    reference = jax.jit(f)
+    for i in range(6):
+        tree = {"x": jnp.full((2 if i % 2 else 5, 3), float(i)), "w": w}
+        np.testing.assert_array_equal(np.asarray(gfn(tree, jnp.float32(0.5))),
+                                      np.asarray(reference(tree, jnp.float32(0.5))))
+    assert full_routes == []
+    assert gfn.traces == 0
+    assert (gfn.route_hits, gfn.route_misses, gfn.aot_fallbacks) == (6, 0, 0)
+
+
+def test_a_signature_no_executable_holds_is_refused_undonated_then_traced(monkeypatch):
+    gfn = jax_compile.guarded_jit(lambda x, y: (x * 2, y + 1), name="t.sel.none", donate_argnums=(0,))
+    gfn.aot_compile(jax.ShapeDtypeStruct((4,), jnp.float32), jax.ShapeDtypeStruct((2,), jnp.float32))
+    x, y = jnp.ones((4,)), jnp.ones((3,))
+    donated_at_route = []
+    real_route = gfn._route
+
+    def spy(args, kwargs):
+        donated_at_route.append(args[0].is_deleted())
+        return real_route(args, kwargs)
+
+    monkeypatch.setattr(gfn, "_route", spy)
+    out_x, out_y = gfn(x, y)
+    # the executable refused the call before anything ran: x was still whole
+    # when the full route began; the jit path then served (and donated) it
+    assert donated_at_route == [False]
+    np.testing.assert_allclose(np.asarray(out_x), 2.0)
+    np.testing.assert_allclose(np.asarray(out_y), 2.0)
+    assert gfn.traces == 1
+    assert (gfn.aot_fallbacks, gfn.route_hits, gfn.route_misses) == (0, 0, 1)
+    assert gfn.last_signature[0][1][0] == (3,)
+    assert len(gfn.aot_executables()) == 1  # a signature fault evicts nothing
+
+
+def test_a_refused_placement_falls_back_once_and_evicts():
+    devices = jax.devices()
+    if len(devices) < 2:
+        pytest.skip("needs two devices")
+    from jax.sharding import SingleDeviceSharding
+
+    gfn = jax_compile.guarded_jit(lambda x: x * 3, name="t.sel.placed")
+    for n in (8, 4):  # the other executable stays: the selector must forget the evicted one
+        gfn.aot_compile(jax.ShapeDtypeStruct((n,), jnp.float32, sharding=SingleDeviceSharding(devices[0])))
+    key = next(key for key in gfn._aot if key[0][0][0] == (4,))
+    exe = gfn._aot[key]
+    dispatched = []
+
+    def counted(*args, **kwargs):
+        dispatched.append(1)
+        return exe(*args, **kwargs)
+
+    with jax_compile._LOCK:
+        gfn._aot[key] = counted
+        gfn._reselect()
+    x = jax.device_put(jnp.ones((4,)), devices[1])  # committed elsewhere: same signature
+    for _ in range(2):
+        np.testing.assert_allclose(np.asarray(gfn(x)), 3.0)
+    assert dispatched == [1]  # refused once, never called again
+    assert gfn.aot_fallbacks == 1
+    assert len(gfn.aot_executables()) == 1 and key not in gfn._aot
+    assert gfn.traces == 1
+    assert (gfn.route_hits, gfn.route_misses) == (0, 2)
+
+
+@pytest.mark.parametrize("scalar", [2.0, jnp.asarray(2.0)], ids=["python", "weak_array"])
+def test_a_weak_typed_scalar_is_served_by_the_pick(scalar):
+    gfn = jax_compile.guarded_jit(lambda x, s: x * s, name="t.sel.weak")
+    gfn.aot_compile(jax.ShapeDtypeStruct((3,), jnp.float32), jax.ShapeDtypeStruct((), jnp.float32))
+    np.testing.assert_allclose(np.asarray(gfn(jnp.ones((3,)), scalar)), 2.0)
+    assert gfn.traces == 0
+    assert (gfn.route_hits, gfn.route_misses) == (1, 0)
+
+
+@pytest.mark.parametrize("registered", [False, True], ids=["nothing_registered", "another_registered"])
+def test_a_call_racing_a_pending_warmup_waits_for_it(registered):
+    import threading
+
+    gfn = jax_compile.guarded_jit(lambda x: x - 1, name="t.sel.pending")
+    if registered:
+        gfn.aot_compile(jax.ShapeDtypeStruct((2,), jnp.float32))
+    release = threading.Event()
+    warmup = jax_compile.AOTWarmup()
+    warmup.add_task(release.wait, name="hold")
+    warmup.add(gfn, jax.ShapeDtypeStruct((6,), jnp.float32))
+    warmup.start()
+    timer = threading.Timer(0.3, release.set)
+    timer.start()
+    try:
+        np.testing.assert_allclose(np.asarray(gfn(jnp.ones((6,)))), 0.0)
+    finally:
+        timer.join()
+        warmup.wait()
+    assert gfn.traces == 0
+    assert gfn.aot_compiles == 1 + registered
+    assert (gfn.route_hits, gfn.route_misses, gfn.aot_fallbacks) == (0, 1, 0)
+    gfn(jnp.ones((6,)))
+    assert (gfn.route_hits, gfn.route_misses) == (1, 1)
