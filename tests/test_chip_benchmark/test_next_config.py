@@ -1,18 +1,21 @@
 """What the next PR that adds to the benchmark may count on, on the CPU.
 
-The first test is the contract of ``BENCHMARK.json``'s ``per_layer`` list, one case a clause: accepted
-entries keep their fields and their place, new ones come after them, a metric with a ``workloads`` list
-is reported by exactly those cells, and every cell reports at least one per-layer metric that has a
-reader. The accepted entries are written here as a PREFIX of the list, never as the whole of it.
+The first test is the contract of ``BENCHMARK.json``'s lists, one case a clause: accepted entries of
+``per_layer``, ``workloads`` and ``configs`` keep their fields and their place, new ones come after them,
+a metric with a ``workloads`` list is reported by exactly those cells, and every cell reports at least
+one per-layer metric that has a reader. The accepted entries are written here as a PREFIX of each list,
+never as the whole of it. This is the one place where the order of entries is asserted: every other
+test of the benchmark checks membership, so that appending an entry breaks none of them.
 
 The others rehearse a further language-model configuration in a temporary copy of the benchmark: new
 files and appended entries only (``models/lm.py``'s blocks under another layer pattern, a count file
 of its own with one more scope, a reader of its own, a cell), found by name, counted, run and read
-without an edit to a file that is there.
+without an edit to a file that is there, with every cell file's checks of the lists run on the copy.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import shutil
@@ -57,15 +60,60 @@ ACCEPTED = (  # in their places; a later `benchmark` PR may lengthen this, and n
     ("flash_attention_roofline_pct", "%", "higher", "device_trace", "token mixers", "gsteps_per_s"),
     ("gmm_roofline_pct", "%", "higher", "device_trace", "expert layer", "gsteps_per_s"),
     ("moe_compact_share", "ratio", "higher", "program_counter", "expert layer", "gsteps_per_s"),
+    ("window_attention_roofline_pct", "%", "higher", "device_trace", "token mixers", "gsteps_per_s"),
+    ("window_attention_device_ms", "ms", "lower", "device_trace", "token mixers", "gsteps_per_s"),
+    ("shared_expert_device_ms", "ms", "lower", "device_trace", "expert layer", "gsteps_per_s"),
+    ("moe_route_device_ms", "ms", "lower", "device_trace", "expert layer", "gsteps_per_s"),
+    ("setup_import_s", "s", "lower", "program_counter", "set-up", "setup_s"),
+    ("setup_build_s", "s", "lower", "program_counter", "set-up", "setup_s"),
+    ("setup_trace_s", "s", "lower", "program_counter", "set-up", "setup_s"),
+    ("setup_xla_compile_s", "s", "lower", "program_counter", "set-up", "setup_s"),
+    ("setup_cache_load_s", "s", "lower", "program_counter", "set-up", "setup_s"),
+    ("setup_harness_s", "s", "lower", "program_counter", "set-up", "setup_s"),
+)
+WORKLOAD_FIELDS = ("name", "config", "traffic", "chips")
+ACCEPTED_WORKLOADS = (  # in their places, as ACCEPTED
+    ("dv3_xl.chip_player", "dv3_xl_crafter", "chip_player", 1),
+    ("lfm2_ep4.ppo_update_8k", "lfm2_8b_a1b_ep4", "ppo_update_8k", 1),
+    ("trinity_ep16.ppo_update_8k", "trinity_mini_ep16", "ppo_update_8k", 1),
+    ("smallthinker_ep8.ppo_update_16k", "smallthinker_21b_ep8", "ppo_update_16k", 1),
+)
+CONFIG_FIELDS = ("name", "file")
+ACCEPTED_CONFIGS = (  # in their places, as ACCEPTED
+    ("dv3_xl_crafter", "benchmarks/chip/configs/dv3_xl_crafter.json"),
+    ("lfm2_8b_a1b_ep4", "benchmarks/chip/configs/lfm2_8b_a1b_ep4.json"),
+    ("trinity_mini_ep16", "benchmarks/chip/configs/trinity_mini_ep16.json"),
+    ("smallthinker_21b_ep8", "benchmarks/chip/configs/smallthinker_21b_ep8.json"),
 )
 NAMES = tuple(entry[0] for entry in ACCEPTED)
 # what only a learner with a replay prefetcher and DV3's count has to read
 DV3_ONLY = ("train_mfu_pct", "prefetch_wait_ms", "prefetch_sample_ms", "prefetch_h2d_ms", "h2d_mib_per_step")
 LM_METRICS = NAMES[17:26]  # the nine that any language-model configuration's cell reports
+SETUP = NAMES[31:37]  # the six set-up readers, which every cell reports
+_LM_SHARED = tuple(n for n in NAMES[:27] if n not in DV3_ONLY)  # the twelve outside readers, the nine, `moe_compact_share`
 ACCEPTED_CELLS = {  # the accepted metrics each accepted cell reports, in the list's order
-    "dv3_xl.chip_player": NAMES[:17],
-    "lfm2_ep4.ppo_update_8k": tuple(n for n in NAMES if n not in DV3_ONLY),
+    "dv3_xl.chip_player": NAMES[:17] + SETUP,
+    "lfm2_ep4.ppo_update_8k": _LM_SHARED + SETUP,
+    "trinity_ep16.ppo_update_8k": _LM_SHARED + ("window_attention_roofline_pct", "window_attention_device_ms", "shared_expert_device_ms") + SETUP,
+    "smallthinker_ep8.ppo_update_16k": _LM_SHARED + ("window_attention_roofline_pct", "window_attention_device_ms", "moe_route_device_ms") + SETUP,
 }
+
+
+def _list_checks():
+    """Every other test file's ``LIST_CHECKS`` here: its checks of what ``BENCHMARK.json``'s lists say of its entries, by
+    membership, each a function of a checkout's root. Found by name, so that a new cell's test file joins with no edit here."""
+    here, checks = os.path.dirname(os.path.abspath(__file__)), []
+    for f in sorted(os.listdir(here)):
+        if not f.startswith("test_") or not f.endswith(".py") or f == os.path.basename(__file__):
+            continue
+        path = os.path.join(here, f)
+        module = sys.modules.get(f[:-3])
+        if getattr(module, "__file__", None) != path:  # a file this process did not collect: load it beside pytest's modules
+            spec = importlib.util.spec_from_file_location(f"_list_checks_{f[:-3]}", path)
+            module = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(module)
+        checks += getattr(module, "LIST_CHECKS", ())
+    return tuple(checks)
 
 
 def _reported(root):
@@ -74,12 +122,24 @@ def _reported(root):
     return {w["name"]: [m["name"] for m in common.resolve_cell(w["name"], root)["per_layer"]] for w in bench["workloads"]}
 
 
+def _keep_their_fields_and_their_place(entries, fields, accepted):
+    assert tuple(tuple(e[k] for k in fields) for e in entries[: len(accepted)]) == accepted
+    names = [e["name"] for e in entries]
+    assert len(set(names)) == len(names)  # so a new entry is a new name, and comes after the accepted ones
+
+
 def accepted_entries_keep_their_fields_and_their_place(root):
     entries = common.load_json(root, "BENCHMARK.json")["per_layer"]
-    assert tuple(tuple(m[k] for k in FIELDS) for m in entries[: len(ACCEPTED)]) == ACCEPTED
+    _keep_their_fields_and_their_place(entries, FIELDS, ACCEPTED)
     assert all(set(m) - {"workloads"} == set(FIELDS) for m in entries)  # an entry has these keys and, at most, a list
-    names = [m["name"] for m in entries]
-    assert len(set(names)) == len(names)  # so a new entry is a new name, and comes after the accepted ones
+
+
+def accepted_workloads_keep_their_fields_and_their_place(root):
+    _keep_their_fields_and_their_place(common.load_json(root, "BENCHMARK.json")["workloads"], WORKLOAD_FIELDS, ACCEPTED_WORKLOADS)
+
+
+def accepted_configs_keep_their_fields_and_their_place(root):
+    _keep_their_fields_and_their_place(common.load_json(root, "BENCHMARK.json")["configs"], CONFIG_FIELDS, ACCEPTED_CONFIGS)
 
 
 def accepted_cells_keep_their_metrics_and_new_ones_come_after(root):
@@ -112,6 +172,8 @@ def every_cell_reports_a_per_layer_metric_and_each_has_a_reader(root):
 
 CLAUSES = (
     accepted_entries_keep_their_fields_and_their_place,
+    accepted_workloads_keep_their_fields_and_their_place,
+    accepted_configs_keep_their_fields_and_their_place,
     accepted_cells_keep_their_metrics_and_new_ones_come_after,
     a_listed_metric_is_reported_by_exactly_its_cells,
     every_cell_reports_a_per_layer_metric_and_each_has_a_reader,
@@ -124,25 +186,32 @@ def test_per_layer_entries_keep_the_contract(clause):
 
 
 def test_the_contract_refuses_what_it_forbids(tmp_path):
-    """Each clause against an edit of its kind: a changed field, an entry put first, a key beyond the contract's,
-    a list that takes a metric from an accepted cell, a list that names no cell, a metric without a reader."""
+    """Each clause against an edit of its kind: a changed field, an entry put before an accepted one (in each of the
+    three lists), a key beyond the contract's, a name twice, a list that takes a metric from an accepted cell, a list
+    that names no cell, a metric without a reader."""
     os.symlink(os.path.join(ROOT, "benchmarks"), tmp_path / "benchmarks")
 
     def edited(edit):
         bench = common.load_json(ROOT, "BENCHMARK.json")
-        edit(bench["per_layer"])
+        edit(bench)
         (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
         return str(tmp_path)
 
     new = {"name": "fence_ms", "unit": "ms", "better": "lower", "source": "program_span", "layer": "train program", "moves": "gsteps_per_s"}
+    cell = {"name": "dv3_xl.sync8", "config": "dv3_xl_crafter", "traffic": "chip_player", "chips": 1, "why": "x"}
+    config = {"name": "dv3_xl_sync8", "source": "x", "file": "benchmarks/chip/configs/dv3_xl_crafter.json", "reduced": [], "why": "x"}
     for clause, edit, error in (
-        (accepted_entries_keep_their_fields_and_their_place, lambda entries: entries[3].update(better="higher"), AssertionError),
-        (accepted_entries_keep_their_fields_and_their_place, lambda entries: entries.insert(0, new), AssertionError),
-        (accepted_entries_keep_their_fields_and_their_place, lambda entries: entries.append({**new, "why": "x"}), AssertionError),
-        (accepted_cells_keep_their_metrics_and_new_ones_come_after, lambda entries: entries[1].update(workloads=["dv3_xl.chip_player"]), AssertionError),
-        (a_listed_metric_is_reported_by_exactly_its_cells, lambda entries: entries[-1].update(workloads=["no.such_cell"]), AssertionError),
-        (a_listed_metric_is_reported_by_exactly_its_cells, lambda entries: entries[-1].update(workloads=[]), AssertionError),
-        (every_cell_reports_a_per_layer_metric_and_each_has_a_reader, lambda entries: entries.append(new), FileNotFoundError),
+        (accepted_entries_keep_their_fields_and_their_place, lambda b: b["per_layer"][3].update(better="higher"), AssertionError),
+        (accepted_entries_keep_their_fields_and_their_place, lambda b: b["per_layer"].insert(0, new), AssertionError),
+        (accepted_entries_keep_their_fields_and_their_place, lambda b: b["per_layer"].append({**new, "why": "x"}), AssertionError),
+        (accepted_workloads_keep_their_fields_and_their_place, lambda b: b["workloads"].insert(3, cell), AssertionError),
+        (accepted_workloads_keep_their_fields_and_their_place, lambda b: b["workloads"][1].update(chips=4), AssertionError),
+        (accepted_configs_keep_their_fields_and_their_place, lambda b: b["configs"].insert(0, config), AssertionError),
+        (accepted_configs_keep_their_fields_and_their_place, lambda b: b["configs"].append(dict(b["configs"][2])), AssertionError),
+        (accepted_cells_keep_their_metrics_and_new_ones_come_after, lambda b: b["per_layer"][1].update(workloads=["dv3_xl.chip_player"]), AssertionError),
+        (a_listed_metric_is_reported_by_exactly_its_cells, lambda b: b["per_layer"][0].update(workloads=["no.such_cell"]), AssertionError),
+        (a_listed_metric_is_reported_by_exactly_its_cells, lambda b: b["per_layer"][0].update(workloads=[]), AssertionError),
+        (every_cell_reports_a_per_layer_metric_and_each_has_a_reader, lambda b: b["per_layer"].append(new), FileNotFoundError),
     ):
         with pytest.raises(error):
             clause(edited(edit))
@@ -208,7 +277,7 @@ def copy(tmp_path_factory):
     bench["configs"].append({"name": NEW_CONFIG, "source": "x", "file": f"benchmarks/chip/configs/{NEW_CONFIG}.json", "reduced": config["reduced"], "why": "x"})
     bench["workloads"].append({"name": NEW_CELL, "config": NEW_CONFIG, "traffic": "ppo_update_8k", "chips": 1, "why": "x"})
     for m in bench["per_layer"]:  # its cell comes to report a shared metric by joining the metric's list
-        if m["name"] in LM_METRICS + ("moe_compact_share",):
+        if m["name"] in LM_METRICS + ("moe_compact_share",) + SETUP:
             m["workloads"].append(NEW_CELL)
     bench["per_layer"].append({"name": NEW_METRIC, "unit": "ms", "better": "lower", "source": "device_trace", "layer": "optimizer", "moves": "gsteps_per_s", "workloads": [NEW_CELL]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
@@ -226,12 +295,15 @@ def _env():
 
 def test_new_configuration_is_found_by_name_and_counted_with_nothing_loaded_first(copy):
     root, here = copy["root"], copy["here"]
-    for clause in CLAUSES:  # the appended entries keep the contract
-        clause(root)
+    checks = _list_checks()
+    found = {os.path.basename(check.__code__.co_filename) for check in checks}
+    assert {"test_trinity_cell.py", "test_smallthinker_cell.py", "test_setup_readers.py"} <= found
+    for check in CLAUSES + checks:  # the appended entries keep the contract, and every test file's checks of its entries hold
+        check(root)
     cell = common.resolve_cell(NEW_CELL, root)
     assert cell["here"] == here and cell["config_file"]["sizes"]["layers"] == [0, 2, 4]
     reported = [m["name"] for m in cell["per_layer"]]
-    assert reported[-len(LM_METRICS) - 2 :] == list(LM_METRICS) + ["moe_compact_share", NEW_METRIC]
+    assert set(LM_METRICS + ("moe_compact_share", NEW_METRIC) + SETUP) <= set(reported)
     assert not set(reported) & set(DV3_ONLY) and reported[:12] == list(ACCEPTED_CELLS["lfm2_ep4.ppo_update_8k"][:12])
     assert NEW_METRIC not in _reported(root)["lfm2_ep4.ppo_update_8k"]
     # a fresh interpreter that loads flops.py and nothing else finds each configuration's count, the new one's too

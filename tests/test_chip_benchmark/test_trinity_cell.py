@@ -27,6 +27,7 @@ SHARED_METRICS = (
     "lm_train_mfu_pct", "moe_device_ms", "mixer_device_ms", "head_loss_device_ms", "moe_experts_roofline_pct",
     "moe_load_max_over_mean", "rollout_feed_ms", "flash_attention_roofline_pct", "gmm_roofline_pct", "moe_compact_share",
 )
+ACCEPTED_CELLS = ("dv3_xl.chip_player", "lfm2_ep4.ppo_update_8k", CELL, "smallthinker_ep8.ppo_update_16k")  # a cell appended later may join a list
 
 
 def _cell():
@@ -67,10 +68,7 @@ def test_configuration_states_the_cut_and_the_catalog_numbers():
         assert "modeling_afmoe.py" in config["assumed"][key], key
     for key in ("expert_bias", "critic", "ppo_recipe", "weights", "expert_placement"):
         assert key in config["assumed"]
-    bench = common.load_json(ROOT, "BENCHMARK.json")
-    entry = next(c for c in bench["configs"] if c["name"] == CONFIG)
-    assert entry["source"] == "https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json" and entry["reduced"] == config["reduced"]
-    assert [w["name"] for w in bench["workloads"] if w["config"] == CONFIG] == [CELL]
+    configuration_is_listed(ROOT)
     # the sizes the reference and the count run on are the file's own numbers
     sizes = config["sizes"]
     assert (sizes["experts_held"], sizes["num_experts"], sizes["vocab"], sizes["layers"]) == (8, 128, 25024, [0, 4, 5, 6, 7])
@@ -145,16 +143,35 @@ def test_flop_count_is_the_issue_s_arithmetic():
     assert only_here <= set(shared.scopes_of(config)) and "lm.conv" not in shared.scopes_of(config)
 
 
-def test_the_cell_reports_the_shared_metrics_and_its_own_three():
-    cell = common.resolve_cell(CELL)
+# What ``<root>/BENCHMARK.json``'s lists say of this configuration and its cell, by membership and never by place:
+# ``test_next_config.py`` asserts the order of entries once, and runs these on a copy with a further configuration appended.
+
+
+def configuration_is_listed(root):
+    bench = common.load_json(root, "BENCHMARK.json")
+    entry = next(c for c in bench["configs"] if c["name"] == CONFIG)
+    assert entry["source"] == "https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json"
+    assert entry["reduced"] == common.load_json(CHIP, "configs", f"{CONFIG}.json")["reduced"]
+    assert [w["name"] for w in bench["workloads"] if w["config"] == CONFIG and w["name"] in ACCEPTED_CELLS] == [CELL]
+
+
+def cell_is_listed_with_its_metrics(root):
+    cell = common.resolve_cell(CELL, root)
     reported = [m["name"] for m in cell["per_layer"]]
-    assert reported[-3:] == list(NEW_METRICS) and set(SHARED_METRICS) <= set(reported)
+    assert set(NEW_METRICS) <= set(reported) and set(SHARED_METRICS) <= set(reported)
     assert [m["name"] for m in cell["end_to_end"]] == ["gsteps_per_s", "step_ms_p95", "setup_s"]
-    other = [m["name"] for m in common.resolve_cell("lfm2_ep4.ppo_update_8k")["per_layer"]]
+    other = [m["name"] for m in common.resolve_cell("lfm2_ep4.ppo_update_8k", root)["per_layer"]]
     assert not set(NEW_METRICS) & set(other) and set(SHARED_METRICS) <= set(other)
     by_name = {m["name"]: m for m in cell["per_layer"]}
     assert [by_name[n]["layer"] for n in NEW_METRICS] == ["token mixers", "token mixers", "expert layer"]
     assert cell["traffic_file"] == common.load_json(CHIP, "traffic", "ppo_update_8k.json") and cell["chips"] == 1 and len(cell["why"]) <= 200
+
+
+LIST_CHECKS = (configuration_is_listed, cell_is_listed_with_its_metrics)
+
+
+def test_the_cell_reports_the_shared_metrics_and_its_own_three():
+    cell_is_listed_with_its_metrics(ROOT)
 
 
 def test_reference_follows_a_given_routing_and_reports_its_own():
